@@ -1,0 +1,419 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the one command that proves the main path runs on the TPU.
+
+    python3 chip_smoke.py                # on a machine with a TPU; exit 0 = up
+    python3 chip_smoke.py --dry-run-cpu  # tiny shapes, interpreted kernels
+
+Drives, through the entry points a user calls and at the flagship widths:
+
+  Leg A  the FedAvg ResNet-20 round (``fedml_tpu.init`` -> ``FedMLRunner.run``
+         -> scanned chunks with a donated carry + ``evaluate``);
+  Leg C  every Pallas kernel compiled by Mosaic (``interpret=False``) against
+         the jnp reference beside it, and one scanned chunk of Leg A's recipe
+         with ``extra.fused_blocks`` checked against Leg A's own losses (on a
+         host with several chips the engine must refuse that recipe on the
+         full mesh — GSPMD cannot shard a Mosaic kernel — and the chunk runs
+         on one chip);
+  Leg B  the 542M-parameter LLM train step (``LLMTrainer.fit``), on every mesh
+         the visible devices allow.
+
+It refuses to start unless ``jax.devices()[0].platform == "tpu"``: no CPU
+continuation, because every kernel would then run interpreted and pass.  Any
+failed leg makes the exit code non-zero.  The last stdout line is one JSON
+object naming the device as JAX reports it.  ``--dry-run-cpu`` exists to
+debug the control flow in a sandbox; it labels everything ``dry_run`` and is
+never reached implicitly.
+
+One process, one chip (or one host's chips): the legs run in sequence and
+drop their references, no child process is started.  Wall seconds are printed
+per leg, split into compile and steady, as set-up facts — not as metrics.
+"""
+
+from __future__ import annotations
+
+import argparse
+import gc
+import json
+import os
+import sys
+import time
+import traceback
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def log(msg: str) -> None:
+    print(f"[chip_smoke] {msg}", flush=True)
+
+
+def check(cond: bool, what: str) -> None:
+    if not cond:
+        raise AssertionError(what)
+
+
+def memory(jax) -> list[dict]:
+    out = []
+    for d in jax.devices():
+        stats = d.memory_stats() or {}
+        out.append({"in_use": stats.get("bytes_in_use"),
+                    "peak": stats.get("peak_bytes_in_use")})
+    return out
+
+
+def fmt_memory(mem: list[dict]) -> str:
+    """``in-use/peak MiB`` per device (peak is the process's so far)."""
+    return ", ".join(
+        "n/a" if m["in_use"] is None
+        else f"{m['in_use'] / 2**20:.0f}/{m['peak'] / 2**20:.0f} MiB" for m in mem)
+
+
+def check_spread(jax, tree, what: str) -> None:
+    """Every leaf lives on every local device, and every device holds bytes:
+    a mesh that quietly put everything on chip 0 must not pass."""
+    n = jax.device_count()
+    for leaf in jax.tree_util.tree_leaves(tree):
+        check(len(leaf.sharding.device_set) == n,
+              f"{what}: a leaf of shape {leaf.shape} sits on "
+              f"{len(leaf.sharding.device_set)} of {n} devices")
+    for d, m in zip(jax.devices(), memory(jax)):
+        check(m["in_use"] is None or m["in_use"] > 0,
+              f"{what}: {d} reports no bytes in use")
+
+
+# ---------------------------------------------------------------------------
+# Leg A — flagship FedAvg round (bench.py's fedavg shape)
+# ---------------------------------------------------------------------------
+
+def fl_recipe(dry: bool, fused: bool, rounds: int, eval_every: int,
+              mesh_shape: str = ""):
+    from fedml_tpu.arguments import Config
+
+    n_clients, per_round, per_client, batch, n_test = (
+        (4, 2, 16, 8, 32) if dry else (128, 64, 512, 128, 1024))
+    return Config(
+        dataset="cifar10", model="resnet20",
+        client_num_in_total=n_clients, client_num_per_round=per_round,
+        comm_round=rounds, epochs=1, batch_size=batch, learning_rate=0.03,
+        partition_method="homo",
+        synthetic_train_size=n_clients * per_client, synthetic_test_size=n_test,
+        frequency_of_the_test=eval_every, compute_dtype="bfloat16",
+        step_mode="match", metrics_jsonl_path="", random_seed=0,
+        mesh_shape=mesh_shape,
+        extra={"fused_blocks": True} if fused else {},
+    )
+
+
+def run_fl(jax, dry: bool, fused: bool, rounds: int, eval_every: int,
+           mesh_shape: str = "") -> dict:
+    """``init`` -> ``FedMLRunner`` -> ``run`` on the recipe; returns history,
+    the compile/steady split and a host copy of the final flat model.  The
+    default mesh must span every device; an explicit ``mesh_shape`` is a
+    deliberate carve and is not checked for spread."""
+    import math
+
+    import fedml_tpu
+    from fedml_tpu.core import pytree as pt
+    from fedml_tpu.runner import FedMLRunner
+    from fedml_tpu.sim import engine
+
+    cfg = fl_recipe(dry, fused, rounds, eval_every, mesh_shape)
+    fedml_tpu.init(cfg)
+    model = None
+    if dry:  # depth cut for the sandbox; the chip runs ResNet-20 from the hub
+        import jax.numpy as jnp
+
+        from fedml_tpu.models import resnet
+
+        model = resnet.CifarResNet(num_blocks=1, dtype=jnp.bfloat16, fused=fused)
+    compile0 = engine.CHUNK_COMPILE_TIME.sum()
+    runner = FedMLRunner(cfg, model=model)
+    sim = runner.runner
+    check(bool(getattr(sim.model, "fused", False)) == fused, "fused flag lost")
+    if not mesh_shape:
+        check(sim.mesh.devices.size == jax.device_count(),
+              f"mesh {dict(sim.mesh.shape)} does not span "
+              f"{jax.device_count()} devices")
+        check_spread(jax, (sim._data, sim.client_states, sim.global_vars,
+                           sim._test), "FL placement")
+    history = runner.run()
+    check(len(history) == rounds, f"{len(history)} rounds of {rounds} ran")
+    for h in history:
+        for k, v in h.items():
+            check(math.isfinite(float(v)), f"round {h['round']}: {k}={v}")
+    check(history[-1]["train_loss"] < history[0]["train_loss"],
+          f"train_loss did not fall: {history[0]['train_loss']} -> "
+          f"{history[-1]['train_loss']}")
+    if eval_every:
+        check("test_acc" in history[eval_every - 1] and "test_acc" in history[-1],
+              "evaluate() did not run at the eval boundary")
+    flat, _ = pt.tree_flatten_to_vector(sim.global_vars)
+    return {
+        "history": history,
+        "compile_s": engine.CHUNK_COMPILE_TIME.sum() - compile0,
+        # the last chunk reuses the first chunk's program: no compile in it
+        "steady_s": history[-1]["chunk_time_s"],
+        "chunk_rounds": history[-1]["chunk_rounds"],
+        "flat_model": jax.device_get(flat),
+        "memory": memory(jax),
+    }
+
+
+def leg_a(jax, dry: bool) -> dict:
+    r = run_fl(jax, dry, fused=False, rounds=4 if dry else 6,
+               eval_every=2 if dry else 3)
+    log(f"leg A: losses {[round(h['train_loss'], 4) for h in r['history']]} "
+        f"test_acc {r['history'][-1]['test_acc']:.4f}")
+    return r
+
+
+# ---------------------------------------------------------------------------
+# Leg C — every Pallas kernel, compiled, against its jnp reference
+# ---------------------------------------------------------------------------
+
+def leg_c(jax, dry: bool, ref_a: dict | None) -> dict:
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.ops.pallas import backend, fused_block as fb, noise, quantize as q
+
+    interp = dry  # explicit, never derived: compiled on the chip
+    check(backend.resolve_interpret(None) == dry,
+          "kernels that derive `interpret` would not run compiled here")
+    t0 = time.perf_counter()
+    key = jax.random.PRNGKey(7)
+
+    # quantize/dequantize: the ResNet-20 flat tree and a rank-8 LoRA factor
+    flat = (ref_a["flat_model"] if ref_a is not None
+            else np.asarray(jax.random.normal(key, (272_474,)) * 0.1))
+    lora = np.asarray(jax.random.normal(key, (128, 8)) * 0.02).reshape(-1)
+    for name, vec in (("flat_model", flat), ("lora_r8", lora)):
+        x = jnp.asarray(vec, jnp.float32)
+        values, scales, n = q.quantize_int8_stochastic(x, key, interpret=interp)
+        v_ref, s_ref, n_ref = q.quantize_int8_reference(x, key)
+        check(n == n_ref == x.shape[0], f"quantize[{name}] length")
+        np.testing.assert_array_equal(np.asarray(values), np.asarray(v_ref),
+                                      err_msg=f"quantize[{name}] values")
+        np.testing.assert_allclose(np.asarray(scales), np.asarray(s_ref),
+                                   rtol=1e-6, err_msg=f"quantize[{name}] scales")
+        back = q.dequantize_int8(values, scales, n, interpret=interp)
+        check(float(jnp.abs(back - x).max()) <= float(scales.max()) + 1e-6,
+              f"dequantize[{name}] error exceeds one step")
+        log(f"leg C: quantize/dequantize[{name}] {x.shape[0]} elements ok")
+
+    x = jnp.asarray(flat, jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(noise.apply_gaussian_noise(x, key, 0.37, interpret=interp)),
+        np.asarray(noise.apply_gaussian_noise_reference(x, key, 0.37)),
+        rtol=1e-6, atol=1e-6, err_msg="apply_gaussian_noise")
+    log("leg C: apply_gaussian_noise ok")
+
+    # fused BN(+residual)+ReLU, forward and backward, at the three stage
+    # shapes of one client batch, in the flagship dtype
+    batch = 2 if dry else 128
+    f32 = lambda a: np.asarray(a, np.float32)
+    for hw, ch in ((32, 16), (16, 32), (8, 64)):
+        shape = (batch, hw, hw, ch)
+        ks = jax.random.split(jax.random.fold_in(key, ch), 5)
+        y, r, g = (jax.random.normal(k, shape, jnp.bfloat16) for k in ks[:3])
+        s, b = (jax.random.normal(k, (ch,), jnp.float32) for k in ks[3:])
+
+        def both(kernel_res, kernel_plain):
+            def f(y, s, b, r, g):
+                out, pull = jax.vjp(kernel_res, y, s, b, r)
+                out2, pull2 = jax.vjp(kernel_plain, y, s, b)
+                return out, pull(g), out2, pull2(g)
+            return jax.jit(f)(y, s, b, r, g)
+
+        got = both(
+            lambda y, s, b, r: fb.fused_bn_residual_relu(y, s, b, r, interpret=interp),
+            lambda y, s, b: fb.fused_bn_relu(y, s, b, interpret=interp))
+        want = both(fb.fused_block_reference, fb.fused_block_reference)
+        for a, e in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            # elementwise outputs are bf16; d(scale)/d(shift) sum ~1e5 terms
+            scale = max(1.0, float(np.abs(f32(e)).max()))
+            np.testing.assert_allclose(f32(a), f32(e), rtol=1e-2, atol=1e-2 * scale,
+                                       err_msg=f"fused epilogue {shape}")
+        log(f"leg C: fused_bn_relu / fused_bn_residual_relu fwd+bwd {shape} ok")
+    kernels_s = time.perf_counter() - t0
+
+    # the kernel as it is actually used: vmapped over clients, inside the
+    # scanned chunk, under custom_vjp — one chunk of Leg A's recipe
+    rounds = 2
+    mesh_shape = ""
+    if jax.device_count() > 1 and not dry:
+        # GSPMD cannot shard a compiled Mosaic kernel, so on several chips
+        # the engine must REFUSE the fused recipe on the default mesh (never
+        # run it interpreted, replicated or unfused) ...
+        try:
+            run_fl(jax, dry, fused=True, rounds=rounds, eval_every=0)
+        except NotImplementedError as e:
+            check("cannot be automatically partitioned" in str(e), str(e))
+            log(f"leg C: fused_blocks on {jax.device_count()} chips refused, "
+                "as it must be")
+        else:
+            raise AssertionError(
+                "fused_blocks ran on a multi-chip mesh: how was the kernel "
+                "partitioned?")
+        mesh_shape = "clients:1"  # ... and the chunk runs on one of them
+    r = run_fl(jax, dry, fused=True, rounds=rounds, eval_every=0,
+               mesh_shape=mesh_shape)
+    fused_losses = [h["train_loss"] for h in r["history"]]
+    if ref_a is not None:
+        want = [h["train_loss"] for h in ref_a["history"][:rounds]]
+        np.testing.assert_allclose(
+            fused_losses, want, rtol=2e-2,
+            err_msg="fused_blocks chunk disagrees with the unfused Leg A")
+    log(f"leg C: fused_blocks chunk losses {[round(l, 4) for l in fused_losses]}"
+        + ("" if ref_a is not None else " (no Leg A reference to compare)"))
+    return {"kernels_s": kernels_s, "compile_s": r["compile_s"],
+            # its only chunk compiled: steady = chunk wall minus the compile
+            "steady_s": r["steady_s"] - r["compile_s"],
+            "chunk_rounds": rounds, "memory": r["memory"]}
+
+
+# ---------------------------------------------------------------------------
+# Leg B — LLM train step (bench.py's llm shape)
+# ---------------------------------------------------------------------------
+
+def leg_b(jax, dry: bool, mesh_shape: dict | None, compiles: list) -> dict:
+    import math
+
+    import numpy as np
+
+    from fedml_tpu.llm.train import LLMTrainArgs, LLMTrainer
+    from fedml_tpu.models.transformer import TransformerConfig
+    from fedml_tpu.parallel import mesh as meshlib
+
+    if dry:
+        tcfg = TransformerConfig.tiny(vocab_size=1024)
+        args = LLMTrainArgs(batch_size=4, seq_len=128, total_steps=16, warmup_steps=1)
+    else:
+        tcfg = TransformerConfig(
+            vocab_size=32000, d_model=2048, n_layers=8, n_heads=16, n_kv_heads=16,
+            d_ff=5632, max_seq_len=2048, remat=True, remat_policy="dots")
+        args = LLMTrainArgs(batch_size=8, seq_len=2048, total_steps=16, warmup_steps=1)
+    mesh = None  # LLMTrainer's default: every device on the data axis
+    if mesh_shape is not None:
+        mesh = meshlib.make_mesh(tuple(mesh_shape), tuple(mesh_shape.values()))
+
+    trainer = LLMTrainer(tcfg, args, mesh=mesh)
+    check(trainer.mesh.devices.size == jax.device_count(),
+          f"mesh {dict(trainer.mesh.shape)} does not span every device")
+    check_spread(jax, (trainer.params, trainer.opt_state), "LLM placement")
+
+    # one seeded batch, repeated: the loss must fall as the step memorises it
+    tokens = np.random.RandomState(0).randint(
+        0, tcfg.vocab_size, (args.batch_size, args.seq_len + 1)).astype(np.int32)
+
+    def batches():
+        while True:
+            yield tokens[:, :-1], tokens[:, 1:]
+
+    steps = 5
+    history = trainer.fit(batches(), steps=1)
+    after_first = len(compiles)
+    history += trainer.fit(batches(), steps=steps - 1)
+    check(len(history) == steps, f"{len(history)} of {steps} steps ran")
+    for h in history:
+        check(math.isfinite(h["loss"]), f"step {h['step']}: loss={h['loss']}")
+    # the schedule warms up from lr 0, so step 2 is the first to see an update
+    check(history[-1]["loss"] < history[0]["loss"],
+          f"loss did not fall: {[h['loss'] for h in history]}")
+    check(len(compiles) == after_first,
+          f"{len(compiles) - after_first} trace/compile event(s) after the "
+          "first step (the step must not recompile)")
+    steady = sorted(h["step_time_s"] for h in history[1:])[len(history[1:]) // 2]
+    log(f"leg B {dict(trainer.mesh.shape)}: {trainer.n_params() / 1e6:.0f}M params, "
+        f"losses {[round(h['loss'], 3) for h in history]}")
+    return {"compile_s": history[0]["step_time_s"] - steady, "steady_s": steady,
+            "memory": memory(jax)}
+
+
+# ---------------------------------------------------------------------------
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--dry-run-cpu", action="store_true",
+                    help="tiny shapes + interpreted kernels on whatever "
+                         "device is there; debugging only, labelled dry_run")
+    dry = ap.parse_args().dry_run_cpu
+
+    if not os.path.isdir(os.path.join(HERE, "fedml_tpu")):
+        print(f"chip_smoke.py: no fedml_tpu package beside {__file__}; run it "
+              "from the root of a checkout", file=sys.stderr)
+        return 2
+    sys.path.insert(0, HERE)
+
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not dry:
+        print(f"chip_smoke.py: no TPU — jax.devices() = {jax.devices()}; "
+              "refusing to continue on another platform", file=sys.stderr)
+        return 2
+
+    import importlib.metadata as md
+
+    import jaxlib
+
+    from fedml_tpu.core.cache import setup_persistent_cache
+    from fedml_tpu.ops import flops as flopslib
+
+    tag = "dry_run " if dry else ""
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    try:
+        libtpu = md.version("libtpu")
+    except md.PackageNotFoundError:
+        libtpu = "not installed"
+    log(f"{tag}platform={device['platform']} device_kind={device['kind']!r} "
+        f"count={device['count']} jax={jax.__version__} jaxlib={jaxlib.__version__} "
+        f"libtpu={libtpu}")
+    log(f"{tag}compile cache: {setup_persistent_cache()}")
+    # an unknown TPU kind raises here (ops/flops.py): no MFU without a peak
+    log(f"{tag}peak bf16 FLOP/s per chip: {flopslib.device_peak_flops(dev)}")
+
+    # every trace and every backend compile (persistent-cache hits included)
+    # from here on: Leg B reads it to prove its second step reuses the first's
+    compiles: list = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, secs, **kw: compiles.append(name)
+        if name.endswith(("jaxpr_trace_duration", "backend_compile_duration"))
+        else None)
+
+    n = device["count"]
+    legs = [("A", lambda: leg_a(jax, dry)),
+            ("C", lambda: leg_c(jax, dry, results.get("A"))),
+            ("B", lambda: leg_b(jax, dry, None, compiles))]
+    if n >= 4 and n % 2 == 0:
+        legs.append(("B data x model", lambda: leg_b(
+            jax, dry, {"data": n // 2, "model": 2}, compiles)))
+    results, failed = {}, []
+    for name, leg in legs:
+        t0 = time.perf_counter()
+        try:
+            results[name] = r = leg()
+            log(f"{tag}leg {name} PASSED in {time.perf_counter() - t0:.1f}s: "
+                f"compile {r['compile_s']:.1f}s, steady {r['steady_s']:.3f}s"
+                + (f" per {r['chunk_rounds']}-round chunk" if "chunk_rounds" in r
+                   else " per step")
+                + (f", kernels {r['kernels_s']:.1f}s" if "kernels_s" in r else "")
+                + f", memory in-use/peak per device: {fmt_memory(r['memory'])}")
+        except Exception:
+            failed.append(name)
+            traceback.print_exc()
+            log(f"{tag}leg {name} FAILED after {time.perf_counter() - t0:.1f}s")
+        gc.collect()  # drop the leg's device buffers before the next one
+
+    out = {"ok": not failed, "device": device}
+    if dry:
+        out["dry_run"] = True
+    if failed:
+        out["failed_legs"] = failed
+    print(json.dumps(out), flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

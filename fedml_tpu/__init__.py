@@ -33,6 +33,12 @@ def init(args: Optional[Config] = None, argv=None) -> Config:
         level=logging.INFO,
         format="[fedml_tpu] %(asctime)s %(levelname)s %(message)s",
     )
+    # every entry point compiles through the persistent cache, placed by the
+    # one rule in core/cache.py ($JAX_COMPILATION_CACHE_DIR, else a fixed
+    # path in the checkout)
+    from .core.cache import setup_persistent_cache
+
+    setup_persistent_cache()
     # MULTIPROCESS/MPI backend: bring up jax.distributed before any backend
     # use so the mesh spans all hosts (reference: MPI rank discovery in
     # fedml.init; here the coordination service replaces mpi4py).

@@ -1,9 +1,9 @@
 """GL003 — donation-safety: never read a variable after donating it.
 
 ``jax.jit(..., donate_argnums=...)`` hands the argument buffers to XLA for
-in-place reuse: the caller's arrays are invalid afterwards, and on XLA:CPU
-(jax 0.4.37) touching them corrupts the heap outright — the tier-1 suite's
-historical wandering segfaults (``sim/engine.py``, ROADMAP).  The rule
+in-place reuse: the caller's arrays are invalid afterwards — reading one
+raises on a deleted buffer at best, and an older XLA:CPU corrupted the heap
+outright (the tier-1 suite's historical wandering segfaults).  The rule
 tracks, per function scope:
 
 1. names bound to ``jax.jit(fn, donate_argnums=<positions>)`` or

@@ -149,7 +149,6 @@ class Config:
     worker_num: int = 0
     n_node_in_silo: int = 1
     n_proc_per_node: int = 1
-    process_id: int = 0
 
     # ---- checkpoint (TPU-native first-class, SURVEY §5) --------------------
     checkpoint_dir: str = ""

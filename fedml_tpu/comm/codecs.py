@@ -92,9 +92,7 @@ def _compress_vec(codec: str, vec, leaf_key, residual, ratio: float):
     if codec == "qsgd8":
         from ..ops.pallas import quantize as q
 
-        values, scales, n = q.quantize_int8_stochastic(
-            vec, leaf_key, interpret=jax.default_backend() != "tpu"
-        )
+        values, scales, n = q.quantize_int8_stochastic(vec, leaf_key)
         segments = (np.asarray(scales, dtype="<f4"),
                     np.asarray(values, np.int8).reshape(-1))
         return segments, {"blocks": int(scales.shape[0]), "length": int(n)}, residual

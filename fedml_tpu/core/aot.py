@@ -44,8 +44,8 @@ Design constraints honored here:
   ``fedml_aot_{load,build}_seconds`` histograms land in the global registry,
   and each load/build emits an obs-trail record through the caller's sink.
 
-Entries live under the same host-fingerprinted repo-root cache directory as
-the XLA persistent cache (``core/cache.py``): ``.jax_cache-<host>/aot_programs``.
+Entries live under whichever directory ``core/cache.py`` resolved for the XLA
+persistent cache: ``<cache_dir>/aot_programs``.
 """
 
 from __future__ import annotations
@@ -282,10 +282,9 @@ def record_program_cost(compiled, key: str) -> Optional[dict]:
 
 
 def default_store_dir() -> str:
-    """``<repo>/.jax_cache-<host>/aot_programs`` — the same host-fingerprinted
-    repo-root cache dir as the XLA persistent compilation cache, so the two
-    halves of a warm start (skip the re-trace, skip the re-compile) travel
-    together."""
+    """``<cache_dir>/aot_programs`` — inside the XLA persistent compilation
+    cache's directory (``core/cache.py``), so the two halves of a warm start
+    (skip the re-trace, skip the re-compile) travel together."""
     return os.path.join(cachelib.cache_dir(), "aot_programs")
 
 
@@ -317,8 +316,12 @@ class StoredProgram:
         if example_args is not None:
             try:
                 return wrapper.lower(*example_args).compile()
-            except Exception:
-                pass
+            except Exception as e:
+                # the lazy wrapper re-raises on first call if the cause is
+                # real; say so now rather than at a later, unrelated line
+                log.warning("aot: eager compile of stored program %s failed "
+                            "(%s: %s) — binding lazily", self.key,
+                            type(e).__name__, e)
         return wrapper
 
 

@@ -1,64 +1,57 @@
-"""Shared persistent XLA compilation-cache setup.
+"""Persistent XLA compilation-cache placement.
 
-One helper, three callers: ``tests/conftest.py`` (the tier-1 suite is
-dominated by XLA compiles), the ``__graft_entry__`` multichip dryrun (whose
-~7 sharded programs previously compiled cold in the re-exec'd child every
-run — the rc=124 driver timeout), and ``bench.py`` (warm re-runs of the A/B
-benches).  All three share ONE on-disk cache at the repo root, so a dryrun
-re-run or a bench after the test suite starts warm.
+One rule, applied by every entry point (``fedml_tpu.init``, ``bench.py``,
+``tests/conftest.py``, ``chip_smoke.py``, the ``__graft_entry__`` dry run):
 
-The cache directory is keyed per host CPU fingerprint: XLA:CPU AOT entries
-compiled on a host with different machine features load with "could lead to
-SIGILL" warnings and occasionally abort the process mid-suite (observed:
-``Fatal Python error: Aborted`` inside a jitted round) — a cache written on
-another machine must never be read.  TPU entries key on the device kind via
-XLA's own cache key, so chip and CPU entries coexist in one directory.
+- ``JAX_COMPILATION_CACHE_DIR`` set: the cache lives where the operator put
+  it.  JAX reads the variable itself, so no directory is set in code — only
+  the thresholds below.
+- unset: one fixed path, ``<checkout>/.jax_cache`` (git-ignored).
+
+The path must not move: JAX hashes the cache directory into every entry's
+key (checked under jax 0.9.0 — a byte-identical copy of a warm directory
+under another name misses on every program), so a name that varies by host
+can never be pre-warmed or carried along with the checkout.
+
+An earlier version of this module suffixed the directory with a digest of
+``/proc/cpuinfo`` because XLA:CPU entries written on another host loaded
+with "could lead to SIGILL" warnings.  Under jaxlib 0.9.0 that is settled
+upstream: the serialized CPU topology that goes into every cache key lists
+the host's machine features (``+avx512f``, ``+amx-bf16``, ...), so an entry
+from a host with a different feature set has a different key and is never
+read; and the warning itself now fires on the *same* host for every hit
+(it trips on the ``+prefer-no-scatter``/``+prefer-no-gather`` tuning
+pseudo-features), so it no longer identifies a foreign entry.  TPU entries
+key on the device topology the same way.
 """
 
 from __future__ import annotations
 
-import hashlib
 import os
-import platform
+
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
 
-def host_fingerprint() -> str:
-    """Stable 12-hex digest of this host's CPU feature set (x86 ``flags``,
-    aarch64 ``Features``, plus model identifiers)."""
-    cpu_flags = platform.machine() + platform.processor()
-    try:
-        seen = set()
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                # x86 says "flags", aarch64 says "Features"; model lines cover
-                # hosts with neither.  First occurrence of each key (cpuinfo
-                # repeats per core) — the feature list is the actual contract.
-                key = line.split(":", 1)[0].strip()
-                if key in ("flags", "Features", "model name", "CPU part") and key not in seen:
-                    seen.add(key)
-                    cpu_flags += line.strip()
-    except OSError:
-        pass
-    return hashlib.sha1(cpu_flags.encode()).hexdigest()[:12]
+def cache_dir() -> str:
+    """Where compiled programs persist: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set, else ``<checkout>/.jax_cache`` (the parent of the ``fedml_tpu``
+    package)."""
+    env = os.environ.get(ENV_VAR)
+    if env:
+        return os.path.abspath(env)
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    return os.path.join(root, ".jax_cache")
 
 
-def cache_dir(root: str | None = None) -> str:
-    """``<root>/.jax_cache-<host_tag>``; root defaults to the repo checkout
-    (the parent of the ``fedml_tpu`` package) — the same path
-    ``tests/conftest.py`` has always used, so existing caches stay warm."""
-    if root is None:
-        root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    return os.path.abspath(os.path.join(root, f".jax_cache-{host_fingerprint()}"))
-
-
-def setup_persistent_cache(root: str | None = None) -> str:
-    """Point jax at the shared persistent compilation cache and return its
-    path.  Call AFTER any platform/env forcing but before the first compile;
+def setup_persistent_cache() -> str:
+    """Enable the persistent compilation cache and return its directory.
+    Call after any platform forcing and before the first compile;
     idempotent."""
     import jax
 
-    path = cache_dir(root)
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = cache_dir()
+    if not os.environ.get(ENV_VAR):
+        jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path

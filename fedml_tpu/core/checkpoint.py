@@ -12,6 +12,7 @@ from __future__ import annotations
 import logging
 import os
 import shutil
+import threading
 from pathlib import Path
 from typing import Any, Optional
 
@@ -21,6 +22,15 @@ import numpy as np
 from ..analysis import tracesan
 
 log = logging.getLogger("fedml_tpu.core.checkpoint")
+
+#: orbax (0.11.32) tracks ONE process-global "current operation id" for its
+#: async-save signalling (``OperationIdGenerator``).  Two managers saving
+#: from two threads interleave next/get on it, and one save then waits for
+#: a directory-creation signal published under the other's id until its
+#: 300 s timeout — seen with two tenants journaling in one process
+#: (tests/test_fleet.py stalled ~1 run in 4).  Saves are synchronous here
+#: anyway, so they take turns.
+_SAVE_LOCK = threading.Lock()
 
 
 class RoundCheckpointer:
@@ -40,21 +50,23 @@ class RoundCheckpointer:
         """state: pytree dict (global_vars, server_state, client_states, key...)."""
         with tracesan.allow("checkpoint"):
             state = jax.device_get(state)
-        try:
-            self.mngr.save(round_idx, args=self._ocp.args.StandardSave(state))
-        except ValueError:
-            # Two managers over one directory (a lingering pre-crash writer's
-            # retention GC racing the restarted server): the other writer can
-            # delete a step this manager still has cached, which fails save()'s
-            # old-step bookkeeping AFTER the write itself was initiated.
-            # Re-sync the cached step list with the directory and retry; when
-            # the initiated write already committed in the background, the
-            # step is on disk and the retry is skipped.
-            self.mngr.wait_until_finished()
-            self.mngr.reload()
-            if round_idx not in set(self.mngr.all_steps()):
+        with _SAVE_LOCK:
+            try:
                 self.mngr.save(round_idx, args=self._ocp.args.StandardSave(state))
-        self.mngr.wait_until_finished()
+            except ValueError:
+                # Two managers over one directory (a lingering pre-crash
+                # writer's retention GC racing the restarted server): the
+                # other writer can delete a step this manager still has
+                # cached, which fails save()'s old-step bookkeeping AFTER the
+                # write itself was initiated.  Re-sync the cached step list
+                # with the directory and retry; when the initiated write
+                # already committed in the background, the step is on disk
+                # and the retry is skipped.
+                self.mngr.wait_until_finished()
+                self.mngr.reload()
+                if round_idx not in set(self.mngr.all_steps()):
+                    self.mngr.save(round_idx, args=self._ocp.args.StandardSave(state))
+            self.mngr.wait_until_finished()
 
     def _step_intact(self, step: int) -> bool:
         """Integrity probe of one step: every array/metadata file orbax

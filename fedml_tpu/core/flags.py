@@ -121,7 +121,7 @@ FLAGS: dict[str, FlagSpec] = _specs(
              "existed."),
     FlagSpec("aot_programs_dir", "str", None,
              "Program-store directory; derived: "
-             "<repo>/.jax_cache-<host>/aot_programs (core/cache.py's dir)."),
+             "<cache_dir>/aot_programs (core/cache.py's dir)."),
     # -- communication / transports ------------------------------------------
     FlagSpec("comm_compression", "str", None,
              "Upload codec for cross-silo model replies: qsgd8 | topk "

@@ -363,8 +363,7 @@ class SAAggregator(FedMLAggregator):
         if self._dp.mechanism == "gaussian":
             sigma = gaussian_sigma(self._dp.epsilon, self._dp.delta,
                                    self._dp.sensitivity)
-            noised = pallas_noise.apply_gaussian_noise(
-                flat, key, sigma, interpret=jax.default_backend() != "tpu")
+            noised = pallas_noise.apply_gaussian_noise(flat, key, sigma)
         else:
             noised = self._dp.add_global_noise(flat, key)
         return np.asarray(noised, np.float64)

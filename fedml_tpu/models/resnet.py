@@ -107,8 +107,7 @@ class FusedBasicBlock(nn.Module):
     executed by the fused Pallas kernel (``ops/pallas/fused_block.py``) —
     one VMEM-resident HBM pass each instead of XLA's separate loop fusions.
     Same parameter/state tree as ``BasicBlock`` (child modules carry the
-    auto-generated names of the unfused variant).  BatchNorm only; the
-    GroupNorm escape hatch keeps the unfused block."""
+    auto-generated names of the unfused variant).  BatchNorm only."""
 
     filters: int
     stride: int = 1
@@ -134,7 +133,8 @@ class CifarResNet(nn.Module):
 
     ``fused=True`` (the ``hp/extra.fused_blocks`` recipe flag) routes every
     conv epilogue — stem BN+ReLU and both BasicBlock epilogues — through the
-    fused Pallas kernel; BatchNorm only.  The variable tree is identical to
+    fused Pallas kernel; BatchNorm only (any other ``norm`` raises).  The
+    variable tree is identical to
     the unfused model (explicit child names), so the two are checkpoint- and
     aggregation-compatible.  The default (``fused=False``) path is untouched.
     """
@@ -147,7 +147,11 @@ class CifarResNet(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = True):
-        fused = self.fused and self.norm == "batch"
+        fused = self.fused
+        if fused and self.norm != "batch":
+            raise ValueError(
+                f"fused=True folds BatchNorm statistics into the kernel's "
+                f"affine; norm={self.norm!r} has no fused path")
         x = x.astype(self.dtype)
         x = nn.Conv(16, (3, 3), padding="SAME", use_bias=False, dtype=self.dtype)(x)
         if fused:

@@ -43,6 +43,7 @@ import time
 from typing import Any, Optional
 
 from ..core.flags import cfg_extra
+from ..ops import flops as flopslib
 from . import registry as obsreg
 
 log = logging.getLogger("fedml_tpu.obs.profiler")
@@ -366,6 +367,8 @@ def profiler_from_config(cfg, *, name: str = "sim",
         return None
     out_dir = cfg_extra(cfg, "profile_dir") or os.path.join(
         os.getcwd(), "profile_traces")
+    if peak_flops is None:
+        peak_flops = flopslib.local_peak_flops()
     try:
         return ProgramTimeAttributor(str(out_dir), window=window, name=name,
                                      peak_flops=peak_flops)

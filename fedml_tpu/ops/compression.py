@@ -59,11 +59,11 @@ def qsgd(vec: jax.Array, key: jax.Array, levels: int = 256) -> jax.Array:
     return jnp.sign(vec) * q * norm / levels
 
 
-def qsgd_int8_fused(vec: jax.Array, key: jax.Array, interpret: bool = False) -> jax.Array:
+def qsgd_int8_fused(vec: jax.Array, key: jax.Array, interpret=None) -> jax.Array:
     """Block-scaled stochastic int8 quantize+dequantize via the Pallas TPU
     kernel (``ops/pallas/quantize.py``) — the fused fast path for the QSGD
     semantics (one HBM read + int8 write instead of materialized f32
-    intermediates).  ``interpret=True`` for CPU/CI."""
+    intermediates).  ``interpret``: see ``ops/pallas/backend.py``."""
     from .pallas import qsgd_int8
 
     return qsgd_int8(vec, key, interpret=interpret)
@@ -86,8 +86,5 @@ def compress(name: str, vec: jax.Array, *, key: Optional[jax.Array] = None,
     if name == "qsgd":
         return qsgd(vec, key, 2 ** quantize_level), residual
     if name == "qsgd_int8":
-        import jax as _jax
-
-        # the pallas interpreter is required off-TPU (CPU CI)
-        return qsgd_int8_fused(vec, key, interpret=_jax.default_backend() != "tpu"), residual
+        return qsgd_int8_fused(vec, key), residual
     raise ValueError(f"unknown compression {name!r}")

@@ -25,7 +25,8 @@ width, a flat element's channel is ``lane % C`` — so the per-channel affine
 rides a single (1, 1, 128) lane vector (``scale`` tiled 128/C times) and the
 backward pass accumulates d(scale)/d(shift) into one (1, 16, 128) VMEM tile
 across grid steps, folded to (C,) outside the kernel.  Channels that do not
-divide 128 fall back to the pure-jnp reference (same math, XLA-fused).
+divide 128 are refused (``ValueError``) — never silently computed by the
+reference.
 
 The backward pass is also a single fused traversal.  The ReLU mask is not
 stored separately: the forward *output* is saved (XLA aliases it — it is the
@@ -35,8 +36,8 @@ convention (zero at the kink).
 
 ``interpret=True`` runs the identical kernels through the Pallas interpreter
 for CPU CI; when ``interpret`` is not given, it is derived from the active
-backend (compiled on TPU, interpreted elsewhere), matching
-``ops/compression.qsgd_int8_fused``.  Parity oracle: ``fused_block_reference``
+backend (``backend.resolve_interpret``: compiled on TPU, interpreted
+elsewhere), like every kernel in this package.  Parity oracle: ``fused_block_reference``
 — jitted kernel vs jitted reference is f32-bitwise (the parity tests in
 ``tests/test_pallas.py`` assert it).
 """
@@ -49,14 +50,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import resolve_interpret
 from .timing import observe_eager
 
 _SUB, _LANE = 16, 128  # sublane x lane block; 16 covers the bf16 min tile
 _BLOCK = _SUB * _LANE
 
 
-def _supported(channels: int) -> bool:
-    return channels <= _LANE and _LANE % channels == 0
+def _check_channels(channels: int) -> None:
+    """The lane-vector layout needs C | 128 (module docstring).  Anything
+    else is refused: quietly computing the reference instead would let a
+    run report the fused path while executing none of it."""
+    if channels > _LANE or _LANE % channels:
+        raise ValueError(
+            f"fused epilogue needs a channel count that divides {_LANE} "
+            f"(CIFAR-ResNet 16/32/64); got {channels} — use the unfused block "
+            "or fused_block_reference")
 
 
 def _to_blocks(a: jax.Array):
@@ -121,11 +130,13 @@ def _fwd_call(y, scale, shift, residual, interpret: bool):
 # Accumulator outputs map every grid step onto the SAME (1, 16, 128) tile
 # (TPU grids run sequentially; step 0 zero-initializes).  Padded tail
 # elements contribute nothing: the cotangent g is zero-padded, so
-# g * mask * (...) vanishes there.
+# g * mask * (...) vanishes there.  The ReLU mask compares in f32: the v5e
+# vector unit has no bf16 compare (Mosaic: "Target does not support this
+# comparison" on arith.cmpf over bf16), and the upcast is exact.
 
 def _bwd_res_kernel(g_ref, y_ref, s_ref, out_ref, dy_ref, dr_ref, ds_ref, db_ref):
     g = g_ref[...].astype(jnp.float32)
-    mask = (out_ref[...] > 0).astype(jnp.float32)
+    mask = (out_ref[...].astype(jnp.float32) > 0).astype(jnp.float32)
     gm = g * mask
     dy_ref[...] = (gm * s_ref[...]).astype(dy_ref.dtype)
     dr_ref[...] = gm.astype(dr_ref.dtype)
@@ -141,7 +152,7 @@ def _bwd_res_kernel(g_ref, y_ref, s_ref, out_ref, dy_ref, dr_ref, ds_ref, db_ref
 
 def _bwd_kernel(g_ref, y_ref, s_ref, out_ref, dy_ref, ds_ref, db_ref):
     g = g_ref[...].astype(jnp.float32)
-    mask = (out_ref[...] > 0).astype(jnp.float32)
+    mask = (out_ref[...].astype(jnp.float32) > 0).astype(jnp.float32)
     gm = g * mask
     dy_ref[...] = (gm * s_ref[...]).astype(dy_ref.dtype)
 
@@ -254,20 +265,13 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 # -- public API --------------------------------------------------------------
 
-def _resolve_interpret(interpret) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return bool(interpret)
-
-
 def fused_bn_relu(y: jax.Array, scale: jax.Array, shift: jax.Array,
                   *, interpret=None) -> jax.Array:
     """``relu(y * scale + shift)`` with per-channel (last-axis) affine, as one
     fused VMEM-resident pass; differentiable (fused backward)."""
-    if not _supported(y.shape[-1]):
-        return fused_block_reference(y, scale, shift)
+    _check_channels(y.shape[-1])
     return observe_eager(
-        "fused_bn_relu", partial(_fused, _resolve_interpret(interpret)),
+        "fused_bn_relu", partial(_fused, resolve_interpret(interpret)),
         y, scale, shift,
     )
 
@@ -276,10 +280,9 @@ def fused_bn_residual_relu(y: jax.Array, scale: jax.Array, shift: jax.Array,
                            residual: jax.Array, *, interpret=None) -> jax.Array:
     """``relu(y * scale + shift + residual)`` — the full BasicBlock epilogue
     (BN apply, shortcut add, activation) as one fused pass; differentiable."""
-    if not _supported(y.shape[-1]):
-        return fused_block_reference(y, scale, shift, residual)
+    _check_channels(y.shape[-1])
     return observe_eager(
-        "fused_bn_residual_relu", partial(_fused_res, _resolve_interpret(interpret)),
+        "fused_bn_residual_relu", partial(_fused_res, resolve_interpret(interpret)),
         y, scale, shift, residual,
     )
 

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import resolve_interpret
 from .timing import observe_eager
 
 _SUB, _LANE = 8, 128  # f32 min tile
@@ -44,12 +45,13 @@ def _pad_blocks(vec: jax.Array):
 
 
 def apply_gaussian_noise(vec: jax.Array, key: jax.Array, sigma: float,
-                         interpret: bool = False) -> jax.Array:
+                         interpret=None) -> jax.Array:
     """flat f32 vector + N(0, sigma^2) noise in one fused VMEM pass.
     ``interpret=True`` runs the same kernel through the pallas interpreter
-    (CPU CI)."""
+    (CPU CI); ``None`` derives it from the backend."""
     return observe_eager(
-        "apply_gaussian_noise", partial(_noise_impl, interpret=interpret),
+        "apply_gaussian_noise",
+        partial(_noise_impl, interpret=resolve_interpret(interpret)),
         vec, key, jnp.float32(sigma),
     )
 

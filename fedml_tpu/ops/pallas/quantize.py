@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import resolve_interpret
 from .timing import observe_eager
 
 _SUB, _LANE = 8, 128  # f32 min tile
@@ -58,12 +59,13 @@ def _pad_blocks(vec: jax.Array):
     return x, n
 
 
-def quantize_int8_stochastic(vec: jax.Array, key: jax.Array, interpret: bool = False):
+def quantize_int8_stochastic(vec: jax.Array, key: jax.Array, interpret=None):
     """flat f32 vector -> (int8 values (blocks, 8, 128), f32 scales (blocks,),
     original length).  ``interpret=True`` runs the same kernel through the
-    pallas interpreter (CPU CI)."""
+    pallas interpreter (CPU CI); ``None`` derives it from the backend."""
     return observe_eager(
-        "quantize_int8_stochastic", partial(_quantize_impl, interpret=interpret),
+        "quantize_int8_stochastic",
+        partial(_quantize_impl, interpret=resolve_interpret(interpret)),
         vec, key,
     )
 
@@ -93,10 +95,11 @@ def _quantize_impl(vec: jax.Array, key: jax.Array, *, interpret: bool):
 
 
 def dequantize_int8(values: jax.Array, scales: jax.Array, length: int,
-                    interpret: bool = False) -> jax.Array:
+                    interpret=None) -> jax.Array:
     return observe_eager(
         "dequantize_int8",
-        partial(_dequantize_impl, length=length, interpret=interpret),
+        partial(_dequantize_impl, length=length,
+                interpret=resolve_interpret(interpret)),
         values, scales,
     )
 
@@ -129,7 +132,7 @@ def quantize_int8_reference(vec: jax.Array, key: jax.Array):
     return q, scale[:, 0, 0], n
 
 
-def qsgd_int8(vec: jax.Array, key: jax.Array, interpret: bool = False) -> jax.Array:
+def qsgd_int8(vec: jax.Array, key: jax.Array, interpret=None) -> jax.Array:
     """Quantize + dequantize round trip — the simulation-path compressor
     (dense-in/dense-out like ops/compression.qsgd, but int8 block-scaled and
     kernel-fused)."""

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..parallel.mesh import SHARD_MAP_UNCHECKED, shard_map
-
 NEG_INF = -1e30
 
 
@@ -135,7 +133,7 @@ def ring_attention(
         out = o / jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
         return out.astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        **SHARD_MAP_UNCHECKED,
+        check_vma=False,
     )(q, k, v)

@@ -28,19 +28,6 @@ AXIS_SILO = "silo"
 AXIS_MODEL = "model"  # tensor-parallel axis (beyond reference parity)
 AXIS_SEQ = "seq"  # context/sequence-parallel axis (ring attention)
 
-# shard_map moved to the jax top level (with check_vma) in newer jax; 0.4.x
-# has it under experimental (with check_rep).  One shim so every shard_map
-# call site works on both — pass **SHARD_MAP_UNCHECKED to skip the
-# replication check.
-try:
-    from jax import shard_map  # noqa: F401  (jax >= 0.6)
-
-    SHARD_MAP_UNCHECKED = {"check_vma": False}
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-    SHARD_MAP_UNCHECKED = {"check_rep": False}
-
 
 def make_mesh(
     axis_names: Sequence[str] = (AXIS_CLIENTS,),
@@ -63,6 +50,14 @@ def make_mesh(
     total = int(np.prod(sizes))
     if total > n:
         raise ValueError(f"mesh {dict(zip(axis_names, sizes))} needs {total} devices, have {n}")
+    if total < n and devices is None:
+        # an explicit device list is a deliberate carve (submeshes, tests);
+        # a shape that leaves part of the default fleet idle is worth a word
+        import logging
+
+        logging.getLogger("fedml_tpu.parallel.mesh").warning(
+            "mesh %s uses %d of %d visible devices; the other %d stay idle",
+            dict(zip(axis_names, sizes)), total, n, n - total)
     dev_array = np.array(devs[:total]).reshape(sizes)
     return Mesh(dev_array, tuple(axis_names))
 

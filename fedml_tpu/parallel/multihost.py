@@ -32,22 +32,9 @@ import numpy as np
 
 log = logging.getLogger("fedml_tpu.parallel.multihost")
 
-_initialized = False
-
 
 def is_multiprocess() -> bool:
     return jax.process_count() > 1
-
-
-def _externally_initialized() -> bool:
-    """True when jax.distributed was already initialized by someone else
-    (standard multi-host launchers call it before user code)."""
-    try:
-        from jax._src import distributed as _dist
-
-        return getattr(_dist.global_state, "client", None) is not None
-    except Exception:
-        return False
 
 
 def ensure_initialized(cfg=None) -> bool:
@@ -56,8 +43,10 @@ def ensure_initialized(cfg=None) -> bool:
     Returns True when running multi-process after the call.  Safe to call
     multiple times and from single-process runs (no-ops).
     """
-    global _initialized
-    if _initialized:
+    if jax.distributed.is_initialized():
+        # by an earlier call, or by the launcher / user script (standard
+        # multi-host launchers call it before user code) — adopt it rather
+        # than crash on a second initialize
         return jax.process_count() > 1
     from ..core.flags import cfg_extra
 
@@ -67,14 +56,8 @@ def ensure_initialized(cfg=None) -> bool:
         or os.environ.get("COORDINATOR_ADDRESS")
     )
     if not coord:
-        # single-process (or externally-initialized) run; jax.process_count
-        # may initialize the backend, which is fine at this point
-        return jax.process_count() > 1
-    if _externally_initialized():
-        # the launcher (or user script) already called
-        # jax.distributed.initialize — adopt it rather than crash on a
-        # second initialize
-        _initialized = True
+        # single-process run; jax.process_count may initialize the backend,
+        # which is fine at this point
         return jax.process_count() > 1
     nproc = int(cfg_extra(cfg, "num_processes") or os.environ.get("JAX_NUM_PROCESSES") or 0)
     pid = cfg_extra(cfg, "process_id", os.environ.get("JAX_PROCESS_ID"))
@@ -84,7 +67,6 @@ def ensure_initialized(cfg=None) -> bool:
     if pid is not None:
         kwargs["process_id"] = int(pid)
     jax.distributed.initialize(**kwargs)
-    _initialized = True
     log.info(
         "jax.distributed up: process %d/%d, %d global devices (%d local)",
         jax.process_index(), jax.process_count(), len(jax.devices()), len(jax.local_devices()),

@@ -138,8 +138,6 @@ class DecentralizedSimulator:
         ``decentralized_framework/algorithm_api.py``)."""
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.mesh import SHARD_MAP_UNCHECKED, shard_map
-
         axis = self._gossip_axis()
         d = self.mesh.shape[axis]
         if n % d:
@@ -167,9 +165,9 @@ class DecentralizedSimulator:
             return jax.tree_util.tree_map(leaf_mix, block)
 
         spec = P(axis)
-        return shard_map(
+        return jax.shard_map(
             local_mix, mesh=self.mesh, in_specs=(spec,), out_specs=spec,
-            **SHARD_MAP_UNCHECKED,
+            check_vma=False,
         )
 
     def _make_round_fn(self):

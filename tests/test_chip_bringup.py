@@ -1,0 +1,114 @@
+"""Bring-up invariants (ISSUE 21): where the compile cache lives, the one
+peak-FLOP/s table, and ``chip_smoke.py``'s refusal to run without a TPU."""
+
+import json
+import os
+import subprocess
+import sys
+import types
+
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_cache_dir_left_alone_when_env_places_it(monkeypatch, tmp_path):
+    import jax
+
+    from fedml_tpu.core import cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(cache.ENV_VAR, str(tmp_path))
+    assert cache.setup_persistent_cache() == str(tmp_path)
+    # JAX reads the variable itself; the program sets no directory in code
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_cache_dir_fixed_in_checkout_when_env_unset(monkeypatch):
+    import jax
+
+    from fedml_tpu.core import cache
+
+    monkeypatch.delenv(cache.ENV_VAR, raising=False)
+    want = os.path.join(_REPO, ".jax_cache")
+    assert cache.setup_persistent_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    # the same path from any process on any host: nothing about the machine
+    # goes into the name (two fresh interpreters; cache_dir() needs no jax)
+    env = {k: v for k, v in os.environ.items() if k != cache.ENV_VAR}
+    code = "from fedml_tpu.core.cache import cache_dir; print(cache_dir())"
+    seen = {subprocess.run([sys.executable, "-c", code], cwd=_REPO, env=env,
+                           capture_output=True, text=True, timeout=60,
+                           check=True).stdout.strip() for _ in range(2)}
+    assert seen == {want}
+
+
+def test_peak_table_known_unknown_and_cpu():
+    import jax
+
+    from fedml_tpu.ops import flops
+
+    def dev(platform, kind):
+        return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+    assert flops.device_peak_flops(dev("tpu", "TPU v5 lite")) == 197e12
+    assert flops.device_peak_flops(dev("tpu", "TPU v4")) == 275e12
+    assert flops.device_peak_flops(dev("tpu", "TPU v5")) == 459e12
+    assert flops.device_peak_flops(dev("tpu", "TPU v6 lite")) == 918e12
+    # a v5 kind the table does not know is an error — never the v5p's peak
+    with pytest.raises(ValueError, match="TPU v5 ultra"):
+        flops.device_peak_flops(dev("tpu", "TPU v5 ultra"))
+    assert flops.device_peak_flops(dev("cpu", "cpu")) is None
+    assert flops.device_peak_flops(jax.devices()[0]) is None
+    assert flops.local_peak_flops() is None
+
+
+def _smoke(*args, timeout):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)  # one device: the smoke sizes its own meshes
+    return subprocess.run([sys.executable, "chip_smoke.py", *args], cwd=_REPO,
+                          env=env, capture_output=True, text=True, timeout=timeout)
+
+
+def test_chip_smoke_refuses_without_a_tpu():
+    res = _smoke(timeout=30)
+    assert res.returncode != 0
+    assert "no TPU" in res.stderr
+    assert res.stdout.strip() == ""  # no leg ran, no result printed
+
+
+def test_chip_smoke_dry_run_is_explicit_and_labelled():
+    res = _smoke("--dry-run-cpu", timeout=600)
+    assert res.returncode == 0, res.stdout[-2000:] + res.stderr[-4000:]
+    last = json.loads(res.stdout.strip().splitlines()[-1])
+    assert last == {"ok": True, "dry_run": True,
+                    "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    for leg in ("A", "C", "B"):
+        assert f"dry_run leg {leg} PASSED" in res.stdout
+
+
+def test_fused_blocks_refused_on_a_multi_device_mesh_when_compiled(
+        eight_devices, make_tiny_config, monkeypatch):
+    """GSPMD cannot shard a compiled Mosaic kernel (seen on a four-chip v5e
+    host): where the kernels would compile, the engine refuses the fused
+    recipe on a multi-device mesh up front — and still accepts it where they
+    are interpreted (this suite) or on one device."""
+    import jax
+
+    from fedml_tpu.data import loader
+    from fedml_tpu.models import resnet
+    from fedml_tpu.parallel import mesh as meshlib
+    from fedml_tpu.sim import engine
+
+    cfg = make_tiny_config(
+        dataset="cifar10", model="resnet20", client_num_in_total=2,
+        client_num_per_round=2, batch_size=8, synthetic_train_size=16,
+        synthetic_test_size=32, frequency_of_the_test=0)
+    ds = loader.load(cfg)
+    model = resnet.CifarResNet(num_blocks=1, num_classes=ds.class_num, fused=True)
+    two = meshlib.make_mesh((meshlib.AXIS_CLIENTS,), (2,), jax.devices()[:2])
+    one = meshlib.make_mesh((meshlib.AXIS_CLIENTS,), (1,), jax.devices()[:1])
+    monkeypatch.setattr(engine, "resolve_interpret", lambda *a: False)
+    with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
+        engine.MeshSimulator(cfg, ds, model, mesh=two)
+    engine.MeshSimulator(cfg, ds, model, mesh=one)  # one device: accepted

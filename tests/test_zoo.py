@@ -18,10 +18,12 @@ def test_cnn_zoo_forward_and_grad(model_name, eight_devices):
     fedml_tpu.init(cfg)
     model = model_hub.create(cfg, 10)
     x = jax.random.normal(jax.random.PRNGKey(42), (2, 32, 32, 3), jnp.float32)
-    variables = model.init({"params": jax.random.PRNGKey(0)}, x, train=True)
-    # jit everything: un-jitted apply/grad compiles op-by-op (eager), which
-    # the persistent compilation cache cannot help with — the jitted programs
-    # cache across suite runs
+    # jit everything, init included: un-jitted init/apply/grad compiles
+    # op-by-op (eager), hundreds of sub-threshold programs the persistent
+    # compilation cache cannot help with — the jitted programs cache across
+    # suite runs
+    variables = jax.jit(
+        lambda: model.init({"params": jax.random.PRNGKey(0)}, x, train=True))()
     logits = jax.jit(lambda v, x: model.apply(v, x, train=False))(variables, x)
     assert logits.shape == (2, 10)
     assert jnp.isfinite(logits).all()
