@@ -26,41 +26,45 @@ def init(args: Optional[Config] = None, argv=None) -> Config:
     replaces worker processes); cross-silo rank/role come from the Config.
     """
     from .core import rng
+    from .obs import trace as obstrace
 
-    cfg = args if args is not None else add_args(argv)
-    rng.seed_everything(cfg.random_seed)
-    logging.basicConfig(
-        level=logging.INFO,
-        format="[fedml_tpu] %(asctime)s %(levelname)s %(message)s",
-    )
-    # every entry point compiles through the persistent cache, placed by the
-    # one rule in core/cache.py ($JAX_COMPILATION_CACHE_DIR, else a fixed
-    # path in the checkout)
-    from .core.cache import setup_persistent_cache
+    # THE listener behind fedml_xla_* (compiles, persistent-cache loads)
+    obstrace.install_xla_listener()
+    with obstrace.traced("entry.init"):
+        cfg = args if args is not None else add_args(argv)
+        rng.seed_everything(cfg.random_seed)
+        logging.basicConfig(
+            level=logging.INFO,
+            format="[fedml_tpu] %(asctime)s %(levelname)s %(message)s",
+        )
+        # every entry point compiles through the persistent cache, placed by the
+        # one rule in core/cache.py ($JAX_COMPILATION_CACHE_DIR, else a fixed
+        # path in the checkout)
+        from .core.cache import setup_persistent_cache
 
-    setup_persistent_cache()
-    # MULTIPROCESS/MPI backend: bring up jax.distributed before any backend
-    # use so the mesh spans all hosts (reference: MPI rank discovery in
-    # fedml.init; here the coordination service replaces mpi4py).
-    from .parallel import multihost
+        setup_persistent_cache()
+        # MULTIPROCESS/MPI backend: bring up jax.distributed before any backend
+        # use so the mesh spans all hosts (reference: MPI rank discovery in
+        # fedml.init; here the coordination service replaces mpi4py).
+        from .parallel import multihost
 
-    requested = getattr(cfg, "backend_sim", "") in (
-        "MULTIPROCESS", constants.SIMULATION_BACKEND_MPI,
-    )
-    from .core.flags import cfg_extra
+        requested = getattr(cfg, "backend_sim", "") in (
+            "MULTIPROCESS", constants.SIMULATION_BACKEND_MPI,
+        )
+        from .core.flags import cfg_extra
 
-    if requested or cfg_extra(cfg, "coordinator_address"):
-        up = multihost.ensure_initialized(cfg)
-        if requested and not up:
-            # an explicitly requested multi-process backend must never
-            # silently degrade to single-process (the other hosts would block
-            # forever in the coordination barrier)
-            raise ValueError(
-                "backend_sim=MULTIPROCESS requires coordinator config: set "
-                "cfg.extra coordinator_address/num_processes/process_id or "
-                "the JAX_COORDINATOR_ADDRESS/JAX_NUM_PROCESSES/JAX_PROCESS_ID "
-                "environment variables on every host"
-            )
+        if requested or cfg_extra(cfg, "coordinator_address"):
+            up = multihost.ensure_initialized(cfg)
+            if requested and not up:
+                # an explicitly requested multi-process backend must never
+                # silently degrade to single-process (the other hosts would block
+                # forever in the coordination barrier)
+                raise ValueError(
+                    "backend_sim=MULTIPROCESS requires coordinator config: set "
+                    "cfg.extra coordinator_address/num_processes/process_id or "
+                    "the JAX_COORDINATOR_ADDRESS/JAX_NUM_PROCESSES/JAX_PROCESS_ID "
+                    "environment variables on every host"
+                )
     return cfg
 
 

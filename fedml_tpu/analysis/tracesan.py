@@ -15,10 +15,12 @@ module turns jax's own instrumentation into a gate:
   (wire encode, checkpoint save, streamed fold ingest, round-boundary
   metric export) and counts each crossing per site, so the report shows
   exactly where the round loop touches the host and how often.
-- a ``jax.monitoring`` listener counts every real XLA backend compile and
-  attributes it to the first ``fedml_tpu`` frame on the calling stack;
-  compiles witnessed INSIDE a steady-state guard are recompile hazards
-  (the GL011 failure mode, observed rather than inferred).
+- every XLA program build (a backend compile or a load from the persistent
+  cache; the process's one ``jax.monitoring`` listener in ``obs/trace.py``
+  reports them here) is counted and attributed to the first ``fedml_tpu``
+  frame on the calling stack; builds witnessed INSIDE a steady-state guard
+  are recompile hazards (the GL011 failure mode, observed rather than
+  inferred).
 
 Gating is absolute: unless ``FEDML_TPU_TRACESAN=1`` is set,
 :func:`maybe_install_from_env` does nothing, :func:`round_guard` /
@@ -52,10 +54,6 @@ ENV_FLAG = "FEDML_TPU_TRACESAN"
 ENV_REPORT = "FEDML_TPU_TRACESAN_REPORT"
 ENV_WARMUP = "FEDML_TPU_TRACESAN_WARMUP"
 
-#: the jax.monitoring event key a real XLA backend compile emits (tracing a
-#: cache-hit program does NOT fire it — exactly the recompile signal we want)
-_COMPILE_KEY = "/jax/core/compile/backend_compile_duration"
-
 #: bound on stored per-event records so a pathological run cannot grow the
 #: report without bound (mirrors sanitizer._MAX_LONG_HOLDS)
 _MAX_EVENTS = 200
@@ -77,9 +75,6 @@ VIOLATIONS = REGISTRY.counter(
     labels=("kind",))
 
 _ACTIVE: "TraceSanitizer | None" = None
-#: jax.monitoring has no unregister API — register the dispatching listener
-#: once per process and route through whatever sanitizer is active
-_LISTENER_INSTALLED = False
 
 
 def _attribute_site(limit: int = 8) -> tuple[str, list[str]]:
@@ -89,7 +84,8 @@ def _attribute_site(limit: int = 8) -> tuple[str, list[str]]:
     site = "<outside-package>"
     for frame in reversed(frames):
         path = frame.filename.replace("\\", "/")
-        if "fedml_tpu/" in path and "analysis/tracesan" not in path:
+        if ("fedml_tpu/" in path and "analysis/tracesan" not in path
+                and "obs/trace.py" not in path):  # the listener's own frames
             parts = path.split("/")
             site = f"{'/'.join(parts[-2:])}:{frame.lineno}:{frame.name}"
             break
@@ -211,32 +207,30 @@ class TraceSanitizer:
             }
 
 
-def _dispatch_compile_event(key: str, duration_s: float, **kw) -> None:
+def _on_program_build(duration_s: float) -> None:
     san = _ACTIVE
-    if san is not None and key == _COMPILE_KEY:
+    if san is not None:
         san.on_compile(duration_s)
 
 
 def install(warmup_rounds: int | None = None) -> TraceSanitizer:
-    """Activate the sanitizer (imports jax; registers the process-wide
-    compile listener on first call).  Idempotent."""
-    global _ACTIVE, _LISTENER_INSTALLED
+    """Activate the sanitizer (imports jax; subscribes to the process's one
+    XLA listener, which ``obs/trace.py`` owns).  Idempotent."""
+    global _ACTIVE
     if _ACTIVE is not None:
         return _ACTIVE
     if warmup_rounds is None:
         warmup_rounds = int(os.environ.get(ENV_WARMUP, "1"))
-    if not _LISTENER_INSTALLED:
-        from jax import monitoring
+    from ..obs import trace as obstrace
 
-        monitoring.register_event_duration_secs_listener(_dispatch_compile_event)
-        _LISTENER_INSTALLED = True
+    obstrace.install_xla_listener(on_build=_on_program_build)
     _ACTIVE = TraceSanitizer(warmup_rounds=warmup_rounds)
     atexit.register(_dump_on_exit)
     return _ACTIVE
 
 
 def uninstall() -> None:
-    """Deactivate (the monitoring listener stays registered — jax has no
+    """Deactivate (the subscription to the XLA listener stays — jax has no
     unregister API — but dispatches to nothing)."""
     global _ACTIVE
     _ACTIVE = None

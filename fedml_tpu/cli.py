@@ -376,20 +376,12 @@ def cmd_obs(args) -> int:
             print(f"error: no readable timeline segments under {args.path}",
                   file=sys.stderr)
             return 1
-        profile = None
-        if args.profile:
-            if not Path(args.profile).exists():
-                print(f"error: no attribution json {args.profile}",
-                      file=sys.stderr)
-                return 2
-            with open(args.profile) as f:
-                profile = json.load(f)
         if args.html:
-            html_doc = obs_dash.render_dash_html(loaded, profile)
+            html_doc = obs_dash.render_dash_html(loaded)
             Path(args.html).write_text(html_doc)
             print(f"wrote {args.html} ({len(html_doc)} bytes)",
                   file=sys.stderr)
-        print(obs_dash.render_dash_text(loaded, profile))
+        print(obs_dash.render_dash_text(loaded))
         return 0
     if args.obs_cmd == "serve":
         from fedml_tpu.obs.registry import REGISTRY, MetricsHTTPServer
@@ -623,9 +615,6 @@ def main(argv=None) -> int:
                        help="timeline segment directory (extra.timeline_dir)")
     odash.add_argument("--html", default="",
                        help="also write a self-contained HTML dashboard here")
-    odash.add_argument("--profile", default="",
-                       help="profiler attribution JSON (obs/profiler.py) to "
-                            "render as an attribution table")
     p.set_defaults(fn=cmd_obs)
 
     p = sub.add_parser("lint", help="AST invariant checker (GL001-GL012) over fedml_tpu/")

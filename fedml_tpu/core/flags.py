@@ -390,15 +390,6 @@ FLAGS: dict[str, FlagSpec] = _specs(
              "the bound that keeps recorder memory constant under "
              "sustained sampling); segments flush every capacity/2 "
              "samples."),
-    FlagSpec("profile_rounds", "str", None,
-             "Profile window for per-program device-time attribution: 'n' "
-             "traces rounds 0..n-1, 'k:n' traces n rounds starting at k "
-             "(programmatic jax.profiler start/stop around the sim "
-             "engine's round chunks; unset = no tracing, bit-identical "
-             "default path)."),
-    FlagSpec("profile_dir", "str", None,
-             "Directory the profiler trace + attribution JSON land in; "
-             "derived: <cwd>/profile_traces."),
     # -- multi-host ----------------------------------------------------------
     FlagSpec("coordinator_address", "str", None,
              "jax.distributed coordinator host:port "

@@ -270,10 +270,11 @@ def make_eval_fn(model, hp: HParams, batch_size: int = 256):
                 seen + jnp.sum(mask),
             ), None
 
-        (loss_sum, correct, seen), _ = jax.lax.scan(
-            body, (jnp.float32(0), jnp.float32(0), jnp.float32(0)), jnp.arange(n_batches)
-        )
-        seen = jnp.maximum(seen, 1.0)
-        return {"test_loss": loss_sum / seen, "test_acc": correct / seen}
+        with jax.named_scope("fl.eval"):  # names the ops in a device profile
+            (loss_sum, correct, seen), _ = jax.lax.scan(
+                body, (jnp.float32(0), jnp.float32(0), jnp.float32(0)), jnp.arange(n_batches)
+            )
+            seen = jnp.maximum(seen, 1.0)
+            return {"test_loss": loss_sum / seen, "test_acc": correct / seen}
 
     return eval_fn

@@ -25,6 +25,7 @@ import optax
 from ..core import rng
 from ..models.transformer import Transformer, TransformerConfig
 from ..obs.metrics import MetricsLogger
+from ..obs.trace import XLA_COUNTERS, install_xla_listener, traced
 from ..parallel import mesh as meshlib, sharding
 
 
@@ -44,9 +45,11 @@ class LLMTrainArgs:
 
 
 class LLMTrainer:
+    @traced("llm.init")
     def __init__(self, cfg: TransformerConfig, args: LLMTrainArgs,
                  mesh=None, seq_axis: Optional[str] = None,
                  logger: Optional[MetricsLogger] = None):
+        install_xla_listener()  # this entry point skips fedml_tpu.init
         self.cfg = cfg
         self.args = args
         if mesh is None:
@@ -65,9 +68,10 @@ class LLMTrainer:
         def init_fn():
             return self.model.init({"params": k0}, sample)["params"]
 
-        self.params = jax.jit(
-            init_fn, out_shardings=self.param_shardings
-        )()
+        with traced("llm.init.params"):
+            self.params = jax.jit(
+                init_fn, out_shardings=self.param_shardings
+            )()
 
         schedule = optax.warmup_cosine_decay_schedule(
             0.0, args.learning_rate, args.warmup_steps, max(args.total_steps, args.warmup_steps + 1)
@@ -83,10 +87,11 @@ class LLMTrainer:
         # the param path ('...nu/layer_0/attn/wq/kernel'), so the same
         # path-regex rules shard them like their params; scalars (count)
         # fall through to the replicate-by-default rule.
-        opt_shardings = sharding.named_shardings(
-            jax.eval_shape(self.opt.init, self.params), mesh
-        )
-        self.opt_state = jax.jit(self.opt.init, out_shardings=opt_shardings)(self.params)
+        with traced("llm.init.opt"):
+            opt_shardings = sharding.named_shardings(
+                jax.eval_shape(self.opt.init, self.params), mesh
+            )
+            self.opt_state = jax.jit(self.opt.init, out_shardings=opt_shardings)(self.params)
         self.data_sharding = sharding.batch_sharding(mesh, seq_axis=self.seq_axis)
         self.step_idx = 0
         # Pin the step's output shardings to the input shardings: with
@@ -113,34 +118,50 @@ class LLMTrainer:
             return losses.mean()
 
         def train_step(params, opt_state, tokens, targets):
-            loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            # the scopes name each op's phase in a device profile (XProf)
+            with jax.named_scope("llm.fwd_bwd"):
+                loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
+            with jax.named_scope("llm.optimizer"):
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, {"loss": loss, "ppl": jnp.exp(loss)}
 
         return train_step
 
     def step(self, tokens: jax.Array, targets: jax.Array) -> dict:
-        tokens = jax.device_put(tokens, self.data_sharding)
-        targets = jax.device_put(targets, self.data_sharding)
-        self.params, self.opt_state, metrics = self._train_step(
-            self.params, self.opt_state, tokens, targets
-        )
+        with traced("llm.h2d"):
+            tokens = jax.device_put(tokens, self.data_sharding)
+            targets = jax.device_put(targets, self.data_sharding)
+        with traced("llm.dispatch"):
+            self.params, self.opt_state, metrics = self._train_step(
+                self.params, self.opt_state, tokens, targets
+            )
         self.step_idx += 1
-        return {k: float(v) for k, v in metrics.items()}
+        with traced("llm.sync"):
+            return {k: float(v) for k, v in metrics.items()}
 
     def fit(self, batch_iter, steps: Optional[int] = None) -> list[dict]:
+        """Spans (``obs/trace.py``; PERF.md names the metric each is for):
+        ``llm.fit`` holds, per step, ``llm.next_batch`` (the caller's
+        iterator), ``llm.step`` (what ``step_time_s`` times: ``llm.h2d``,
+        ``llm.dispatch``, ``llm.sync``) and ``llm.log``."""
         history = []
         steps = steps or self.args.total_steps
-        for i, (tokens, targets) in enumerate(batch_iter):
-            if i >= steps:
-                break
-            t0 = time.perf_counter()
-            m = self.step(tokens, targets)
-            m["step"] = self.step_idx
-            m["step_time_s"] = time.perf_counter() - t0
-            self.logger.log(m)
-            history.append(m)
+        batches = iter(batch_iter)
+        with traced("llm.fit", counters=XLA_COUNTERS):
+            for i in range(steps + 1):
+                with traced("llm.next_batch"):
+                    batch = next(batches, None)
+                if batch is None or i >= steps:
+                    break
+                with traced("llm.step", step=self.step_idx + 1):
+                    t0 = time.perf_counter()
+                    m = self.step(*batch)
+                    m["step"] = self.step_idx
+                    m["step_time_s"] = time.perf_counter() - t0
+                with traced("llm.log"):
+                    self.logger.log(m)
+                history.append(m)
         return history
 
     def n_params(self) -> int:

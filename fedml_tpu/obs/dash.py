@@ -16,9 +16,7 @@ cannot disagree:
 - **per-tenant rows** — every ``job=`` label value the ScopedRegistry
   stamped, with rounds and SLO breaches per tenant,
 - **SLO-breach markers** — sample pairs where any
-  ``fedml_slo_breaches_total`` series increased,
-- **profile attribution** — the compile/h2d/device-compute/host-gap
-  split and per-category rows from ``obs/profiler``'s JSON, when given.
+  ``fedml_slo_breaches_total`` series increased.
 """
 
 from __future__ import annotations
@@ -77,7 +75,7 @@ def _hist_count_rate(samples: Sequence[dict], key: str) -> Optional[float]:
     return (win[-1]["hists"][key]["count"] - win[0]["hists"][key]["count"]) / (t1 - t0)
 
 
-def dash_data(timeline: dict, profile: Optional[dict] = None) -> dict:
+def dash_data(timeline: dict) -> dict:
     """Every panel as plain data — the single computation both renderers
     (and tests) consume.  ``timeline`` is :func:`obs.timeline.load_timeline`
     output (or a live recorder's ``{"samples","rounds","buckets"}``)."""
@@ -152,7 +150,6 @@ def dash_data(timeline: dict, profile: Optional[dict] = None) -> dict:
         "convergence": {"curve": curve, "rounds_to_target": targets},
         "tenants": jobs,
         "slo_markers": markers,
-        "profile": profile,
     }
 
 
@@ -164,8 +161,8 @@ def _num(v, digits: int = 3) -> str:
     return "-" if v is None else f"{float(v):.{digits}f}"
 
 
-def render_dash_text(timeline: dict, profile: Optional[dict] = None) -> str:
-    d = dash_data(timeline, profile)
+def render_dash_text(timeline: dict) -> str:
+    d = dash_data(timeline)
     lines = ["== performance timeline =="]
     lines.append(f"samples: {d['n_samples']}  rounds: {d['n_rounds']}  "
                  f"span: {d['span_s']}s  skipped segments: "
@@ -197,19 +194,6 @@ def render_dash_text(timeline: dict, profile: Optional[dict] = None) -> str:
         lines.append(f"slo breaches ({len(d['slo_markers'])}):")
         for m in d["slo_markers"][:10]:
             lines.append(f"  +{m['inc']:g} {m['series']}")
-    p = d["profile"]
-    if p:
-        lines.append("")
-        lines.append("profile attribution:")
-        for k, v in sorted((p.get("buckets") or {}).items()):
-            lines.append(f"  {k:<18} {v:.4f}")
-        for label in ("mfu_cost_model", "mfu_trace", "sim_mfu_gauge"):
-            if p.get(label) is not None:
-                lines.append(f"  {label:<18} {p[label]:.4f}")
-        for row in (p.get("by_category") or [])[:8]:
-            lines.append(f"  {row['key']:<18} {row['ms']:>9.2f} ms  "
-                         f"{row['tflops']:>7.2f} TFLOP/s  "
-                         f"{row['gbps']:>7.1f} GB/s")
     return "\n".join(lines)
 
 
@@ -265,9 +249,9 @@ def _table(headers: Sequence[str], rows: Sequence[Sequence]) -> str:
     return f"<table><tr>{head}</tr>{body}</table>"
 
 
-def render_dash_html(timeline: dict, profile: Optional[dict] = None,
+def render_dash_html(timeline: dict,
                      title: str = "fedml-tpu performance timeline") -> str:
-    d = dash_data(timeline, profile)
+    d = dash_data(timeline)
     out = [f"<!doctype html><html><head><meta charset='utf-8'>"
            f"<title>{_html.escape(title)}</title><style>{_CSS}</style></head>"
            f"<body><h1>{_html.escape(title)}</h1>"]
@@ -303,24 +287,8 @@ def render_dash_html(timeline: dict, profile: Optional[dict] = None,
         out.append(_table(["ts", "series", "increase"], [
             [f"{m['ts']:.3f}", m["series"], f"{m['inc']:g}"]
             for m in d["slo_markers"]]))
-    p = d["profile"]
-    if p:
-        out.append("<h2>Profile attribution</h2>")
-        out.append(_table(["bucket", "seconds"], [
-            [k, f"{v:.4f}"] for k, v in sorted((p.get("buckets") or {}).items())]))
-        mfu_rows = [[label, f"{p[label]:.4f}"]
-                    for label in ("mfu_cost_model", "mfu_trace", "sim_mfu_gauge")
-                    if p.get(label) is not None]
-        if mfu_rows:
-            out.append(_table(["MFU cross-check", "value"], mfu_rows))
-        if p.get("by_category"):
-            out.append(_table(["hlo category", "ms", "n", "TFLOP/s", "GB/s"], [
-                [r["key"], r["ms"], r["n"], r["tflops"], r["gbps"]]
-                for r in p["by_category"]]))
     out.append("<details><summary>raw panel data</summary><pre>"
-               + _html.escape(json.dumps(
-                   {k: v for k, v in d.items() if k != "profile"},
-                   indent=1, default=str))
+               + _html.escape(json.dumps(d, indent=1, default=str))
                + "</pre></details>")
     out.append("</body></html>")
     return "".join(out)

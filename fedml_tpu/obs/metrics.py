@@ -1,10 +1,9 @@
-"""Metrics / events / spans with pluggable sinks.
+"""Metrics with pluggable sinks.
 
 TPU-native replacement for ``core/mlops`` (SURVEY.md §2.12/§5): the reference
-ships metrics over MQTT to a SaaS backend (``MLOpsMetrics``,
-``mlops_profiler_event.py:9``); here the same call shapes write to pluggable
-sinks — stdout, JSONL file, or an in-memory buffer (tests) — and spans use
-``jax.profiler`` trace annotations so they show up in TPU profiles.
+ships metrics over MQTT to a SaaS backend (``MLOpsMetrics``); here the same
+call shapes write to pluggable sinks — stdout, JSONL file, or an in-memory
+buffer (tests).  Spans are ``obs/trace.py``'s.
 """
 
 from __future__ import annotations
@@ -13,10 +12,7 @@ import json
 import logging
 import sys
 import time
-from contextlib import contextmanager
-from typing import Any, Optional
-
-import jax
+from typing import Optional
 
 log = logging.getLogger("fedml_tpu")
 
@@ -50,28 +46,3 @@ class MetricsLogger:
         if self._fh:
             self._fh.close()
             self._fh = None
-
-
-class EventTracer:
-    """Span events (``MLOpsProfilerEvent`` ``mlops_profiler_event.py:9``):
-    ``started/ended`` pairs, mirrored into jax.profiler TraceAnnotation so
-    spans land in XLA device profiles."""
-
-    def __init__(self, logger: Optional[MetricsLogger] = None):
-        self.logger = logger
-        self.events: list[dict] = []
-
-    def log_event_started(self, name: str, value: Any = None) -> None:
-        self.events.append({"event": name, "phase": "started", "value": value, "ts": time.time()})
-
-    def log_event_ended(self, name: str, value: Any = None) -> None:
-        self.events.append({"event": name, "phase": "ended", "value": value, "ts": time.time()})
-
-    @contextmanager
-    def span(self, name: str, value: Any = None):
-        self.log_event_started(name, value)
-        with jax.profiler.TraceAnnotation(name):
-            try:
-                yield
-            finally:
-                self.log_event_ended(name, value)

@@ -39,10 +39,10 @@ _REGISTERING_MODULES = (
     "fedml_tpu.obs.flight",
     "fedml_tpu.obs.health",
     "fedml_tpu.obs.otlp",
-    "fedml_tpu.obs.profiler",
     "fedml_tpu.obs.remote",
     "fedml_tpu.obs.slo",
     "fedml_tpu.obs.timeline",
+    "fedml_tpu.obs.trace",
     "fedml_tpu.ops.pallas.timing",
     "fedml_tpu.population.cohorts",
     "fedml_tpu.population.store",
@@ -73,7 +73,6 @@ _SECTIONS = {
     "otlp": "OTLP egress",
     "pallas": "Pallas kernels",
     "pop": "Population-scale store",
-    "profile": "Program-time attribution",
     "program": "Compiled-program cost model",
     "runtime": "Event-driven runtime",
     "serving": "Serving fleet",
@@ -81,6 +80,7 @@ _SECTIONS = {
     "slo": "SLO watchdog",
     "timeline": "Performance timeline",
     "tracesan": "Runtime trace sanitizer",
+    "xla": "XLA program builds (compiles and persistent-cache loads)",
 }
 
 

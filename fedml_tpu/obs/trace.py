@@ -14,27 +14,63 @@ context manager and mirrors every span into ``jax.profiler.TraceAnnotation``
 so the same names show up in XLA device profiles; the current span rides a
 ``contextvars.ContextVar`` so nested spans parent automatically, including
 under the comm receive loop's per-message ``activate`` window.
+
+Every finished span is also kept in a process-wide bounded ring
+(:func:`recent`), so a harness in the same process can read what the program
+measured without configuring a sink.  A span can note registry counters at
+its start and their growth at its end (``counters=``); the XLA compile and
+persistent-cache counters that the timed paths note are fed by the one
+``jax.monitoring`` listener this module installs
+(:func:`install_xla_listener`).
 """
 
 from __future__ import annotations
 
+import collections
 import contextvars
 import functools
-import secrets
+import os
+import random
+import threading
 import time
-from typing import Any, Callable, Optional, Union
+from typing import Any, Callable, Optional, Sequence, Union
 
 import jax
 
+from .registry import REGISTRY
+
 __all__ = [
     "Span", "traced", "activate", "current", "start_span",
-    "inject", "extract", "new_id",
+    "inject", "extract", "new_id", "recent", "clear_recent",
+    "install_xla_listener", "XLA_COUNTERS",
 ]
+
+#: finished spans, oldest first; a window of some thousand steps fits, and
+#: a server that runs for weeks holds no more than this
+RING_SIZE = 8192
+_ring: collections.deque = collections.deque(maxlen=RING_SIZE)
+
+
+def recent() -> list["Span"]:
+    """The finished spans still in the ring, in the order they ended (a
+    parent after its children)."""
+    return list(_ring.copy())  # deque.copy is one C call: no appender can cut in
+
+
+def clear_recent() -> None:
+    _ring.clear()
+
+
+#: ids come from a generator of this module's own, seeded from the OS once:
+#: no system call per span, and ``random.seed`` (``rng.seed_everything``
+#: gives every process of a run the same seed) cannot make two processes
+#: draw the same ids
+_ids = random.Random(os.urandom(16))
 
 
 def new_id() -> str:
-    """128-bit-ish random hex id (16 chars is plenty for run-local traces)."""
-    return secrets.token_hex(8)
+    """64 random bits as 16 hex chars (plenty for run-local traces)."""
+    return "%016x" % _ids.getrandbits(64)
 
 
 class Span:
@@ -59,7 +95,7 @@ class Span:
     def end(self) -> "Span":
         if self.end_mono is None:
             self.end_mono = time.monotonic()
-            self.end_wall = time.time()
+            self.end_wall = self.start_wall + (self.end_mono - self.start_mono)
         return self
 
     @property
@@ -124,15 +160,19 @@ class traced:
     """
 
     def __init__(self, name: str, parent: Any = None,
-                 sink: Optional[Callable[[dict], None]] = None, **attrs):
+                 sink: Optional[Callable[[dict], None]] = None,
+                 counters: Sequence[str] = (), **attrs):
         self.name = name
         self.parent = parent
         self.sink = sink
+        self.counters = counters
         self.attrs = attrs
         self.span: Optional[Span] = None
 
     def __enter__(self) -> Span:
         self.span = start_span(self.name, parent=self.parent, **self.attrs)
+        if self.counters:
+            self._noted = [(m, m.value()) for m in map(REGISTRY.get, self.counters)]
         self._token = _current.set(self.span)
         self._annotation = jax.profiler.TraceAnnotation(self.name)
         self._annotation.__enter__()
@@ -142,6 +182,11 @@ class traced:
         self._annotation.__exit__(exc_type, exc, tb)
         _current.reset(self._token)
         self.span.end()
+        if self.counters:
+            # unlabelled registry counters: [value on entering, growth inside]
+            self.span.attrs["counters"] = {
+                m.name: [v0, m.value() - v0] for m, v0 in self._noted}
+        _ring.append(self.span)
         if self.sink is not None:
             try:
                 self.sink(self.span.to_record())
@@ -152,7 +197,8 @@ class traced:
     def __call__(self, fn: Callable) -> Callable:
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            with traced(self.name, parent=self.parent, sink=self.sink, **self.attrs):
+            with traced(self.name, parent=self.parent, sink=self.sink,
+                        counters=self.counters, **self.attrs):
                 return fn(*args, **kwargs)
 
         return wrapper
@@ -199,3 +245,65 @@ def extract(msg) -> Optional[dict]:
     if isinstance(header, dict) and header.get("trace_id"):
         return header
     return None
+
+
+# -- XLA program builds: compiles and loads from the persistent cache ---------
+#: jax 0.9.0 (jax/_src/interpreters/pxla.py, jax/_src/compiler.py): the first
+#: duration is recorded around ``compile_or_get_cached``, so it fires for a
+#: backend compile AND for a load from the persistent cache; the second only
+#: for a load, and before the first.  A jit call that finds its program in
+#: memory fires neither.
+_BUILD_KEY = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_KEY = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+XLA_COMPILES = REGISTRY.counter(
+    "fedml_xla_compiles_total",
+    "XLA backend compiles (programs built by the compiler, not loaded from "
+    "the persistent cache) since the listener was installed.")
+XLA_COMPILE_SECONDS = REGISTRY.counter(
+    "fedml_xla_compile_seconds_total",
+    "Wall seconds inside those backend compiles.")
+XLA_CACHE_LOADS = REGISTRY.counter(
+    "fedml_xla_cache_loads_total",
+    "Programs taken from the persistent compilation cache.")
+XLA_CACHE_LOAD_SECONDS = REGISTRY.counter(
+    "fedml_xla_cache_load_seconds_total",
+    "Wall seconds retrieving and loading programs from the persistent cache.")
+#: what a top-level span of a timed path notes (``traced(counters=...)``)
+XLA_COUNTERS = (XLA_COMPILES.name, XLA_COMPILE_SECONDS.name,
+                XLA_CACHE_LOADS.name, XLA_CACHE_LOAD_SECONDS.name)
+
+_listener_lock = threading.Lock()
+_listener_installed = False
+_build_subscribers: list[Callable[[float], None]] = []
+_loading = threading.local()  # set between a cache load's two events
+
+
+def _on_duration(key: str, duration_s: float, **_kw) -> None:
+    if key == _CACHE_LOAD_KEY:
+        XLA_CACHE_LOADS.inc()
+        XLA_CACHE_LOAD_SECONDS.inc(max(duration_s, 0.0))
+        _loading.hit = True
+    elif key == _BUILD_KEY:
+        if getattr(_loading, "hit", False):
+            _loading.hit = False
+        else:
+            XLA_COMPILES.inc()
+            XLA_COMPILE_SECONDS.inc(max(duration_s, 0.0))
+        for fn in _build_subscribers:
+            fn(duration_s)
+
+
+def install_xla_listener(on_build: Optional[Callable[[float], None]] = None) -> None:
+    """Register THE process's ``jax.monitoring`` duration listener (jax has
+    no unregister, so there is one, installed once; both entry points,
+    ``fedml_tpu.init`` and ``LLMTrainer``, pass here).  ``on_build`` is
+    called with the duration of every program build, compiled or loaded
+    (``analysis/tracesan.py`` attributes them to round phases)."""
+    global _listener_installed
+    with _listener_lock:
+        if on_build is not None and on_build not in _build_subscribers:
+            _build_subscribers.append(on_build)
+        if not _listener_installed:
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            _listener_installed = True

@@ -56,20 +56,23 @@ class FedMLRunner:
         self.client_trainer = client_trainer
         self.server_aggregator = server_aggregator
         _check_unimplemented_flags(cfg)
-        if cfg.training_type == C.TRAINING_PLATFORM_SIMULATION:
-            self.runner = self._init_simulation_runner()
-        elif cfg.training_type == C.TRAINING_PLATFORM_CROSS_SILO:
-            self.runner = self._init_cross_silo_runner()
-        elif cfg.training_type == C.TRAINING_PLATFORM_CROSS_DEVICE:
-            self.runner = self._init_cross_device_runner()
-        elif cfg.training_type == C.TRAINING_PLATFORM_CROSS_CLOUD:
-            self.runner = self._init_cross_cloud_runner()
-        elif cfg.training_type == C.TRAINING_PLATFORM_SERVING:
-            self.runner = self._init_serving_runner()
-        elif cfg.training_type == C.TRAINING_PLATFORM_CENTRALIZED:
-            self.runner = self._init_centralized_runner()
-        else:
-            raise ValueError(f"unsupported training_type {cfg.training_type!r}")
+        from .obs.trace import traced  # imports jax, which importing this module does not
+
+        with traced("entry.runner", training_type=cfg.training_type):
+            if cfg.training_type == C.TRAINING_PLATFORM_SIMULATION:
+                self.runner = self._init_simulation_runner()
+            elif cfg.training_type == C.TRAINING_PLATFORM_CROSS_SILO:
+                self.runner = self._init_cross_silo_runner()
+            elif cfg.training_type == C.TRAINING_PLATFORM_CROSS_DEVICE:
+                self.runner = self._init_cross_device_runner()
+            elif cfg.training_type == C.TRAINING_PLATFORM_CROSS_CLOUD:
+                self.runner = self._init_cross_cloud_runner()
+            elif cfg.training_type == C.TRAINING_PLATFORM_SERVING:
+                self.runner = self._init_serving_runner()
+            elif cfg.training_type == C.TRAINING_PLATFORM_CENTRALIZED:
+                self.runner = self._init_centralized_runner()
+            else:
+                raise ValueError(f"unsupported training_type {cfg.training_type!r}")
 
     def _load_data_model(self):
         if self.dataset is None:

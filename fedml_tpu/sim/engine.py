@@ -48,7 +48,7 @@ from ..fl.local_sgd import make_eval_fn
 from ..parallel import mesh as meshlib
 from ..obs import otlp as obsotlp, registry as obsreg
 from ..obs.metrics import MetricsLogger
-from ..obs.trace import traced
+from ..obs.trace import XLA_COUNTERS, traced
 from ..ops import flops as flopslib
 from ..ops.pallas.backend import resolve_interpret
 
@@ -93,6 +93,18 @@ SIM_MFU = obsreg.REGISTRY.gauge(
     "reports achieved FLOP/s only).  extra.cost_model_gauges.",
 )
 
+SAMPLES = obsreg.REGISTRY.counter(
+    "fedml_sim_samples_total",
+    "Samples of local SGD in the scanned chunks: kind=real is the sampled "
+    "clients' own counts x epochs (summed on the device, where they are "
+    "sampled), kind=lane what the padded lanes compute (lanes x steps per "
+    "epoch x batch x epochs).  real/lane is the useful share of lane work.",
+    labels=("kind",),
+)
+#: the round program's extra stacked output that carries kind=real to the
+#: host; taken out before the per-round metric dicts are built
+REAL_COUNT_KEY = "_real_count"
+
 from ..core.checkpoint import RoundCheckpointMixin
 
 
@@ -105,6 +117,7 @@ class MeshSimulator(RoundCheckpointMixin):
     #: bit-identical to before the hook existed.
     round_gate = None
 
+    @traced("sim.init")
     def __init__(
         self,
         cfg: Config,
@@ -139,15 +152,10 @@ class MeshSimulator(RoundCheckpointMixin):
         # zero extra work on any hot path.
         self._cost_gauges = bool(cfg_extra(cfg, "cost_model_gauges"))
         self._chunk_flops: dict = {}
-        # per-program device-time attribution (ISSUE 18, obs/profiler.py):
-        # a programmatic trace window around rounds k..k+n behind
-        # extra.profile_rounds.  Flag unset -> None, no trace, no window.
-        from ..obs import profiler as obsprofiler
-
-        self.profiler = obsprofiler.profiler_from_config(cfg, name="sim")
 
         # ---- data: pad + stack, shard over the clients axis ----
-        stacked = stack_clients(dataset, multiple_of=cfg.batch_size)
+        with traced("sim.init.stack_clients"):
+            stacked = stack_clients(dataset, multiple_of=cfg.batch_size)
         self.capacity = stacked.capacity
         steps_per_epoch = max(1, math.ceil(self.capacity / cfg.batch_size))
         self.hp = hparams_from_config(cfg, steps_per_epoch=steps_per_epoch)
@@ -180,6 +188,9 @@ class MeshSimulator(RoundCheckpointMixin):
         # are never sampled (sampling stays over n_clients) and never
         # scattered to, so numerics are untouched.
         self._client_axis, self._lane_multiple = self._client_axis_info()
+        #: lanes a mesh round computes: the sampled clients padded to the mesh
+        self._lanes = meshlib.round_up(
+            min(cfg.client_num_per_round, dataset.n_clients), self._lane_multiple)
         self._n_real = dataset.n_clients
         self._n_pad = meshlib.round_up(self._n_real, self._lane_multiple)
         if self._n_pad > self._n_real:
@@ -188,7 +199,8 @@ class MeshSimulator(RoundCheckpointMixin):
                 y=meshlib.pad_leading_axis_np(stacked.y, self._n_pad),
                 counts=meshlib.pad_leading_axis_np(stacked.counts, self._n_pad),
             )
-        self._data = self._place_data(stacked)
+        with traced("sim.init.place_data"):
+            self._data = self._place_data(stacked)
         # replicate ONCE at init: a bare jnp.asarray stays single-device and
         # every mesh dispatch would re-reshard it device-to-device per call
         # (witnessed by TRACESAN's round guard)
@@ -199,11 +211,12 @@ class MeshSimulator(RoundCheckpointMixin):
         # ---- model/state init ----
         k0 = rng.root_key(cfg.random_seed)
         sample_x = jnp.asarray(stacked.x[0, : cfg.batch_size])
-        self.global_vars = self.model.init(
-            {"params": jax.random.fold_in(k0, 1), "dropout": jax.random.fold_in(k0, 2)},
-            sample_x, train=True,
-        )
-        self.global_vars = meshlib.replicate(jax.device_get(self.global_vars), self.mesh)
+        with traced("sim.init.model_init"):
+            self.global_vars = self.model.init(
+                {"params": jax.random.fold_in(k0, 1), "dropout": jax.random.fold_in(k0, 2)},
+                sample_x, train=True,
+            )
+            self.global_vars = meshlib.replicate(jax.device_get(self.global_vars), self.mesh)
         self.server_state = self.algorithm.init_server_state(self.global_vars)
         cs_template = self.algorithm.init_client_state(self.global_vars)
         if cs_template is not None:
@@ -222,19 +235,20 @@ class MeshSimulator(RoundCheckpointMixin):
         # default device, every evaluate() would re-stage the test set from
         # chip 0 to the rest of the mesh
         test = (tx, ty, np.int32(n_test))
-        self._test = (tuple(jnp.asarray(t) for t in test)
-                      if self.backend == C.SIMULATION_BACKEND_SP
-                      else tuple(meshlib.replicate(test, self.mesh)))
-        self._eval_bs = eval_bs  # the padding multiple of self._test
-        eval_fn = make_eval_fn(model, self.hp, batch_size=eval_bs)
-        if self._aot is not None:
-            self._eval_fn = self._aot.cached_jit(
-                eval_fn, (self.global_vars, *self._test),
-                key=self._aot_key("sim.eval", trees={
-                    "global_vars": self.global_vars, "test": self._test}),
-            )
-        else:
-            self._eval_fn = jax.jit(eval_fn)
+        with traced("sim.init.eval_fn"):
+            self._test = (tuple(jnp.asarray(t) for t in test)
+                          if self.backend == C.SIMULATION_BACKEND_SP
+                          else tuple(meshlib.replicate(test, self.mesh)))
+            self._eval_bs = eval_bs  # the padding multiple of self._test
+            eval_fn = make_eval_fn(model, self.hp, batch_size=eval_bs)
+            if self._aot is not None:
+                self._eval_fn = self._aot.cached_jit(
+                    eval_fn, (self.global_vars, *self._test),
+                    key=self._aot_key("sim.eval", trees={
+                        "global_vars": self.global_vars, "test": self._test}),
+                )
+            else:
+                self._eval_fn = jax.jit(eval_fn)
 
         # OTLP egress (gated on extra.otlp_endpoint; None -> spans keep
         # their no-sink default and no exporter thread exists): the
@@ -351,43 +365,49 @@ class MeshSimulator(RoundCheckpointMixin):
         cfg = self.cfg
         n_total = self.dataset.n_clients
         m = min(cfg.client_num_per_round, n_total)
-
-        m_pad = meshlib.round_up(m, self._lane_multiple)
+        m_pad = self._lanes
 
         def round_fn(global_vars, server_state, client_states, counts, data_x, data_y, round_idx, key, prev_delta):
-            sampled = rng.sample_clients(key, round_idx, n_total, m)
-            xs, ys, cnts, cs, rkey, keys = self._gather_round_inputs(
-                sampled, m, m_pad, counts, data_x, data_y, client_states, key, round_idx
-            )
+            # the scopes name each op's phase in a device profile (XProf)
+            with jax.named_scope("fl.gather"):
+                sampled = rng.sample_clients(key, round_idx, n_total, m)
+                xs, ys, cnts, cs, rkey, keys = self._gather_round_inputs(
+                    sampled, m, m_pad, counts, data_x, data_y, client_states, key, round_idx
+                )
 
             def one_client(cstate, x, y, cnt, k):
                 out = algo.client_update(global_vars, cstate, server_state, x, y, cnt, k)
                 return out.contribution, out.client_state, out.metrics
 
-            if cs is not None:
-                contribs, new_cs, metrics = jax.vmap(one_client, in_axes=(0, 0, 0, 0, 0))(cs, xs, ys, cnts, keys)
-            else:
-                contribs, new_cs, metrics = jax.vmap(
-                    lambda x, y, cnt, k: one_client(None, x, y, cnt, k)
-                )(xs, ys, cnts, keys)
+            with jax.named_scope("fl.local_sgd"):
+                if cs is not None:
+                    contribs, new_cs, metrics = jax.vmap(one_client, in_axes=(0, 0, 0, 0, 0))(cs, xs, ys, cnts, keys)
+                else:
+                    contribs, new_cs, metrics = jax.vmap(
+                        lambda x, y, cnt, k: one_client(None, x, y, cnt, k)
+                    )(xs, ys, cnts, keys)
 
-            # drop the pad lanes: everything downstream (trust hooks,
-            # aggregation, scatter, metrics) sees exactly the real m clients
-            contribs = self._slice_lanes(contribs, m)
-            new_cs = self._slice_lanes(new_cs, m) if new_cs is not None else None
-            metrics = self._slice_lanes(metrics, m)
-            weights = cnts[:m].astype(jnp.float32)
-            new_global, new_server, new_delta = self._server_path(
-                contribs, weights, sampled, global_vars, server_state, rkey, round_idx, prev_delta
-            )
-
-            if client_states is not None:
-                new_states = jax.tree_util.tree_map(
-                    lambda full, upd: full.at[sampled].set(upd), client_states, new_cs
+            with jax.named_scope("fl.fold"):
+                # drop the pad lanes: everything downstream (trust hooks,
+                # aggregation, scatter, metrics) sees exactly the real m clients
+                contribs = self._slice_lanes(contribs, m)
+                new_cs = self._slice_lanes(new_cs, m) if new_cs is not None else None
+                metrics = self._slice_lanes(metrics, m)
+                weights = cnts[:m].astype(jnp.float32)
+                new_global, new_server, new_delta = self._server_path(
+                    contribs, weights, sampled, global_vars, server_state, rkey, round_idx, prev_delta
                 )
-            else:
-                new_states = None
-            round_metrics = {k: jnp.mean(v) for k, v in metrics.items()}
+
+                if client_states is not None:
+                    new_states = jax.tree_util.tree_map(
+                        lambda full, upd: full.at[sampled].set(upd), client_states, new_cs
+                    )
+                else:
+                    new_states = None
+                round_metrics = {k: jnp.mean(v) for k, v in metrics.items()}
+                # what this round really trained on, said by the program
+                # that sampled it (fedml_sim_samples_total{kind="real"})
+                round_metrics[REAL_COUNT_KEY] = jnp.sum(cnts[:m])
             return new_global, new_server, new_states, new_delta, round_metrics
 
         return round_fn
@@ -681,7 +701,8 @@ class MeshSimulator(RoundCheckpointMixin):
                 prog = self._aot.get_or_build(
                     self._aot_key("sim.multi_round",
                                   trees={"args": example_args},
-                                  extra={"chunk": n, "donate": list(donate)}),
+                                  extra={"chunk": n, "donate": list(donate),
+                                         "stacked": REAL_COUNT_KEY}),
                     lambda: aotlib.export_program(jax.jit(multi), example_args),
                 )
             # a failed compile propagates: swallowing it here used to defer
@@ -718,38 +739,56 @@ class MeshSimulator(RoundCheckpointMixin):
         The carried state is DONATED to the chunk (in-place HBM rewrite); if
         the chunk itself fails (OOM, device loss) the simulator's state
         buffers are gone — recover via ``try_resume`` from the last
-        checkpoint, not by retrying in-process."""
-        if n <= 0:
-            return []
-        if self._population is not None:
-            return self._run_population_rounds(n)
-        if self.backend == C.SIMULATION_BACKEND_SP:
-            out = []
-            for _ in range(n):
-                t0 = time.perf_counter()
-                out.append(self.run_round())
-                ROUND_TIME.observe(time.perf_counter() - t0)
-            return out
-        args = (
-            self.global_vars, self.server_state, self.client_states,
-            self.counts, self._data[0], self._data[1],
-            self._stage_scalar(jnp.int32(self.round_idx)), self.root_key,
-            self.defense_history,
-        )
-        fn = self._get_multi_round_fn(n, example_args=args)
-        if self.profiler is not None:
-            self.profiler.maybe_start(self.round_idx)
+        checkpoint, not by retrying in-process.
+
+        Spans (``obs/trace.py``; PERF.md names the metric each is for):
+        ``sim.run_rounds`` holds ``sim.stage`` (arguments, and the chunk
+        program's ``sim.chunk_compile`` on its first use) and ``sim.chunk``
+        (``sim.dispatch``, ``sim.metrics_sync``)."""
+        with traced("sim.run_rounds", counters=XLA_COUNTERS, rounds=n,
+                    start_round=self.round_idx) as span:
+            if n <= 0:
+                return []
+            if self._population is not None:
+                return self._run_population_rounds(n)
+            if self.backend == C.SIMULATION_BACKEND_SP:
+                out = []
+                for _ in range(n):
+                    t0 = time.perf_counter()
+                    out.append(self.run_round())
+                    ROUND_TIME.observe(time.perf_counter() - t0)
+                return out
+            return self._run_chunk(n, span)
+
+    def _count_samples(self, real_counts, rounds: int) -> tuple[int, int]:
+        """Feed ``fedml_sim_samples_total`` from the device's sum of the
+        sampled clients' counts over ``rounds`` rounds; returns (real, lane)."""
+        hp = self.hp
+        real = int(np.sum(real_counts)) * hp.epochs
+        lane = self._lanes * hp.steps_per_epoch * hp.batch_size * hp.epochs * rounds
+        SAMPLES.inc(real, kind="real")
+        SAMPLES.inc(lane, kind="lane")
+        return real, lane
+
+    def _run_chunk(self, n: int, span) -> list[dict]:
+        """The mesh path of ``run_rounds``; ``span`` is its ``sim.run_rounds``."""
+        with traced("sim.stage"):
+            args = (
+                self.global_vars, self.server_state, self.client_states,
+                self.counts, self._data[0], self._data[1],
+                self._stage_scalar(jnp.int32(self.round_idx)), self.root_key,
+                self.defense_history,
+            )
+            fn = self._get_multi_round_fn(n, example_args=args)
         t0 = time.perf_counter()
         try:
             with traced("sim.chunk", rounds=n, start_round=self.round_idx,
                         sink=self._otlp_sink):
-                with tracesan.round_guard(self.round_idx, rounds=n):
+                with traced("sim.dispatch"), tracesan.round_guard(self.round_idx, rounds=n):
                     gv, ss, cs, nd, stacked = fn(*args)
-                with tracesan.allow("round_metrics"):
+                with traced("sim.metrics_sync"), tracesan.allow("round_metrics"):
                     host = jax.device_get(stacked)  # graftlint: disable=GL010(annotated measurement site: THE single explicit host sync for the whole scanned chunk — n rounds of stacked metrics in one transfer)
         except Exception as e:
-            if self.profiler is not None:
-                self.profiler.finalize()  # keep the trace of the failing chunk
             raise RuntimeError(
                 f"scanned chunk of {n} rounds failed at round {self.round_idx}; "
                 "carried state was donated and is no longer valid — resume from "
@@ -757,10 +796,8 @@ class MeshSimulator(RoundCheckpointMixin):
             ) from e
         execute_s = time.perf_counter() - t0
         CHUNK_EXECUTE_TIME.observe(execute_s)
-        if self.profiler is not None:
-            self.profiler.note_program(f"sim.multi_round.{n}",
-                                       flops=self._chunk_flops.get(n), rounds=n)
-            self.profiler.maybe_stop(self.round_idx + n)
+        real, lane = self._count_samples(host.pop(REAL_COUNT_KEY), n)
+        span.attrs.update(real_samples=real, lane_samples=lane)
         if self._cost_gauges and self._chunk_flops.get(n):
             achieved = self._chunk_flops[n] / max(execute_s, 1e-9)
             ACHIEVED_FLOPS.set(achieved)
@@ -797,6 +834,8 @@ class MeshSimulator(RoundCheckpointMixin):
                 self.defense_history = nd
         self.round_idx += 1
         with tracesan.allow("round_metrics"):
+            if self.backend != C.SIMULATION_BACKEND_SP:
+                self._count_samples(metrics.pop(REAL_COUNT_KEY), 1)
             return {k: float(v) for k, v in metrics.items()}  # graftlint: disable=GL010(annotated measurement site: single-round entry point syncs its own metric dict — the chunked path run_rounds amortizes this to one sync per chunk)
 
     def _run_round_sp(self, r: int) -> dict:
@@ -841,9 +880,12 @@ class MeshSimulator(RoundCheckpointMixin):
     # ------------------------------------------------------------------
     def evaluate(self) -> dict:
         t0 = time.perf_counter()
-        with traced("sim.eval", round_idx=self.round_idx, sink=self._otlp_sink):
-            res = self._eval_fn(self.global_vars, *self._test)
-            out = {k: float(v) for k, v in res.items()}  # graftlint: disable=GL010(annotated measurement site: evaluation runs OFF the round loop at frequency_of_the_test cadence — its scalar sync never sits on the steady-state path)
+        with traced("sim.eval", counters=XLA_COUNTERS,
+                    round_idx=self.round_idx, sink=self._otlp_sink):
+            with traced("sim.eval.dispatch"):
+                res = self._eval_fn(self.global_vars, *self._test)
+            with traced("sim.eval.sync"):
+                out = {k: float(v) for k, v in res.items()}  # graftlint: disable=GL010(annotated measurement site: evaluation runs OFF the round loop at frequency_of_the_test cadence — its scalar sync never sits on the steady-state path)
         EVAL_TIME.observe(time.perf_counter() - t0)
         return out
 
@@ -941,10 +983,6 @@ class MeshSimulator(RoundCheckpointMixin):
             scores = self.assess_contribution()
             if scores is not None:
                 self.logger.log({f"contribution_c{i}": float(s) for i, s in enumerate(scores)})
-        if self.profiler is not None:
-            # a window still open at fit end (profile_rounds past comm_round)
-            # closes and attributes here rather than losing the trace
-            self.profiler.finalize()
         if self._otlp is not None:
             # end-of-fit egress: drain queued spans and ship the registry
             # snapshot; flush (not close) so a caller running fit again on

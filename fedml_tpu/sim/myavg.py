@@ -48,7 +48,7 @@ from .. import constants as C
 from ..core import pytree as pt, rng
 from ..fl.local_sgd import make_eval_fn
 from ..parallel import mesh as meshlib
-from .engine import MeshSimulator
+from .engine import REAL_COUNT_KEY, MeshSimulator
 
 
 class LayerFilter:
@@ -442,6 +442,7 @@ class MyAvgSimulator(MeshSimulator):
                 new_delta = new_flat - old_flat
             round_metrics = {k: jnp.mean(v) for k, v in metrics.items()}
             round_metrics["myavg_config_id"] = cid.astype(jnp.float32)
+            round_metrics[REAL_COUNT_KEY] = jnp.sum(cnts[:m])  # as the engine's round
             return new_global, server_state, new_states, new_delta, round_metrics
 
         return round_fn

@@ -35,7 +35,7 @@ INSTRUMENTED_MODULES = [
     "fedml_tpu.obs.flight",
     "fedml_tpu.obs.health",
     "fedml_tpu.obs.otlp",
-    "fedml_tpu.obs.profiler",
+    "fedml_tpu.obs.trace",
     "fedml_tpu.obs.remote",
     "fedml_tpu.obs.slo",
     "fedml_tpu.obs.timeline",
