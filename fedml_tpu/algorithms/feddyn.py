@@ -47,9 +47,9 @@ class FedDyn(FedAlgorithm):
     def make_ctx(self, global_variables, client_state, server_state):
         return (global_variables["params"], client_state)
 
-    def client_update(self, global_variables, client_state, server_state, x, y, count, key):
+    def client_update(self, global_variables, client_state, server_state, x, y, count, key, step_bound=None):
         ctx = self.make_ctx(global_variables, client_state, server_state)
-        new_vars, metrics = self._local_train(global_variables, x, y, count, key, ctx)
+        new_vars, metrics = self._local_train(global_variables, x, y, count, key, ctx, step_bound)
         g_params, _ = split_variables(global_variables)
         l_params, l_rest = split_variables(new_vars)
         alpha = self.hp.feddyn_alpha

@@ -22,20 +22,19 @@ import jax.numpy as jnp
 
 from ..core import pytree as pt
 from ..fl.algorithm import FedAlgorithm
-from ..fl.local_sgd import split_variables
+from ..fl.local_sgd import own_step_budget, split_variables
 from ..fl.types import ClientOutput
 
 
 class FedNova(FedAlgorithm):
     name = "FedNova"
 
-    def client_update(self, global_variables, client_state, server_state, x, y, count, key):
-        new_vars, metrics = self._local_train(global_variables, x, y, count, key, None)
+    def client_update(self, global_variables, client_state, server_state, x, y, count, key, step_bound=None):
+        new_vars, metrics = self._local_train(global_variables, x, y, count, key, None, step_bound)
         g_params, _ = split_variables(global_variables)
         l_params, l_rest = split_variables(new_vars)
-        bsz = self.hp.batch_size
         if self.hp.step_mode == "match":
-            tau = (self.hp.epochs * ((count + bsz - 1) // bsz)).astype(jnp.float32)
+            tau = own_step_budget(self.hp, count).astype(jnp.float32)
         else:
             tau = jnp.float32(self.hp.local_steps)
         rho = self.hp.momentum

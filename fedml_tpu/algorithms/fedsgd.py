@@ -22,6 +22,7 @@ from ..ops import compression as comp
 
 class FedSGD(FedAlgorithm):
     name = "FedSGD"
+    has_step_loop = False  # one full-shard gradient, no local steps
 
     def __init__(self, hp, cfg=None):
         super().__init__(hp, cfg)

@@ -45,9 +45,9 @@ class Mime(FedAlgorithm):
     def make_ctx(self, global_variables, client_state, server_state):
         return server_state
 
-    def client_update(self, global_variables, client_state, server_state, x, y, count, key):
+    def client_update(self, global_variables, client_state, server_state, x, y, count, key, step_bound=None):
         ctx = self.make_ctx(global_variables, client_state, server_state)
-        new_vars, metrics = self._local_train(global_variables, x, y, count, key, ctx)
+        new_vars, metrics = self._local_train(global_variables, x, y, count, key, ctx, step_bound)
         gkey = jax.random.fold_in(key, 0x6D696D65)
         fg = self._full_grad(global_variables, x, y, count, gkey)
         return ClientOutput(

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from ..core import pytree as pt
 from ..fl.algorithm import FedAlgorithm
-from ..fl.local_sgd import split_variables
+from ..fl.local_sgd import own_step_budget, split_variables
 from ..fl.types import ClientOutput
 
 
@@ -43,14 +43,13 @@ class Scaffold(FedAlgorithm):
     def make_ctx(self, global_variables, client_state, server_state):
         return (global_variables["params"], server_state, client_state)
 
-    def client_update(self, global_variables, client_state, server_state, x, y, count, key):
+    def client_update(self, global_variables, client_state, server_state, x, y, count, key, step_bound=None):
         ctx = self.make_ctx(global_variables, client_state, server_state)
-        new_vars, metrics = self._local_train(global_variables, x, y, count, key, ctx)
+        new_vars, metrics = self._local_train(global_variables, x, y, count, key, ctx, step_bound)
         g_params, _ = split_variables(global_variables)
         l_params, l_rest = split_variables(new_vars)
-        bsz = self.hp.batch_size
         if self.hp.step_mode == "match":
-            k_steps = self.hp.epochs * ((count + bsz - 1) // bsz)
+            k_steps = own_step_budget(self.hp, count)
         else:
             k_steps = jnp.int32(self.hp.local_steps)
         inv_klr = 1.0 / (k_steps.astype(jnp.float32) * self.hp.learning_rate)

@@ -34,6 +34,9 @@ from .types import ClientOutput, HParams
 
 class FedAlgorithm:
     name = "FedAvg"
+    #: ``client_update`` trains through ``local_train``'s step loop and hands it
+    #: ``step_bound``; the round engine's lane buckets are for such algorithms
+    has_step_loop = True
 
     def __init__(self, hp: HParams, cfg=None):
         self.hp = hp
@@ -66,9 +69,9 @@ class FedAlgorithm:
         """Context pytree passed to loss/grad hooks during local training."""
         return None
 
-    def client_update(self, global_variables, client_state, server_state, x, y, count, key) -> ClientOutput:
+    def client_update(self, global_variables, client_state, server_state, x, y, count, key, step_bound=None) -> ClientOutput:
         ctx = self.make_ctx(global_variables, client_state, server_state)
-        new_vars, metrics = self._local_train(global_variables, x, y, count, key, ctx)
+        new_vars, metrics = self._local_train(global_variables, x, y, count, key, ctx, step_bound)
         return ClientOutput(contribution=new_vars, client_state=client_state, metrics=metrics)
 
     # -- server side -----------------------------------------------------------

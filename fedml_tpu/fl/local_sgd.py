@@ -10,8 +10,17 @@ Ragged client shards (SURVEY.md §7 hard part 1) are handled by:
 - cyclic-padded shards (every slot is a real sample, see ``data.dataset``),
 - per-epoch permutations for shuffled epoch semantics,
 - ``step_mode="match"``: steps beyond a client's own budget
-  ``epochs * ceil(count/batch)`` are masked to no-ops, reproducing the
-  reference's per-client step counts while keeping shapes static.
+  ``epochs * ceil(count/batch)`` (``own_step_budget``) are masked to no-ops,
+  reproducing the reference's per-client step counts while keeping shapes
+  static.  A masked step is not free: it runs its whole forward, backward and
+  optimizer update and a ``where`` throws the result away.  With no
+  ``step_bound`` the scan runs all ``epochs * steps_per_epoch`` steps, so a
+  lane pays for the capacity of the largest client of the population.  With
+  ``step_bound`` (an unbatched int32 the caller computes: the round engine's
+  bucketed program sorts its lanes by budget and passes the longest budget
+  among the lanes of a bucket, ``sim/engine.py:_run_lane_buckets``) the loop
+  ends there, and only lanes shorter than their bucket's longest still
+  compute masked steps.
 
 Algorithm customisation is via two pure hooks (closed over at build time):
 ``loss_extra(params, global_params, ctx)`` (FedProx/FedDyn terms) and
@@ -29,6 +38,8 @@ donated — the fused path composes with ``jit(scan)`` + donation unchanged
 from __future__ import annotations
 
 from typing import Any, Callable, Optional
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +62,12 @@ def make_optimizer(hp: HParams) -> optax.GradientTransformation:
     raise ValueError(f"unknown client optimizer {hp.client_optimizer!r}")
 
 
+def own_step_budget(hp: HParams, count):
+    """A client's own step count under ``step_mode="match"`` (reference:
+    ``epochs * ceil(len(local) / batch)``)."""
+    return hp.epochs * ((count + hp.batch_size - 1) // hp.batch_size)
+
+
 def split_variables(variables: dict) -> tuple[Any, dict]:
     """Split flax variables into (params, rest-collections e.g. batch_stats)."""
     params = variables["params"]
@@ -65,10 +82,15 @@ def make_local_train_fn(
     grad_hook: Optional[Callable] = None,
     batch_constraint: Optional[Callable] = None,
 ):
-    """Build ``local_train(variables, x, y, count, key, ctx) -> (new_variables, metrics)``.
+    """Build ``local_train(variables, x, y, count, key, ctx, step_bound) -> (new_variables, metrics)``.
 
     ``ctx`` is an arbitrary pytree threaded to the hooks (global params,
     control variates, server momentum...).  All shapes static; jit/vmap-safe.
+
+    ``step_bound`` (``step_mode="match"`` only) ends the step loop after that
+    many steps instead of ``epochs * steps_per_epoch``: an int32 scalar that
+    must not be smaller than the client's own budget and, under ``vmap``, must
+    be unbatched (one trip count for all lanes).  ``None`` is the static scan.
 
     ``batch_constraint(bx, by) -> (bx, by)`` is applied to each step's
     gathered minibatch — the intra-silo data-parallel hook: constraining the
@@ -106,8 +128,14 @@ def make_local_train_fn(
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
-    def local_train(variables: dict, x: jax.Array, y: jax.Array, count: jax.Array, key: jax.Array, ctx=None):
+    def local_train(variables: dict, x: jax.Array, y: jax.Array, count: jax.Array, key: jax.Array,
+                    ctx=None, step_bound=None):
         params, rest = split_variables(variables)
+        if step_bound is not None and hp.step_mode != "match":
+            raise ValueError(
+                "step_bound cuts the step loop at the longest own budget of the "
+                f"lanes; step_mode={hp.step_mode!r} has no own budgets to cut at"
+            )
         if x.shape[0] < hp.batch_size:
             # the old per-epoch dynamic_slice rejected this at trace time
             # (slice size > dim); keep the refusal explicit
@@ -126,8 +154,7 @@ def make_local_train_fn(
         bsz = hp.batch_size
         spe = hp.steps_per_epoch
         total_steps = hp.epochs * spe
-        # per-client step budget (reference: epochs * ceil(len(local)/batch))
-        own_steps = hp.epochs * ((count + bsz - 1) // bsz)
+        own_steps = own_step_budget(hp, count)
 
         # Per-epoch permutations hoisted OUT of the step scan: the permutation
         # is constant within an epoch, but recomputing it per step costs a
@@ -141,7 +168,12 @@ def make_local_train_fn(
             )
         )(jnp.arange(hp.epochs)).reshape(-1)
 
-        def step(carry, s):
+        # what a step reads of its client, handed over and not closed over: the
+        # bounded loop below has to say which of it is a lane's own
+        env = (x, y, all_perms, own_steps, key, ctx)
+
+        def step(env, carry, s):
+            x, y, all_perms, own_steps, key, ctx = env
             params, rest, opt_state = carry
             epoch = s // spe
             step_in_epoch = s % spe
@@ -182,18 +214,64 @@ def make_local_train_fn(
                 active_f = jnp.float32(1.0)
             return (new_params, new_rest, new_opt), (loss, active_f)
 
-        (params, rest, _), (losses, actives) = jax.lax.scan(
-            step, (params, rest, opt_state), jnp.arange(total_steps)
-        )
-        n_active = jnp.maximum(jnp.sum(actives), 1.0)
+        if step_bound is None:
+            (params, rest, _), (losses, actives) = jax.lax.scan(
+                partial(step, env), (params, rest, opt_state), jnp.arange(total_steps)
+            )
+            n_active = jnp.maximum(jnp.sum(actives), 1.0)
+            loss_sum = jnp.sum(losses)
+        else:
+            # the same step, its loss and active count carried instead of
+            # stacked: the trip count is a value of the program, not a shape
+            def bounded(env, s, carry):
+                state, loss_sum, n_active = carry
+                state, (loss, active_f) = step(env, state, s)
+                return state, loss_sum + loss, n_active + active_f
+
+            (params, rest, _), loss_sum, n_active = _bounded_loop(
+                bounded, step_bound, env,
+                ((params, rest, opt_state), jnp.float32(0.0), jnp.float32(0.0)),
+            )
+            n_active = jnp.maximum(n_active, 1.0)
         metrics = {
-            "train_loss": jnp.sum(losses) / n_active,
+            "train_loss": loss_sum / n_active,
             "num_steps": n_active,
             "num_samples": count.astype(jnp.float32),
         }
         return {"params": params, **rest}, metrics
 
     return local_train
+
+
+def _bounded_loop(body, bound, env, init):
+    """``fori_loop(0, bound, lambda s, c: body(env, s, c), init)`` with a
+    ``vmap`` rule of its own: the loop stays where it is and runs the vmapped
+    body, ``bound`` being one trip count for all lanes.  jax's own rule for a
+    ``while`` gives the same program by re-interpreting the traced body once
+    for each guess at which carries are batched and once more for the result;
+    with a model's whole training step as the body that is most of the time
+    the round program takes to trace (PERF.md section 6, PR 27).  Here no
+    traced body is gone over again: the body is traced as it stands and, under
+    a ``vmap``, once more as a vmapped function."""
+
+    @jax.custom_batching.custom_vmap
+    def loop(bound, env, carry):
+        return jax.lax.fori_loop(0, bound, lambda s, c: body(env, s, c), carry)
+
+    @loop.def_vmap
+    def loop_over_lanes(axis_size, in_batched, bound, env, carry):
+        bound_batched, env_batched, carry_batched = in_batched
+        if bound_batched:
+            raise ValueError("step_bound must be one trip count for all lanes of a vmap")
+        # a carry that is not yet a lane's own becomes one inside the loop
+        carry = jax.tree_util.tree_map(
+            lambda a, b: a if b else jnp.broadcast_to(a, (axis_size,) + a.shape), carry, carry_batched)
+        lanes_body = jax.vmap(
+            body, in_axes=(jax.tree_util.tree_map(lambda b: 0 if b else None, env_batched), None, 0))
+        out = jax.lax.fori_loop(0, bound, lambda s, c: lanes_body(env, s, c), carry)
+        return out, jax.tree_util.tree_map(lambda _: True, carry_batched)
+
+    return loop(bound, env, init)
 
 
 def _select_tree(pred, on_true, on_false):

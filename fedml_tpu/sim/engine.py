@@ -20,6 +20,14 @@ data/state, so local SGD runs on every chip in parallel and the weighted mean
 lowers to one ICI all-reduce — the reference's whole process/message machinery
 (bullets P1-P3 of SURVEY.md §2.14) collapses into sharding annotations.
 
+A vmapped lane pays for every step of the loop it shares, masked or not
+(``fl/local_sgd.py``, "Ragged client shards").  So where ``step_mode="match"``
+gives the population's clients different step budgets, the round sorts its
+lanes by budget and runs them in buckets, one after another inside the same
+program, each with a step loop that ends at the bucket's own longest client
+(``_bucket_count``, ``_order_lanes``, ``_run_lane_buckets``).  Equal budgets
+build the one vmap.
+
 ``backend="sp"`` runs the same pure functions in a host loop over clients
 (one jitted client_update at a time) — the numerics-regression twin of the
 reference's single-process simulator; tests assert MESH == SP.
@@ -44,7 +52,7 @@ from ..arguments import Config
 from ..core import aot as aotlib, pytree as pt, rng
 from ..core.flags import cfg_extra
 from ..data.dataset import FederatedDataset, StackedClientData, pad_eval_set, stack_clients
-from ..fl.local_sgd import make_eval_fn
+from ..fl.local_sgd import make_eval_fn, own_step_budget
 from ..parallel import mesh as meshlib
 from ..obs import otlp as obsotlp, registry as obsreg
 from ..obs.metrics import MetricsLogger
@@ -97,13 +105,18 @@ SAMPLES = obsreg.REGISTRY.counter(
     "fedml_sim_samples_total",
     "Samples of local SGD in the scanned chunks: kind=real is the sampled "
     "clients' own counts x epochs (summed on the device, where they are "
-    "sampled), kind=lane what the padded lanes compute (lanes x steps per "
-    "epoch x batch x epochs).  real/lane is the useful share of lane work.",
+    "sampled), kind=lane what the lanes computed, masked steps and cyclic "
+    "padding included: lanes x steps x batch, with the steps each lane "
+    "bucket's loop really ran summed on the device (a program without "
+    "buckets runs epochs x steps per epoch in every lane).  real/lane is the "
+    "useful share of lane work.",
     labels=("kind",),
 )
-#: the round program's extra stacked output that carries kind=real to the
-#: host; taken out before the per-round metric dicts are built
+#: the round program's extra stacked outputs that carry kind=real, and the
+#: bucketed program's lane-steps for kind=lane, to the host; taken out before
+#: the per-round metric dicts are built
 REAL_COUNT_KEY = "_real_count"
+LANE_STEPS_KEY = "_lane_steps"
 
 from ..core.checkpoint import RoundCheckpointMixin
 
@@ -192,6 +205,7 @@ class MeshSimulator(RoundCheckpointMixin):
         self._lanes = meshlib.round_up(
             min(cfg.client_num_per_round, dataset.n_clients), self._lane_multiple)
         self._n_real = dataset.n_clients
+        self._lane_buckets = self._bucket_count(stacked.counts)
         self._n_pad = meshlib.round_up(self._n_real, self._lane_multiple)
         if self._n_pad > self._n_real:
             stacked = StackedClientData(
@@ -299,6 +313,72 @@ class MeshSimulator(RoundCheckpointMixin):
                 else self.mesh.axis_names[0])
         return axis, int(self.mesh.shape[axis])
 
+    def _bucket_count(self, counts) -> int:
+        """How many lane buckets the round program runs one after another;
+        1 is one ``vmap`` over all lanes, the program as it always was.
+
+        A lane pays for every step of the loop it is vmapped into, masked or
+        not.  Where ``step_mode="match"`` gives the population's clients
+        different step budgets, the round sorts its lanes by budget and runs
+        them in buckets whose loop ends at the bucket's own longest client
+        (``_make_round_fn``).  Nothing to gain, so nothing built, where the
+        budgets are equal, steps are not masked, or the algorithm has no step
+        loop (``FedAlgorithm.has_step_loop``).
+
+        A bucket is twice as many lanes wide as an epoch has steps (the next
+        width that divides the lanes and fills every chip of the clients axis
+        alike).  Along the sorted lanes the budget changes at most
+        ``steps_per_epoch - 1`` times, so that many buckets at most hold lanes
+        shorter than their longest (in ``fedavg_r20.cross_device`` 48% of the
+        lanes' steps were masked, 8% still are).  Every bucket is a loop
+        to start, so narrower ones are not free: a model whose step is bound
+        by the launch of its ops pays about 35 us a bucket, and buckets as
+        wide as ONE epoch made ``fedavg_r20.cross_device`` issue more device
+        ops in its 20 s window than a profile keeps (PERF.md section 6,
+        PR 27, has both readings)."""
+        hp = self.hp
+        if (self.backend == C.SIMULATION_BACKEND_SP or hp.step_mode != "match"
+                or not self.algorithm.has_step_loop):
+            return 1
+        budgets = own_step_budget(hp, np.asarray(counts[: self.dataset.n_clients]))
+        if budgets.min() == budgets.max():
+            return 1
+        per_chip = self._lanes // self._lane_multiple
+        wide = next(w for w in range(1, per_chip + 1)
+                    if per_chip % w == 0 and w * self._lane_multiple >= min(2 * hp.steps_per_epoch, self._lanes))
+        return per_chip // wide
+
+    def _order_lanes(self, lanes, counts):
+        """The bucketed round's own step before the shared gather: the lane
+        ids by step budget, longest first (stable, so equal budgets keep the
+        sampled order), so that a bucket's lanes have like budgets, and the
+        permutation that puts what the lanes return back in the order of
+        ``lanes``."""
+        budgets = own_step_budget(self.hp, jnp.take(counts, lanes))
+        order = jnp.argsort(-budgets, stable=True)
+        return jnp.take(lanes, order), jnp.argsort(order)
+
+    def _run_lane_buckets(self, run_lanes, lanes_in):
+        """Run the lanes ``(cs, xs, ys, cnts, keys)`` bucket after bucket: ONE
+        bucket body in the program, scanned over the bucket axis.  A bucket is
+        the plain round's ``run_lanes`` over its lanes, with a step loop that
+        ends at the longest budget among them (one trip count for all of a
+        bucket's lanes).  Returns what the lanes returned, in their order, and
+        the lane-steps the buckets ran."""
+        k, per_bucket = self._lane_buckets, self._lanes // self._lane_buckets
+
+        def bucket(lane_steps, of_bucket):
+            cs, xs, ys, cnts, keys = of_bucket
+            bound = jnp.max(own_step_budget(self.hp, cnts))
+            out = run_lanes(self._constrain_lanes(cs), self._constrain_lanes(xs),
+                            self._constrain_lanes(ys), cnts, keys, step_bound=bound)
+            return lane_steps + per_bucket * bound, out
+
+        lane_steps, out = jax.lax.scan(bucket, jnp.int32(0), jax.tree_util.tree_map(
+            lambda a: a.reshape((k, per_bucket) + a.shape[1:]), lanes_in))
+        return jax.tree_util.tree_map(
+            lambda a: a.reshape((k * per_bucket,) + a.shape[2:]), out), lane_steps
+
     def _pad_lanes(self, sampled, m: int, m_pad: int):
         """Extend the sampled id vector with client-0 lanes up to the mesh
         multiple.  Pad lanes redo client 0's local SGD (same cost as an idle
@@ -366,26 +446,40 @@ class MeshSimulator(RoundCheckpointMixin):
         n_total = self.dataset.n_clients
         m = min(cfg.client_num_per_round, n_total)
         m_pad = self._lanes
+        buckets = self._lane_buckets
 
         def round_fn(global_vars, server_state, client_states, counts, data_x, data_y, round_idx, key, prev_delta):
             # the scopes name each op's phase in a device profile (XProf)
             with jax.named_scope("fl.gather"):
                 sampled = rng.sample_clients(key, round_idx, n_total, m)
+                lanes = sampled
+                if buckets > 1:  # lanes of like budgets together
+                    lanes, restore = self._order_lanes(self._pad_lanes(sampled, m, m_pad), counts)
                 xs, ys, cnts, cs, rkey, keys = self._gather_round_inputs(
-                    sampled, m, m_pad, counts, data_x, data_y, client_states, key, round_idx
+                    lanes, lanes.shape[0], m_pad, counts, data_x, data_y, client_states, key, round_idx
                 )
 
-            def one_client(cstate, x, y, cnt, k):
-                out = algo.client_update(global_vars, cstate, server_state, x, y, cnt, k)
+            def one_client(cstate, x, y, cnt, k, **bound):
+                out = algo.client_update(global_vars, cstate, server_state, x, y, cnt, k, **bound)
                 return out.contribution, out.client_state, out.metrics
 
-            with jax.named_scope("fl.local_sgd"):
+            def run_lanes(cs, xs, ys, cnts, keys, **bound):
                 if cs is not None:
-                    contribs, new_cs, metrics = jax.vmap(one_client, in_axes=(0, 0, 0, 0, 0))(cs, xs, ys, cnts, keys)
+                    return jax.vmap(partial(one_client, **bound), in_axes=(0, 0, 0, 0, 0))(cs, xs, ys, cnts, keys)
+                return jax.vmap(
+                    lambda x, y, cnt, k: one_client(None, x, y, cnt, k, **bound)
+                )(xs, ys, cnts, keys)
+
+            with jax.named_scope("fl.local_sgd"):
+                if buckets > 1:
+                    (contribs, new_cs, metrics), lane_steps = self._run_lane_buckets(
+                        run_lanes, (cs, xs, ys, cnts, keys))
+                    # back in the order of ``sampled``: the fold, the trust
+                    # hooks and the scatter see what they always saw
+                    contribs, new_cs, metrics, cnts = jax.tree_util.tree_map(
+                        lambda a: jnp.take(a, restore, axis=0), (contribs, new_cs, metrics, cnts))
                 else:
-                    contribs, new_cs, metrics = jax.vmap(
-                        lambda x, y, cnt, k: one_client(None, x, y, cnt, k)
-                    )(xs, ys, cnts, keys)
+                    contribs, new_cs, metrics = run_lanes(cs, xs, ys, cnts, keys)
 
             with jax.named_scope("fl.fold"):
                 # drop the pad lanes: everything downstream (trust hooks,
@@ -408,6 +502,9 @@ class MeshSimulator(RoundCheckpointMixin):
                 # what this round really trained on, said by the program
                 # that sampled it (fedml_sim_samples_total{kind="real"})
                 round_metrics[REAL_COUNT_KEY] = jnp.sum(cnts[:m])
+                if buckets > 1:
+                    # and what its lanes computed (kind="lane")
+                    round_metrics[LANE_STEPS_KEY] = lane_steps
             return new_global, new_server, new_states, new_delta, round_metrics
 
         return round_fn
@@ -702,7 +799,8 @@ class MeshSimulator(RoundCheckpointMixin):
                     self._aot_key("sim.multi_round",
                                   trees={"args": example_args},
                                   extra={"chunk": n, "donate": list(donate),
-                                         "stacked": REAL_COUNT_KEY}),
+                                         "stacked": REAL_COUNT_KEY,
+                                         "lane_buckets": self._lane_buckets}),
                     lambda: aotlib.export_program(jax.jit(multi), example_args),
                 )
             # a failed compile propagates: swallowing it here used to defer
@@ -760,12 +858,18 @@ class MeshSimulator(RoundCheckpointMixin):
                 return out
             return self._run_chunk(n, span)
 
-    def _count_samples(self, real_counts, rounds: int) -> tuple[int, int]:
-        """Feed ``fedml_sim_samples_total`` from the device's sum of the
-        sampled clients' counts over ``rounds`` rounds; returns (real, lane)."""
+    def _count_samples(self, metrics: dict, rounds: int) -> tuple[int, int]:
+        """Feed ``fedml_sim_samples_total`` from what the round program said
+        of its ``rounds`` rounds, taking its extra outputs out of ``metrics``:
+        the sum of the sampled clients' counts and, from a bucketed program,
+        the lane-steps its buckets ran (a program without buckets runs every
+        lane to full capacity).  Returns (real, lane)."""
         hp = self.hp
-        real = int(np.sum(real_counts)) * hp.epochs
-        lane = self._lanes * hp.steps_per_epoch * hp.batch_size * hp.epochs * rounds
+        real = int(np.sum(metrics.pop(REAL_COUNT_KEY))) * hp.epochs
+        lane_steps = metrics.pop(LANE_STEPS_KEY, None)
+        if lane_steps is None:
+            lane_steps = self._lanes * hp.steps_per_epoch * hp.epochs * rounds
+        lane = int(np.sum(lane_steps)) * hp.batch_size
         SAMPLES.inc(real, kind="real")
         SAMPLES.inc(lane, kind="lane")
         return real, lane
@@ -796,8 +900,9 @@ class MeshSimulator(RoundCheckpointMixin):
             ) from e
         execute_s = time.perf_counter() - t0
         CHUNK_EXECUTE_TIME.observe(execute_s)
-        real, lane = self._count_samples(host.pop(REAL_COUNT_KEY), n)
-        span.attrs.update(real_samples=real, lane_samples=lane)
+        lane_buckets = self._lane_buckets if LANE_STEPS_KEY in host else 1
+        real, lane = self._count_samples(host, n)
+        span.attrs.update(real_samples=real, lane_samples=lane, lane_buckets=lane_buckets)
         if self._cost_gauges and self._chunk_flops.get(n):
             achieved = self._chunk_flops[n] / max(execute_s, 1e-9)
             ACHIEVED_FLOPS.set(achieved)
@@ -835,7 +940,7 @@ class MeshSimulator(RoundCheckpointMixin):
         self.round_idx += 1
         with tracesan.allow("round_metrics"):
             if self.backend != C.SIMULATION_BACKEND_SP:
-                self._count_samples(metrics.pop(REAL_COUNT_KEY), 1)
+                self._count_samples(metrics, 1)
             return {k: float(v) for k, v in metrics.items()}  # graftlint: disable=GL010(annotated measurement site: single-round entry point syncs its own metric dict — the chunked path run_rounds amortizes this to one sync per chunk)
 
     def _run_round_sp(self, r: int) -> dict:

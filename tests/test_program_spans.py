@@ -181,8 +181,9 @@ def tiny_sim():
     from fedml_tpu.runner import FedMLRunner
 
     obstrace.clear_recent()
-    cfg = fedml_tpu.init(tiny_config(partition_method="hetero", client_num_in_total=8,
-                                     client_num_per_round=3, comm_round=100, epochs=2,
+    # a ragged population and a cohort that does not fill the 2-device mesh
+    cfg = fedml_tpu.init(tiny_config(partition_method="hetero", client_num_in_total=16,
+                                     client_num_per_round=15, comm_round=100, epochs=2,
                                      mesh_shape="clients:2"))
     sim = FedMLRunner(cfg).runner
     return sim, obstrace.recent()
@@ -232,15 +233,23 @@ def test_run_rounds_span_tree_and_sample_counts(tiny_sim):
     cfg = sim.cfg
     counts = np.array([len(ix) for ix in sim.dataset.client_idx])
     root = jax.random.PRNGKey(cfg.random_seed)
-    real = 0
+    # lane: what the lanes computed, summed on the device: 15 clients padded
+    # with client 0 to the 2-device mesh, sorted by step budget, each bucket of
+    # lanes run to its own longest client
+    lanes, buckets = sim._lanes, sim._lane_buckets
+    assert (lanes, buckets) == (16, 2) and rr.attrs["lane_buckets"] == 2
+    real = lane = 0
     for r in range(start, start + 3):
         perm = jax.random.permutation(jax.random.fold_in(root, r), cfg.client_num_in_total)
-        real += int(counts[np.asarray(perm[:cfg.client_num_per_round])].sum()) * cfg.epochs
-    # lane: 3 clients padded to the 2-device mesh, every lane at full capacity
-    lanes, steps = 4, -(-sim.capacity // cfg.batch_size)
-    lane = lanes * steps * cfg.batch_size * cfg.epochs * 3
+        cohort = counts[np.asarray(perm[:cfg.client_num_per_round])]
+        real += int(cohort.sum()) * cfg.epochs
+        budgets = cfg.epochs * -(-np.append(cohort, counts[0]) // cfg.batch_size)
+        longest = np.sort(budgets)[::-1].reshape(buckets, lanes // buckets)[:, 0]
+        lane += int(longest.sum()) * (lanes // buckets) * cfg.batch_size
     assert (rr.attrs["real_samples"], rr.attrs["lane_samples"]) == (real, lane)
-    assert 0 < real < lane
+    # every lane at full capacity is what a program without buckets computes
+    old_formula = lanes * -(-sim.capacity // cfg.batch_size) * cfg.batch_size * cfg.epochs * 3
+    assert 0 < real <= lane <= old_formula
     assert counter.value(kind="real") == before[0] + real
     assert counter.value(kind="lane") == before[1] + lane
     # a second chunk of the same length builds nothing

@@ -288,6 +288,42 @@ def test_tracesan_gate_flagship_rounds_and_async_fold(eight_devices):
             uninstall()
 
 
+def test_round_guard_is_silent_on_the_bucketed_chunk(eight_devices):
+    """A ragged population's chunk sorts its cohort and runs it in lane
+    buckets whose step loop ends at a value of the program (sim/engine.py).
+    All of that is on the device: rounds whose cohorts, and so whose trip
+    counts, differ are one compiled program and no transfer."""
+    import dataclasses
+
+    was_active = active()
+    san = install()
+    try:
+        from fedml_tpu.sim.engine import MeshSimulator
+
+        cfg = tiny_config(comm_round=5, client_num_in_total=24, client_num_per_round=20,
+                          batch_size=8, mesh_shape="clients:2")
+        ds, model = _load(cfg)
+        counts = [3, 40, 9, 17, 25, 33, 8, 12, 20, 28, 36, 5,
+                  7, 15, 23, 31, 39, 1, 10, 19, 27, 32, 16, 24]
+        starts = np.cumsum([0] + counts[:-1])
+        ds = dataclasses.replace(
+            ds, client_idx=[np.arange(s, s + c) for s, c in zip(starts, counts)])
+        sim = MeshSimulator(cfg, ds, model)
+        assert sim._lane_buckets == 2
+        guarded_before = san.report()["guarded_rounds"]
+        out = []
+        for _ in range(5):  # round 0 warms up; rounds 1-4 run guarded
+            out.extend(sim.run_rounds(1))
+        assert len({m["num_steps"] for m in out}) > 1, "the cohorts' budgets did not differ"
+        rep = san.report()
+        assert rep["violations"] == [], json.dumps(rep["violations"], indent=1)
+        assert rep["guarded_rounds"] - guarded_before >= 4, rep
+        assert rep["compiles"].get("steady", 0) == 0, rep["compiles"]
+    finally:
+        if was_active is None:
+            uninstall()
+
+
 def test_default_path_is_bitwise_pinned(eight_devices):
     """Training with the sanitizer installed must be BITWISE the default
     run: the guard observes, it never reorders or re-places a computation
