@@ -67,8 +67,8 @@ class FedLLMSimulator(RoundCheckpointMixin):
         alpha = self.alpha
         opt = optax.adamw(cfg.learning_rate)
 
-        def loss_fn(lora, x, y):
-            params = lora_lib.merge(self.base_params, lora, alpha=alpha)
+        def loss_fn(lora, base, x, y):
+            params = lora_lib.merge(base, lora, alpha=alpha)
             logits = model.apply({"params": params}, x, train=True)
             return optax.softmax_cross_entropy_with_integer_labels(
                 logits.astype(jnp.float32), y
@@ -83,7 +83,9 @@ class FedLLMSimulator(RoundCheckpointMixin):
         self._capacity = int(counts.max())
         steps = cfg.epochs * max(1, self._capacity // cfg.batch_size)
 
-        def client_step(lora, x, y, count, key):
+        # the frozen base is an argument: closed over, its weights would be
+        # constants baked into the compiled program
+        def client_step(lora, base, x, y, count, key):
             opt_state = opt.init(lora)
 
             def step(carry, s):
@@ -91,7 +93,7 @@ class FedLLMSimulator(RoundCheckpointMixin):
                 idx = jax.random.randint(
                     jax.random.fold_in(key, s), (cfg.batch_size,), 0, count
                 )
-                loss, g = grad_fn(lora, jnp.take(x, idx, 0), jnp.take(y, idx, 0))
+                loss, g = grad_fn(lora, base, jnp.take(x, idx, 0), jnp.take(y, idx, 0))
                 u, opt_state = opt.update(g, opt_state, lora)
                 return (optax.apply_updates(lora, u), opt_state), loss
 
@@ -100,8 +102,8 @@ class FedLLMSimulator(RoundCheckpointMixin):
 
         return client_step
 
-    def _eval_loss(self, lora, x, y):
-        params = lora_lib.merge(self.base_params, lora, alpha=self.alpha)
+    def _eval_loss(self, lora, base, x, y):
+        params = lora_lib.merge(base, lora, alpha=self.alpha)
         logits = self.model.apply({"params": params}, x, train=False)
         loss = optax.softmax_cross_entropy_with_integer_labels(
             logits.astype(jnp.float32), y
@@ -122,7 +124,8 @@ class FedLLMSimulator(RoundCheckpointMixin):
             x = jnp.asarray(ds.train_x[reps])
             y = jnp.asarray(ds.train_y[reps])
             new_lora, loss = self._client_step(
-                self.global_lora, x, y, jnp.int32(len(ix)), rng.client_key(rkey, int(ci))
+                self.global_lora, self.base_params, x, y, jnp.int32(len(ix)),
+                rng.client_key(rkey, int(ci))
             )
             loras.append(new_lora)
             weights.append(float(len(ix)))
@@ -136,7 +139,7 @@ class FedLLMSimulator(RoundCheckpointMixin):
         ds = self.dataset
         x = jnp.asarray(ds.test_x[:max_samples])
         y = jnp.asarray(ds.test_y[:max_samples])
-        return {k: float(v) for k, v in self._eval(self.global_lora, x, y).items()}
+        return {k: float(v) for k, v in self._eval(self.global_lora, self.base_params, x, y).items()}
 
     # -- round-level checkpoint/resume (reference FedLLM PauseResumeCallback,
     # spotlight_prj/fedllm/src/trainer_callback.py: each FL round resumes the
@@ -149,6 +152,11 @@ class FedLLMSimulator(RoundCheckpointMixin):
         }
 
     def _apply_ckpt_state(self, state: dict) -> None:
+        """Resume from a round checkpoint.  Not compatible across PR 29:
+        ``lora.init_lora`` now factors ``attn/wo`` as (heads x head_dim, r) x
+        (r, hidden), where it was (heads, r) x (r, head_dim x hidden), so a
+        checkpoint whose targets include ``wo`` (the default) and that was saved
+        before holds adapter shapes this simulator no longer draws."""
         self.global_lora = jax.tree_util.tree_map(jnp.asarray, state["global_lora"])
         self.round_idx = int(state["round_idx"])
         # checkpointed key is authoritative (same contract as MeshSimulator)

@@ -12,6 +12,12 @@ is differentiable w.r.t. the adapters, so ``jax.grad`` of
 no model surgery, works for any flax model.  The federated payload is the
 adapter tree alone (the whole point of FedLLM: exchange K entries of rank-r
 factors, not 7B weights).
+
+``merge`` builds a full-size copy of every target and its gradient.  The
+transformer's own projections also take the adapters on the activation side,
+``x W + (x a) b`` (``as_collection`` lays the tree out as the flax collection
+``lora`` that ``models/transformer.py:_project`` reads): the same function of
+``(base, lora)``, with no merged copy and no gradient of a frozen kernel.
 """
 
 from __future__ import annotations
@@ -22,8 +28,17 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from flax import traverse_util
 
 DEFAULT_TARGETS = r".*attn/w[qkvo]/kernel"
+#: kernels that contract all but their LAST dim ((heads, head_dim, d_model));
+#: every other kernel contracts its first
+_FAN_IN_ALL_BUT_LAST = r".*attn/wo/kernel"
+
+
+def _fan_in(path: str, shape) -> int:
+    lead = shape[:-1] if re.fullmatch(_FAN_IN_ALL_BUT_LAST, path) else shape[:1]
+    return int(np.prod(lead))
 
 
 def _match_paths(params, targets: str):
@@ -43,8 +58,8 @@ def init_lora(params, rank: int, key: jax.Array, targets: str = DEFAULT_TARGETS,
     """Adapter tree keyed by 'path/with/slashes' -> {a, b}."""
     lora = {}
     for i, (path, shape, _) in enumerate(_match_paths(params, targets)):
-        d_in = shape[0]
-        d_out = int(np.prod(shape[1:]))
+        d_in = _fan_in(path, shape)
+        d_out = int(np.prod(shape)) // d_in
         ka = jax.random.fold_in(key, 2 * i)
         lora[path] = {
             "a": jax.random.normal(ka, (d_in, rank), dtype) * (1.0 / max(1, d_in)) ** 0.5,
@@ -71,6 +86,22 @@ def merge(base_params, lora: dict, alpha: float = 16.0, rank: Optional[int] = No
         return leaf + delta.astype(leaf.dtype)
 
     return jax.tree_util.tree_map_with_path(update, base_params)
+
+
+def as_collection(lora: dict, alpha: float = 16.0, rank: Optional[int] = None) -> dict:
+    """The adapter tree as the flax variable collection ``lora``:
+    ``{"layer_0/attn/wq/kernel": {a, b}}`` becomes ``{"layer_0": {"attn":
+    {"wq": {"a": a, "b": b * alpha / r}}}}``, which the transformer's
+    projections add on the activation side.  Differentiable in ``lora``."""
+    if rank is None:
+        rank = next(iter(lora.values()))["a"].shape[1]
+    scale, flat = alpha / rank, {}
+    for path, ab in lora.items():
+        module, _, leaf = path.rpartition("/")
+        if leaf != "kernel":
+            raise ValueError(f"the activation-side path adapts kernels only, not {path!r}")
+        flat[module] = {"a": ab["a"], "b": ab["b"] * scale}
+    return traverse_util.unflatten_dict(flat, sep="/")
 
 
 def lora_size(lora: dict) -> int:

@@ -8,6 +8,13 @@ the parameter sharding rules (``parallel/sharding.py``), tensor parallelism
 is the model axis, ring attention the seq axis; AdamW + cosine schedule +
 grad clipping mirror the reference's TrainingArguments defaults; perplexity
 logging matches ``hf_trainer.py``'s metric.
+
+With ``lora_rank`` the trainer fine-tunes adapters over a frozen base (the
+reference's HF Trainer + PEFT LoRA): the base is held in the model's dtype
+with the parameters' shardings (2 bytes a parameter, no gradient, no moments),
+the adapters (``llm/lora.py``) and their AdamW state in float32; the step
+differentiates with respect to the adapters only and takes the base as an
+argument it neither donates nor returns.
 """
 
 from __future__ import annotations
@@ -25,8 +32,13 @@ import optax
 from ..core import rng
 from ..models.transformer import Transformer, TransformerConfig
 from ..obs.metrics import MetricsLogger
-from ..obs.trace import XLA_COUNTERS, install_xla_listener, traced
+from ..obs.trace import LLM_ATTENDED_KEYS, XLA_COUNTERS, install_xla_listener, traced
 from ..parallel import mesh as meshlib, sharding
+from . import lora as lora_lib
+
+#: what a model with block-sparse layers reports beside its loss: the keys
+#: its sparse layers attended and those a causal layer would have
+ATTENDED = ("sparse_kept", "sparse_causal")
 
 
 @dataclass(frozen=True)
@@ -42,6 +54,10 @@ class LLMTrainArgs:
     batch_size: int = 8
     seq_len: int = 512
     seed: int = 0
+    # adapter fine-tuning over a frozen base (llm/lora.py); 0 trains everything
+    lora_rank: int = 0
+    lora_alpha: float = 16.0
+    lora_targets: str = lora_lib.DEFAULT_TARGETS
 
 
 class LLMTrainer:
@@ -65,8 +81,13 @@ class LLMTrainer:
         # materialize params directly into their shardings (no host spike)
         self.param_shardings = sharding.named_shardings(variables["params"], mesh)
 
+        adapters = args.lora_rank > 0
+
         def init_fn():
-            return self.model.init({"params": k0}, sample)["params"]
+            params = self.model.init({"params": k0}, sample)["params"]
+            if adapters:  # frozen: held in the model's dtype
+                params = jax.tree_util.tree_map(lambda x: x.astype(cfg.dtype), params)
+            return params
 
         with traced("llm.init.params"):
             self.params = jax.jit(
@@ -87,11 +108,22 @@ class LLMTrainer:
         # the param path ('...nu/layer_0/attn/wq/kernel'), so the same
         # path-regex rules shard them like their params; scalars (count)
         # fall through to the replicate-by-default rule.
+        self.lora = None
+        trained_shardings = self.param_shardings
+        if adapters:
+            with traced("llm.init.adapters"):
+                make = lambda: lora_lib.init_lora(
+                    variables["params"], args.lora_rank, jax.random.fold_in(k0, 1),
+                    targets=args.lora_targets)
+                # no rule names an adapter's path: replicated, like the norms
+                trained_shardings = sharding.named_shardings(jax.eval_shape(make), mesh)
+                self.lora = jax.jit(make, out_shardings=trained_shardings)()
         with traced("llm.init.opt"):
+            trained = self.params if self.lora is None else self.lora
             opt_shardings = sharding.named_shardings(
-                jax.eval_shape(self.opt.init, self.params), mesh
+                jax.eval_shape(self.opt.init, trained), mesh
             )
-            self.opt_state = jax.jit(self.opt.init, out_shardings=opt_shardings)(self.params)
+            self.opt_state = jax.jit(self.opt.init, out_shardings=opt_shardings)(trained)
         self.data_sharding = sharding.batch_sharding(mesh, seq_axis=self.seq_axis)
         self.step_idx = 0
         # Pin the step's output shardings to the input shardings: with
@@ -102,40 +134,77 @@ class LLMTrainer:
         self._train_step = jax.jit(
             self._make_train_step(),
             donate_argnums=(0, 1),
-            out_shardings=(self.param_shardings, opt_shardings,
-                           {"loss": scalar_sh, "ppl": scalar_sh}),
+            out_shardings=(trained_shardings, opt_shardings,
+                           {k: scalar_sh for k in self._metric_names()}),
         )
 
+    def _metric_names(self) -> tuple:
+        return ("loss", "ppl") + (ATTENDED if self.cfg.has_sparse_layers else ())
+
     def _make_train_step(self):
+        """``(params, opt_state, tokens, targets)``, or with adapters
+        ``(lora, opt_state, base, tokens, targets)``; the first two are
+        donated and come back updated, with the step's metrics."""
         model = self.model
         opt = self.opt
+        args = self.args
+        attended = self.cfg.has_sparse_layers
+        chunked = self.cfg.loss_chunk > 0
 
-        def loss_fn(params, tokens, targets):
-            logits = model.apply({"params": params}, tokens, train=True)
-            losses = optax.softmax_cross_entropy_with_integer_labels(
-                logits.astype(jnp.float32), targets
-            )
-            return losses.mean()
+        def loss_fn(trained, base, tokens, targets):
+            variables = {"params": trained} if base is None else {
+                "params": base,
+                "lora": lora_lib.as_collection(trained, args.lora_alpha, args.lora_rank)}
+            stats = {}
+            # with a loss_chunk the model takes the targets and returns the
+            # per-token losses: the whole logits matrix never exists
+            kw = {"targets": targets} if chunked else {}
+            if attended:  # the sparse layers sow what they attended
+                out, sown = model.apply(variables, tokens, train=True, mutable=["stats"], **kw)
+                for name in ATTENDED:
+                    stats[name] = sum(v for path, v in jax.tree_util.tree_leaves_with_path(sown)
+                                      if path[-1].key == name)
+            else:
+                out = model.apply(variables, tokens, train=True, **kw)
+            if chunked:
+                return out.mean(), stats
+            with jax.named_scope("llm.head_loss"):
+                losses = optax.softmax_cross_entropy_with_integer_labels(
+                    out.astype(jnp.float32), targets
+                )
+                return losses.mean(), stats
 
-        def train_step(params, opt_state, tokens, targets):
+        def update(trained, opt_state, base, tokens, targets):
             # the scopes name each op's phase in a device profile (XProf)
             with jax.named_scope("llm.fwd_bwd"):
-                loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
+                (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                    trained, base, tokens, targets)
             with jax.named_scope("llm.optimizer"):
-                updates, opt_state = opt.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
-            return params, opt_state, {"loss": loss, "ppl": jnp.exp(loss)}
+                updates, opt_state = opt.update(grads, opt_state, trained)
+                trained = optax.apply_updates(trained, updates)
+            return trained, opt_state, {"loss": loss, "ppl": jnp.exp(loss), **stats}
 
-        return train_step
+        if self.lora is None:
+            return lambda params, opt_state, tokens, targets: update(
+                params, opt_state, None, tokens, targets)
+        return update
+
+    def _dispatch(self, tokens, targets) -> dict:
+        """One call of the step program on the trainer's own state."""
+        if self.lora is None:
+            self.params, self.opt_state, metrics = self._train_step(
+                self.params, self.opt_state, tokens, targets)
+        else:
+            self.lora, self.opt_state, metrics = self._train_step(
+                self.lora, self.opt_state, self.params, tokens, targets)
+        return metrics
 
     def step(self, tokens: jax.Array, targets: jax.Array) -> dict:
         with traced("llm.h2d"):
             tokens = jax.device_put(tokens, self.data_sharding)
             targets = jax.device_put(targets, self.data_sharding)
         with traced("llm.dispatch"):
-            self.params, self.opt_state, metrics = self._train_step(
-                self.params, self.opt_state, tokens, targets
-            )
+            metrics = self._dispatch(tokens, targets)
         self.step_idx += 1
         with traced("llm.sync"):
             return {k: float(v) for k, v in metrics.items()}
@@ -144,7 +213,10 @@ class LLMTrainer:
         """Spans (``obs/trace.py``; PERF.md names the metric each is for):
         ``llm.fit`` holds, per step, ``llm.next_batch`` (the caller's
         iterator), ``llm.step`` (what ``step_time_s`` times: ``llm.h2d``,
-        ``llm.dispatch``, ``llm.sync``) and ``llm.log``."""
+        ``llm.dispatch``, ``llm.sync``) and ``llm.log``.  A model with
+        block-sparse layers also says what they attended: ``sparse_kept`` and
+        ``sparse_causal`` in each history entry, as attributes of ``llm.step``
+        and in ``fedml_llm_attended_keys_total``."""
         history = []
         steps = steps or self.args.total_steps
         batches = iter(batch_iter)
@@ -154,11 +226,15 @@ class LLMTrainer:
                     batch = next(batches, None)
                 if batch is None or i >= steps:
                     break
-                with traced("llm.step", step=self.step_idx + 1):
+                with traced("llm.step", step=self.step_idx + 1) as span:
                     t0 = time.perf_counter()
                     m = self.step(*batch)
                     m["step"] = self.step_idx
                     m["step_time_s"] = time.perf_counter() - t0
+                    if ATTENDED[0] in m:
+                        span.attrs.update({k: m[k] for k in ATTENDED})
+                        for name, kind in zip(ATTENDED, ("kept", "causal")):
+                            LLM_ATTENDED_KEYS.inc(m[name], kind=kind)
                 with traced("llm.log"):
                     self.logger.log(m)
                 history.append(m)
@@ -180,14 +256,11 @@ class LLMTrainer:
         targets = jnp.roll(tokens, -1, axis=1)
         tokens = jax.device_put(tokens, self.data_sharding)
         targets = jax.device_put(targets, self.data_sharding)
-        params, opt_state = self.params, self.opt_state
         for _ in range(2):  # warmup: compile + layout settle
-            params, opt_state, m = self._train_step(params, opt_state, tokens, targets)
-            float(m["loss"])
+            float(self._dispatch(tokens, targets)["loss"])
         t0 = time.perf_counter()
         for _ in range(steps):
-            params, opt_state, m = self._train_step(params, opt_state, tokens, targets)
+            m = self._dispatch(tokens, targets)
         float(m["loss"])  # host sync
         dt = time.perf_counter() - t0
-        self.params, self.opt_state = params, opt_state
         return a.batch_size * a.seq_len * steps / dt

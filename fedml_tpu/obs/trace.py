@@ -42,7 +42,7 @@ from .registry import REGISTRY
 __all__ = [
     "Span", "traced", "activate", "current", "start_span",
     "inject", "extract", "new_id", "recent", "clear_recent",
-    "install_xla_listener", "XLA_COUNTERS",
+    "install_xla_listener", "XLA_COUNTERS", "LLM_ATTENDED_KEYS",
 ]
 
 #: finished spans, oldest first; a window of some thousand steps fits, and
@@ -272,6 +272,17 @@ XLA_CACHE_LOAD_SECONDS = REGISTRY.counter(
 #: what a top-level span of a timed path notes (``traced(counters=...)``)
 XLA_COUNTERS = (XLA_COMPILES.name, XLA_COMPILE_SECONDS.name,
                 XLA_CACHE_LOADS.name, XLA_CACHE_LOAD_SECONDS.name)
+
+#: fed by ``LLMTrainer.fit`` from what the step program summed on the device
+LLM_ATTENDED_KEYS = REGISTRY.counter(
+    "fedml_llm_attended_keys_total",
+    "Keys the block-sparse attention layers of an LLM step attended, summed "
+    "over batch, KV heads, queries and sparse layers: kind=kept is what the "
+    "per-query block selection kept (tokens at or before the query inside its "
+    "kept blocks), kind=causal what plain causal attention would attend.  "
+    "kept/causal is the share of the past a sparse layer reads.",
+    labels=("kind",),
+)
 
 _listener_lock = threading.Lock()
 _listener_installed = False

@@ -1,0 +1,260 @@
+"""InfLLM-v2 block-sparse softmax attention: every query picks the key blocks
+it attends.
+
+Two stages, both plain ``jax.numpy``/``lax``, and the entry that joins them:
+
+``select_blocks``  scores key blocks per query and KV head without a gradient:
+    compressed keys (the mean of ``kernel_size`` keys every ``kernel_stride``),
+    a softmax of each query head over the compressed keys that end at or
+    before it, summed over the heads of the KV head's group; a key block's
+    score is the largest of the compressed keys that overlap it.  Kept: the
+    first ``init_blocks`` blocks, every block that holds one of the last
+    ``window_size`` tokens, and the ``topk`` best of the other blocks that
+    start at or before the query.
+
+``sparse_attention``  the layer's whole mixer: the selection past ``dense_len``
+    tokens (every block up to it), then the attention below; what
+    ``SparseAttention`` calls and what a benchmark times alone.
+
+``block_sparse_attention``  exact softmax attention over the kept blocks'
+    tokens ``j <= t``: query chunks in an outer loop, key chunks in an inner
+    scan with the online-softmax accumulator (running max, normaliser,
+    weighted sum), key chunks wholly in a query chunk's future skipped.  It
+    computes the scores of every key chunk at or before the query chunk and
+    lets the mask drop what was not kept: a dense-masked pass, not yet one
+    that skips dropped blocks.  The backward pass is written out (the
+    flash-attention one): the forward keeps the output and each row's
+    log-sum-exp, the backward recomputes each pair of chunks' probabilities
+    once; no score matrix is kept and nothing is rematerialised twice.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+NEG_INF = -1e30
+#: the name a selection's block mask carries, so that a remat policy can keep
+#: it (``save_only_these_names``): no gradient passes through the selection,
+#: and selecting again in the backward pass costs a second sort
+SPARSE_KEEP = "sparse_keep"
+#: queries and keys a blockwise pass takes at a time: 256 / 512 / 1,024 read
+#: 203 / 148 / 269 ms a layer (selection + attention, forward + backward at
+#: 16,384 tokens, 32 query and 2 KV heads x 128, on a v5e)
+CHUNK = 512
+
+
+def _chunk(n: int, want: int, multiple: int = 1) -> int:
+    """The largest divisor of ``n`` that is at most ``want`` and a multiple of
+    ``multiple``."""
+    for c in range(min(want, n), 0, -1):
+        if n % c == 0 and c % multiple == 0:
+            return c
+    raise ValueError(f"no chunk of {n} is a multiple of {multiple}")
+
+
+def compress_keys(k, kernel_size: int, kernel_stride: int):
+    """k: (b, s, kv, d) -> (b, m, kv, d), ``m = (s - kernel_size) // kernel_stride + 1``
+    means of ``kernel_size`` consecutive keys, float32."""
+    s = k.shape[1]
+    m = (s - kernel_size) // kernel_stride + 1
+    idx = jnp.arange(m)[:, None] * kernel_stride + jnp.arange(kernel_size)[None, :]
+    return jnp.mean(k.astype(jnp.float32)[:, idx], axis=2)
+
+
+def select_blocks(q, k, *, kernel_size: int, kernel_stride: int, block_size: int, topk: int,
+                  init_blocks: int, window_size: int, q_chunk: int = 1024):
+    """q: (b, s, h, d), k: (b, s, kv, d) -> (keep, kept, causal): ``keep``
+    (b, kv, s, s // block_size) bool, the blocks each query attends; ``kept``
+    the keys ``j <= t`` inside them, summed over batch, KV heads and queries,
+    and ``causal`` the same sum for plain causal attention (float32)."""
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    if s % block_size or block_size % kernel_stride or kernel_size % kernel_stride:
+        raise ValueError("block selection needs seq % block_size == 0 and the stride to divide "
+                         f"block and kernel: {s}, {block_size}, {kernel_size}, {kernel_stride}")
+    q, k = jax.lax.stop_gradient((q, k))
+    nblk, per_block, before = s // block_size, block_size // kernel_stride, kernel_size // kernel_stride - 1
+    kbar = compress_keys(k, kernel_size, kernel_stride)
+    m = kbar.shape[1]
+    ends = jnp.arange(m) * kernel_stride + kernel_size - 1   # a compressed key's last token
+    blocks = jnp.arange(nblk)
+    cq = _chunk(s, q_chunk)
+    qg = q.astype(jnp.float32).reshape(b, s // cq, cq, kv, h // kv, d)
+
+    def one_chunk(args):
+        qc, t = args                                          # (b, cq, kv, g, d), (cq,)
+        logits = jnp.einsum("bqkgd,bmkd->bkgqm", qc, kbar,
+                            precision=jax.lax.Precision.HIGHEST) * d ** -0.5
+        valid = ends[None, :] <= t[:, None]                   # (cq, m)
+        logits = jnp.where(valid, logits, NEG_INF)
+        e = jnp.where(valid, jnp.exp(logits - logits.max(-1, keepdims=True)), 0.0)
+        p = (e / jnp.maximum(e.sum(-1, keepdims=True), 1e-30)).sum(2)   # (b, kv, cq, m)
+        # a block's compressed keys: the per_block that start inside it and
+        # the `before` that start ahead of it and reach into it
+        p = jnp.pad(p, ((0, 0), (0, 0), (0, 0), (before, nblk * per_block - m)))
+        score = p[..., before:].reshape(*p.shape[:-1], nblk, per_block).max(-1)
+        for j in range(before):
+            score = jnp.maximum(score, p[..., j: j + nblk * per_block: per_block])
+        visible = blocks[None, :] * block_size <= t[:, None]  # starts at or before the query
+        forced = visible & ((blocks[None, :] < init_blocks)
+                            | (blocks[None, :] >= (t[:, None] - window_size + 1) // block_size))
+        vals, idx = jax.lax.top_k(jnp.where(visible & ~forced, score, -1.0), min(topk, nblk))
+        chosen = ((idx[..., None] == blocks) & (vals[..., None] >= 0.0)).any(-2)
+        return forced | chosen                                # (b, kv, cq, nblk)
+
+    t_all = jnp.arange(s).reshape(s // cq, cq)
+    keep = jax.lax.map(one_chunk, (jnp.moveaxis(qg, 1, 0), t_all))     # (n, b, kv, cq, nblk)
+    keep = jnp.moveaxis(keep, 0, 2).reshape(b, kv, s, nblk)
+    held = jnp.clip(jnp.arange(s)[:, None] - blocks[None, :] * block_size + 1, 0, block_size)
+    kept = jnp.sum(jnp.where(keep, held.astype(jnp.float32), 0.0))
+    return keep, kept, jnp.float32(b * kv) * (s * (s + 1) / 2)
+
+
+def _chunks(q, k, v, keep, block_size: int, cq: int, ck: int):
+    """The operands cut into chunks, the chunk index leading: q (nq, b, cq,
+    kv, g, d), k and v (nk, b, ck, kv, d), and the mask by pair of chunks
+    (nq, nk, b, kv, cq, ck // block_size)."""
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    nq, nk = s // cq, s // ck
+    qs = jnp.moveaxis(q.reshape(b, nq, cq, kv, h // kv, d), 1, 0)
+    ks = jnp.moveaxis(k.reshape(b, nk, ck, kv, d), 1, 0)
+    vs = jnp.moveaxis(v.reshape(b, nk, ck, kv, d), 1, 0)
+    keeps = jnp.transpose(keep.reshape(b, kv, nq, cq, nk, ck // block_size), (2, 4, 0, 1, 3, 5))
+    return qs, ks, vs, keeps
+
+
+def _masked_logits(qc, kc, keep_qk, iq, ik, block_size: int, scale: float):
+    """Scores of one pair of chunks, (b, kv, g, cq, ck) float32, with their
+    mask: the kept blocks' tokens at or before each query."""
+    cq, ck = qc.shape[1], kc.shape[1]
+    q_pos, k_pos = iq * cq + jnp.arange(cq), ik * ck + jnp.arange(ck)
+    mask = (jnp.repeat(keep_qk, block_size, axis=-1) & (q_pos[:, None] >= k_pos[None, :]))[:, :, None]
+    logits = jnp.einsum("bqkgd,btkd->bkgqt", qc, kc, preferred_element_type=jnp.float32) * scale
+    return jnp.where(mask, logits, NEG_INF), mask
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _attend(q, k, v, keep, block_size, cq, ck, scale):
+    return _attend_fwd(q, k, v, keep, block_size, cq, ck, scale)[0]
+
+
+def _attend_fwd(q, k, v, keep, block_size, cq, ck, scale):
+    """Online softmax over key chunks for each query chunk; keeps the output
+    and each row's log-sum-exp for the backward pass, and no score."""
+    b, s, h, d = q.shape
+    kv, f32 = k.shape[2], jnp.float32
+    qs, ks, vs, keeps = _chunks(q, k, v, keep, block_size, cq, ck)
+
+    def one_query_chunk(args):
+        qc, keep_q, iq = args
+
+        def one_key_chunk(carry, xs):
+            kc, vc, keep_qk, ik = xs
+
+            def attend(carry):
+                m, l, o = carry
+                logits, mask = _masked_logits(qc, kc, keep_qk, iq, ik, block_size, scale)
+                m_new = jnp.maximum(m, logits.max(-1))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.where(mask, jnp.exp(logits - m_new[..., None]), 0.0)
+                o = o * jnp.moveaxis(alpha, 3, 1)[..., None] + jnp.einsum(
+                    "bkgqt,btkd->bqkgd", p.astype(vc.dtype), vc, preferred_element_type=f32)
+                return m_new, l * alpha + p.sum(-1), o
+
+            # a key chunk wholly in the query chunk's future adds nothing
+            return jax.lax.cond(ik * ck <= iq * cq + cq - 1, attend, lambda c: c, carry), None
+
+        init = (jnp.full((b, kv, h // kv, cq), NEG_INF, f32), jnp.zeros((b, kv, h // kv, cq), f32),
+                jnp.zeros((b, cq, kv, h // kv, d), f32))
+        (m, l, o), _ = jax.lax.scan(one_key_chunk, init, (ks, vs, keep_q, jnp.arange(s // ck)))
+        l = jnp.maximum(l, 1e-30)
+        return (o / jnp.moveaxis(l, 3, 1)[..., None]).astype(q.dtype), m + jnp.log(l)
+
+    out, lse = jax.lax.map(one_query_chunk, (qs, keeps, jnp.arange(s // cq)))
+    out = jnp.moveaxis(out, 0, 1).reshape(b, s, h, d)
+    return out, (q, k, v, keep, out, lse)
+
+
+def _attend_bwd(block_size, cq, ck, scale, saved, d_out):
+    """The flash-attention backward: each pair of chunks recomputes its
+    probabilities from the saved log-sum-exp, once."""
+    q, k, v, keep, out, lse = saved                      # lse: (nq, b, kv, g, cq)
+    b, s, h, d = q.shape
+    kv, f32 = k.shape[2], jnp.float32
+    qs, ks, vs, keeps = _chunks(q, k, v, keep, block_size, cq, ck)
+    dos = jnp.moveaxis(d_out.reshape(b, s // cq, cq, kv, h // kv, d), 1, 0)
+    # delta_t = sum_j p_tj dp_tj = do_t . o_t
+    deltas = jnp.moveaxis(jnp.sum(d_out.astype(f32) * out.astype(f32), -1)
+                          .reshape(b, s // cq, cq, kv, h // kv), 1, 0)
+
+    def one_query_chunk(dkv, args):
+        qc, doc, keep_q, lse_q, delta_q, iq = args
+        delta_q = jnp.transpose(delta_q, (0, 2, 3, 1))   # (b, kv, g, cq)
+
+        def one_key_chunk(dq, xs):
+            kc, vc, keep_qk, ik = xs
+
+            def attend(dq):
+                logits, mask = _masked_logits(qc, kc, keep_qk, iq, ik, block_size, scale)
+                p = jnp.where(mask, jnp.exp(logits - lse_q[..., None]), 0.0)
+                dv = jnp.einsum("bkgqt,bqkgd->btkd", p.astype(doc.dtype), doc, preferred_element_type=f32)
+                dp = jnp.einsum("bqkgd,btkd->bkgqt", doc, vc, preferred_element_type=f32)
+                ds = (p * (dp - delta_q[..., None]) * scale).astype(qc.dtype)
+                dq = dq + jnp.einsum("bkgqt,btkd->bqkgd", ds, kc, preferred_element_type=f32)
+                dk = jnp.einsum("bkgqt,bqkgd->btkd", ds, qc, preferred_element_type=f32)
+                return dq, (dk, dv)
+
+            nothing = jnp.zeros(kc.shape, f32)
+            return jax.lax.cond(ik * ck <= iq * cq + cq - 1, attend,
+                                lambda dq: (dq, (nothing, nothing)), dq)
+
+        dq, (dk, dv) = jax.lax.scan(one_key_chunk, jnp.zeros(qc.shape, f32),
+                                    (ks, vs, keep_q, jnp.arange(s // ck)))
+        return (dkv[0] + dk, dkv[1] + dv), dq.astype(q.dtype)
+
+    zeros = jnp.zeros(ks.shape, f32)
+    (dk, dv), dq = jax.lax.scan(one_query_chunk, (zeros, zeros),
+                                (qs, dos, keeps, lse, deltas, jnp.arange(s // cq)))
+    unchunk = lambda t, like: jnp.moveaxis(t, 0, 1).reshape(like.shape).astype(like.dtype)
+    return unchunk(dq, q), unchunk(dk, k), unchunk(dv, v), None
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+def block_sparse_attention(q, k, v, keep=None, *, block_size: int = 64, q_chunk: int = 1024,
+                           k_chunk: int = 1024, scale=None):
+    """q: (b, s, h, d); k, v: (b, s, kv, d); keep: (b, kv, s, s // block_size)
+    bool or None (every block) -> softmax attention of each query over the
+    tokens ``j <= t`` of its kept blocks, (b, s, h, d) in q's dtype."""
+    b, s, h, d = q.shape
+    scale = d ** -0.5 if scale is None else scale
+    if keep is None:
+        block_size = _chunk(s, block_size)
+        keep = jnp.ones((b, k.shape[2], s, s // block_size), bool)
+    return _attend(q, k, v, keep, block_size, _chunk(s, q_chunk),
+                   _chunk(s, k_chunk, block_size), float(scale))
+
+
+def sparse_attention(q, k, v, *, dense_len: int, chunk: int = 0, **selection):
+    """The InfLLM-v2 mixer on projected q (b, s, h, d) and k, v (b, s, kv, d):
+    plain causal attention for at most ``dense_len`` tokens, else attention
+    over the blocks ``select_blocks(**selection)`` keeps -> (out, kept,
+    causal), the counts as ``select_blocks`` gives them.  ``chunk`` 0 is
+    ``CHUNK``."""
+    b, s = q.shape[:2]
+    chunk = chunk or CHUNK
+    if s > dense_len:
+        keep, kept, causal = select_blocks(q, k, q_chunk=chunk, **selection)
+        keep = checkpoint_name(keep, SPARSE_KEEP)
+    else:
+        keep = None
+        kept = causal = jnp.float32(b * k.shape[2]) * (s * (s + 1) / 2)
+    out = block_sparse_attention(q, k, v, keep, block_size=selection["block_size"],
+                                 q_chunk=chunk, k_chunk=chunk)
+    return out, kept, causal

@@ -28,6 +28,8 @@ TRANSFORMER_RULES = [
     # attention projections: shard heads/out over model axis, in over data (zero3)
     (r".*attn/w[qkv]/kernel", lambda dp, tp: P(dp, tp, None)),
     (r".*attn/wo/kernel", lambda dp, tp: P(tp, None, dp)),
+    # a mixer's output gate (d_model -> heads x head_dim), laid out like wq
+    (r".*attn/wg/kernel", lambda dp, tp: P(dp, tp, None)),
     # mlp: gate/up shard out over model; down shards in over model
     (r".*mlp/w_(gate|up)/kernel", lambda dp, tp: P(dp, tp)),
     (r".*mlp/w_down/kernel", lambda dp, tp: P(tp, dp)),
