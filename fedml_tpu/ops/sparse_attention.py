@@ -10,7 +10,8 @@ Two stages, both plain ``jax.numpy``/``lax``, and the entry that joins them:
     score is the largest of the compressed keys that overlap it.  Kept: the
     first ``init_blocks`` blocks, every block that holds one of the last
     ``window_size`` tokens, and the ``topk`` best of the other blocks that
-    start at or before the query.
+    start at or before the query (``best_of``: exact, ties to the lower
+    index, and without a sort, which XLA:TPU makes of ``lax.top_k``).
 
 ``sparse_attention``  the layer's whole mixer: the selection past ``dense_len``
     tokens (every block up to it), then the attention below; what
@@ -39,11 +40,13 @@ from jax.ad_checkpoint import checkpoint_name
 NEG_INF = -1e30
 #: the name a selection's block mask carries, so that a remat policy can keep
 #: it (``save_only_these_names``): no gradient passes through the selection,
-#: and selecting again in the backward pass costs a second sort
+#: and selecting again in the backward pass would score every block a second
+#: time (9 ms at 16,384 tokens on a v5e) to save 8 MB
 SPARSE_KEEP = "sparse_keep"
 #: queries and keys a blockwise pass takes at a time: 256 / 512 / 1,024 read
-#: 203 / 148 / 269 ms a layer (selection + attention, forward + backward at
-#: 16,384 tokens, 32 query and 2 KV heads x 128, on a v5e)
+#: 200 / 126 / 314 ms of device time a layer (what a remat block holds of it:
+#: the selection once, the attention's forward twice and its backward, at
+#: 16,384 tokens, 32 query and 2 KV heads x 128, on a v5e; PR 30)
 CHUNK = 512
 
 
@@ -63,6 +66,26 @@ def compress_keys(k, kernel_size: int, kernel_stride: int):
     m = (s - kernel_size) // kernel_stride + 1
     idx = jnp.arange(m)[:, None] * kernel_stride + jnp.arange(kernel_size)[None, :]
     return jnp.mean(k.astype(jnp.float32)[:, idx], axis=2)
+
+
+def best_of(score, candidate, topk: int):
+    """score: (..., n) float32, every entry ``>= +0.0`` and none NaN;
+    candidate: (..., n) bool -> (..., n) bool, the ``topk`` best candidates of
+    each row: a candidate is chosen iff fewer than ``topk`` candidates rank
+    before it, where ``j`` ranks before ``i`` when it scores higher, or the
+    same with ``j < i`` (what ``lax.top_k`` and a stable descending sort give);
+    a row with fewer candidates chooses them all.  No sort: non-negative
+    floats order as their bit patterns do, so the row's ``topk``-th largest
+    pattern is found bit by bit, 31 times one compare and one count over the
+    row, and ties at it are cut by a prefix count."""
+    bits = jnp.where(candidate, jax.lax.bitcast_convert_type(score, jnp.int32), -1)
+    at = jnp.zeros((*bits.shape[:-1], 1), jnp.int32)      # largest v with #(bits >= v) >= topk
+    for bit in range(30, -1, -1):
+        trial = at | (1 << bit)
+        at = jnp.where(jnp.sum(bits >= trial, -1, keepdims=True) >= topk, trial, at)
+    above, tied = bits > at, bits == at
+    room = topk - jnp.sum(above, -1, keepdims=True)
+    return above | (tied & (jnp.cumsum(tied, -1) <= room))
 
 
 def select_blocks(q, k, *, kernel_size: int, kernel_stride: int, block_size: int, topk: int,
@@ -102,9 +125,7 @@ def select_blocks(q, k, *, kernel_size: int, kernel_stride: int, block_size: int
         visible = blocks[None, :] * block_size <= t[:, None]  # starts at or before the query
         forced = visible & ((blocks[None, :] < init_blocks)
                             | (blocks[None, :] >= (t[:, None] - window_size + 1) // block_size))
-        vals, idx = jax.lax.top_k(jnp.where(visible & ~forced, score, -1.0), min(topk, nblk))
-        chosen = ((idx[..., None] == blocks) & (vals[..., None] >= 0.0)).any(-2)
-        return forced | chosen                                # (b, kv, cq, nblk)
+        return forced | best_of(score, visible & ~forced, topk)   # (b, kv, cq, nblk)
 
     t_all = jnp.arange(s).reshape(s // cq, cq)
     keep = jax.lax.map(one_chunk, (jnp.moveaxis(qg, 1, 0), t_all))     # (n, b, kv, cq, nblk)

@@ -142,6 +142,136 @@ def test_sparse_layer_is_the_per_query_selection(seq, chunk, bench):
         np.testing.assert_allclose(a, b, atol=1e-5 * max(1.0, float(np.abs(b).max())))
 
 
+def _top_k_form(score, candidate, topk):
+    """The selection ``best_of`` replaced (PR 30), kept here as its oracle:
+    ``lax.top_k`` (equal values: lower index first) and a one-hot of what it
+    returned."""
+    import jax
+    import jax.numpy as jnp
+
+    n = score.shape[-1]
+    vals, idx = jax.lax.top_k(jnp.where(candidate, score, -1.0), min(topk, n))
+    return ((idx[..., None] == jnp.arange(n)) & (vals[..., None] >= 0.0)).any(-2)
+
+
+def _planted_scores(case: str):
+    """(score (rows, n) float32, candidate (rows, n) bool, topk) of one case."""
+    rng = np.random.default_rng(30)
+    rows, n, topk = 48, 256, 64
+    score = rng.random((rows, n), dtype=np.float32)
+    candidate = rng.random((rows, n)) < 0.85
+    if case == "all_equal":
+        score[:] = np.float32(0.37)
+        score[rows // 2:] = rng.random((rows - rows // 2, 1), dtype=np.float32)  # a value a row
+    elif case == "ties_astride_topk":
+        # a run of equal values that starts inside the best topk and ends
+        # outside them: 40 above it, 50 equal, the rest below
+        for r in range(rows):
+            cols = rng.permutation(np.flatnonzero(candidate[r]))
+            score[r] = rng.random(n, dtype=np.float32) * np.float32(0.4)
+            score[r, cols[:40]] += np.float32(0.6)
+            score[r, cols[40:90]] = np.float32(0.5)
+    elif case == "few_distinct_values":
+        score = (np.floor(score * 5) / 5).astype(np.float32)
+    elif case == "zeros":
+        score[:] = 0.0                                        # exactly +0.0 everywhere
+        score[rows // 2:] = np.where(rng.random((rows - rows // 2, n)) < 0.8, 0.0,
+                                     score[rows // 2:]).astype(np.float32)
+    elif case == "tiny_and_large":
+        score = (score * np.float32(2.0) ** rng.integers(-100, 4, (rows, n))).astype(np.float32)
+    elif case == "fewer_candidates_than_topk":
+        candidate = rng.random((rows, n)) < np.linspace(0.0, 0.3, rows)[:, None]
+        candidate[1, :] = False
+        candidate[2, :topk] = True                            # exactly topk candidates
+        candidate[2, topk:] = False
+    elif case == "topk_at_least_nblk":
+        n, topk = 32, 64
+        score, candidate = score[:, :n], candidate[:, :n]
+    elif case == "topk_is_nblk":
+        n = topk = 64
+        score, candidate = score[:, :n], candidate[:, :n]
+    elif case != "random":
+        raise ValueError(case)
+    return score, candidate, topk
+
+
+@pytest.mark.parametrize("case", ["random", "all_equal", "ties_astride_topk", "few_distinct_values", "zeros",
+                                  "tiny_and_large", "fewer_candidates_than_topk", "topk_at_least_nblk",
+                                  "topk_is_nblk"])
+def test_best_of_is_the_top_k_form(case):
+    """The sort-free selection chooses, element for element, what ``top_k``
+    chose: ties go to the lower index, a short row keeps every candidate."""
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.ops.sparse_attention import best_of
+
+    score, candidate, topk = _planted_scores(case)
+    assert not np.signbit(score).any()
+    got = np.asarray(jax.jit(best_of, static_argnums=2)(jnp.asarray(score), jnp.asarray(candidate), topk))
+    want = np.asarray(_top_k_form(jnp.asarray(score), jnp.asarray(candidate), topk))
+    np.testing.assert_array_equal(got, want)
+    assert not (got & ~candidate).any()
+    np.testing.assert_array_equal(got.sum(-1), np.minimum(candidate.sum(-1), topk))
+
+
+@pytest.mark.parametrize("keys", ["random", "constant"])
+@pytest.mark.parametrize("q_chunk", [16, 32])
+def test_select_blocks_keeps_what_the_top_k_form_kept(q_chunk, keys, bench, monkeypatch):
+    """The whole selection, at two chunk sizes: the mask with ``best_of`` is
+    the mask with the ``top_k`` form in its place; constant keys make every
+    visible compressed key score the same, so whole rows are ties."""
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.ops import sparse_attention as sa
+
+    z = bench["config"]["sparse_config"]
+    q, k, _ = _qkv(5, 128, 4, 2, 16, b=2)
+    if keys == "constant":
+        k = jnp.broadcast_to(k[:, :1], k.shape)
+    with jax.default_matmul_precision("highest"):
+        got = sa.select_blocks(q, k, **_select_kw(z, q_chunk=q_chunk))
+        monkeypatch.setattr(sa, "best_of", _top_k_form)
+        want = sa.select_blocks(q, k, **_select_kw(z, q_chunk=q_chunk))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    assert float(got[1]) == float(want[1]) < float(got[2])
+
+
+def _primitives(jaxpr) -> set:
+    """Names of every primitive of a jaxpr and of the jaxprs inside it."""
+    import jax
+
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+def test_selection_at_the_cells_constants_never_sorts(bench, monkeypatch):
+    """No ``sort`` / ``top_k`` / ``approx_top_k`` in the jaxpr of
+    ``select_blocks`` at the cell's shapes: XLA:TPU lowers each to a sort of
+    every row (50 ms a step at 16k tokens: ledger, PR 29), which a CPU-only
+    check would never feel.  The walk is proven on the ``top_k`` form."""
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.ops import sparse_attention as sa
+
+    c = bench["full_config"]
+    with open(os.path.join(BENCH, "traffic", "lora_sft_16k_b1.json")) as fh:
+        t = json.load(fh)
+    z = c["sparse_config"]
+    assert (z["topk"], z["block_size"], t["seq_len"] // z["block_size"]) == (64, 64, 256)
+    q, k = (jax.ShapeDtypeStruct((t["batch_size"], t["seq_len"], n, c["head_dim"]), jnp.bfloat16)
+            for n in (c["num_attention_heads"], c["num_key_value_heads"]))
+    trace = lambda: _primitives(jax.make_jaxpr(
+        lambda q, k: sa.select_blocks(q, k, **_select_kw(z, q_chunk=sa.CHUNK)))(q, k).jaxpr)
+    sorts = {"sort", "top_k", "approx_top_k"}
+    assert not trace() & sorts
+    monkeypatch.setattr(sa, "best_of", _top_k_form)
+    assert trace() & sorts == {"top_k"}
+
+
 def test_sparse_layer_under_dense_len_is_causal_attention(bench):
     import jax
     import jax.numpy as jnp
