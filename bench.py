@@ -10,10 +10,7 @@ Three benches, one JSON line:
    token x tokens/s / chip peak); vs_baseline = MFU / 0.35 target.
 2. **FedAvg CIFAR-10 ResNet-20 simulation** (the north-star FL recipe,
    BASELINE.md): samples/s/chip with 64 vmapped clients/round x batch 128
-   on the clients mesh axis, plus its own (low, conv-bound) MFU — measured
-   twice, unfused and with the fused Pallas conv epilogues
-   (``extra.fused_blocks``, ops/pallas/fused_block.py), the round-6 A/B.
-   The regression floors are asserted on the UNFUSED number only.
+   on the clients mesh axis, plus its own (low, conv-bound) MFU.
 3. **Compressed cross-silo rounds** (round-7): the qsgd8 wire ratio on the
    ResNet-20 pytree (floor 3.5x, platform independent) plus an in-proc
    4-client e2e raw-vs-qsgd8 A/B — wall/round, wire bytes, payload
@@ -71,7 +68,7 @@ import sys
 import time
 
 
-def bench_fedavg(peak, fused=False):
+def bench_fedavg(peak):
     import jax
 
     import fedml_tpu
@@ -101,9 +98,6 @@ def bench_fedavg(peak, fused=False):
         compute_dtype="bfloat16",
         step_mode="match",
         metrics_jsonl_path="",
-        # fused=True: identical recipe, conv epilogues via the fused Pallas
-        # kernel (ops/pallas/fused_block.py) — the round-6 A/B
-        extra={"fused_blocks": True} if fused else {},
     )
     fedml_tpu.init(cfg)
     sim = FedMLRunner(cfg).runner
@@ -130,7 +124,7 @@ def bench_fedavg(peak, fused=False):
     #   mandatory BN/relu/residual second passes account for the rest.
     #   See PERF.md "Per-op attribution".
     lane_ceiling, attainable = 0.214, 0.150
-    result = {
+    return {
         "samples_per_sec_chip": round(sps_chip, 1),
         "mfu": round(mfu, 4) if mfu is not None else None,
         "mfu_ceiling": lane_ceiling,
@@ -141,44 +135,7 @@ def bench_fedavg(peak, fused=False):
         "clients_total": n_clients,
         "clients_per_round": per_round,
         "batch": batch,
-        "fused_blocks": fused,
     }
-    if fused:
-        result["pallas_kernels"] = _kernel_microbench(batch)
-    return result
-
-
-def _kernel_microbench(batch):
-    """Standalone eager timings of each Pallas kernel on the flagship's
-    per-stage activation shapes: populates the process-global
-    ``pallas_kernel_seconds`` histogram (ROADMAP "Pallas-level timing hooks")
-    and returns its summary for the BENCH json.  Eager wall time includes
-    dispatch — an upper bound on the in-program cost, useful for
-    kernel-vs-kernel comparison, not for round accounting."""
-    import jax
-    import jax.numpy as jnp
-
-    from fedml_tpu.ops.pallas import (
-        fused_bn_relu, fused_bn_residual_relu, kernel_time_summary, qsgd_int8,
-    )
-
-    key = jax.random.PRNGKey(0)
-    iters = int(os.environ.get("BENCH_KERNEL_ITERS", "10"))
-    for shape in [(batch, 32, 32, 16), (batch, 16, 16, 32), (batch, 8, 8, 64)]:
-        y = jax.random.normal(key, shape, jnp.bfloat16)
-        r = jax.random.normal(key, shape, jnp.bfloat16)
-        s = jnp.full((shape[-1],), 1.1, jnp.float32)
-        b = jnp.full((shape[-1],), -0.1, jnp.float32)
-        g = jnp.ones(shape, jnp.bfloat16)
-        for _ in range(iters):
-            fused_bn_residual_relu(y, s, b, r)  # eager fwd, observed
-            _, pull = jax.vjp(lambda yy, rr: fused_bn_residual_relu(yy, s, b, rr), y, r)
-            pull(g)  # eager pullback -> the fused bwd kernel, also observed
-            fused_bn_relu(y, s, b)
-    vec = jax.random.normal(key, (1 << 20,), jnp.float32)
-    for i in range(iters):
-        qsgd_int8(vec, jax.random.PRNGKey(i))
-    return kernel_time_summary()
 
 
 def bench_crosssilo():
@@ -1345,8 +1302,6 @@ def _run_one(mode):
     peak = flopslib.device_peak_flops(dev)
     if mode == "llm":
         result = bench_llm(peak)
-    elif mode == "fedavg_fused":
-        result = bench_fedavg(peak, fused=True)
     elif mode == "crosssilo":
         result = bench_crosssilo()
     elif mode == "population":
@@ -1761,13 +1716,6 @@ def main():
     }
     llm = _subprocess_bench("llm")
     fedavg = _subprocess_bench("fedavg")
-    # round-6 A/B: the identical FedAvg recipe with conv epilogues through
-    # the fused Pallas kernels.  Soft-fail — a fused-path failure is recorded
-    # in the JSON but must not take down the two floor-guarded benches.
-    try:
-        fedavg_fused = _subprocess_bench("fedavg_fused")
-    except Exception as e:  # noqa: BLE001 — the error string IS the record
-        fedavg_fused = {"error": str(e)[-2000:]}
     # ISSUE-4: compressed streaming cross-silo rounds (in-proc backend) —
     # bytes-on-wire, compression ratio, and round wall time raw vs qsgd8
     crosssilo = _subprocess_bench("crosssilo")
@@ -1984,11 +1932,6 @@ def main():
 
     mfu = llm["mfu"]
     target = 0.35  # BASELINE.md MFU floor
-    fused_speedup = None
-    if fedavg.get("samples_per_sec_chip") and fedavg_fused.get("samples_per_sec_chip"):
-        fused_speedup = round(
-            fedavg_fused["samples_per_sec_chip"] / fedavg["samples_per_sec_chip"], 4
-        )
     print(json.dumps({
         "metric": "llm_542m_train_step_mfu",
         "value": mfu if mfu is not None else llm["tokens_per_sec_chip"],
@@ -2000,8 +1943,6 @@ def main():
             "chip_peak_tflops": llm.get("chip_peak_tflops"),
             "llm": llm,
             "fedavg_cifar10_resnet20": fedavg,
-            "fedavg_cifar10_resnet20_fused": fedavg_fused,
-            "fedavg_fused_speedup": fused_speedup,
             "crosssilo_comm": crosssilo,
             "population": population,
             "async": async_soak,

@@ -9,11 +9,7 @@ Drives, through the entry points a user calls and at the flagship widths:
   Leg A  the FedAvg ResNet-20 round (``fedml_tpu.init`` -> ``FedMLRunner.run``
          -> scanned chunks with a donated carry + ``evaluate``);
   Leg C  every Pallas kernel compiled by Mosaic (``interpret=False``) against
-         the jnp reference beside it, and one scanned chunk of Leg A's recipe
-         with ``extra.fused_blocks`` checked against Leg A's own losses (on a
-         host with several chips the engine must refuse that recipe on the
-         full mesh — GSPMD cannot shard a Mosaic kernel — and the chunk runs
-         on one chip);
+         the jnp reference beside it;
   Leg B  the 542M-parameter LLM train step (``LLMTrainer.fit``), on every mesh
          the visible devices allow.
 
@@ -26,7 +22,8 @@ never reached implicitly.
 
 One process, one chip (or one host's chips): the legs run in sequence and
 drop their references, no child process is started.  Wall seconds are printed
-per leg, split into compile and steady, as set-up facts — not as metrics.
+per leg (Legs A and B split into compile and steady), as set-up facts — not
+as metrics.
 """
 
 from __future__ import annotations
@@ -84,8 +81,7 @@ def check_spread(jax, tree, what: str) -> None:
 # Leg A — flagship FedAvg round (bench.py's fedavg shape)
 # ---------------------------------------------------------------------------
 
-def fl_recipe(dry: bool, fused: bool, rounds: int, eval_every: int,
-              mesh_shape: str = ""):
+def fl_recipe(dry: bool, rounds: int, eval_every: int):
     from fedml_tpu.arguments import Config
 
     n_clients, per_round, per_client, batch, n_test = (
@@ -98,17 +94,13 @@ def fl_recipe(dry: bool, fused: bool, rounds: int, eval_every: int,
         synthetic_train_size=n_clients * per_client, synthetic_test_size=n_test,
         frequency_of_the_test=eval_every, compute_dtype="bfloat16",
         step_mode="match", metrics_jsonl_path="", random_seed=0,
-        mesh_shape=mesh_shape,
-        extra={"fused_blocks": True} if fused else {},
     )
 
 
-def run_fl(jax, dry: bool, fused: bool, rounds: int, eval_every: int,
-           mesh_shape: str = "") -> dict:
+def run_fl(jax, dry: bool, rounds: int, eval_every: int) -> dict:
     """``init`` -> ``FedMLRunner`` -> ``run`` on the recipe; returns history,
     the compile/steady split and a host copy of the final flat model.  The
-    default mesh must span every device; an explicit ``mesh_shape`` is a
-    deliberate carve and is not checked for spread."""
+    default mesh must span every device."""
     import math
 
     import fedml_tpu
@@ -116,7 +108,7 @@ def run_fl(jax, dry: bool, fused: bool, rounds: int, eval_every: int,
     from fedml_tpu.runner import FedMLRunner
     from fedml_tpu.sim import engine
 
-    cfg = fl_recipe(dry, fused, rounds, eval_every, mesh_shape)
+    cfg = fl_recipe(dry, rounds, eval_every)
     fedml_tpu.init(cfg)
     model = None
     if dry:  # depth cut for the sandbox; the chip runs ResNet-20 from the hub
@@ -124,17 +116,15 @@ def run_fl(jax, dry: bool, fused: bool, rounds: int, eval_every: int,
 
         from fedml_tpu.models import resnet
 
-        model = resnet.CifarResNet(num_blocks=1, dtype=jnp.bfloat16, fused=fused)
+        model = resnet.CifarResNet(num_blocks=1, dtype=jnp.bfloat16)
     compile0 = engine.CHUNK_COMPILE_TIME.sum()
     runner = FedMLRunner(cfg, model=model)
     sim = runner.runner
-    check(bool(getattr(sim.model, "fused", False)) == fused, "fused flag lost")
-    if not mesh_shape:
-        check(sim.mesh.devices.size == jax.device_count(),
-              f"mesh {dict(sim.mesh.shape)} does not span "
-              f"{jax.device_count()} devices")
-        check_spread(jax, (sim._data, sim.client_states, sim.global_vars,
-                           sim._test), "FL placement")
+    check(sim.mesh.devices.size == jax.device_count(),
+          f"mesh {dict(sim.mesh.shape)} does not span "
+          f"{jax.device_count()} devices")
+    check_spread(jax, (sim._data, sim.client_states, sim.global_vars,
+                       sim._test), "FL placement")
     history = runner.run()
     check(len(history) == rounds, f"{len(history)} rounds of {rounds} ran")
     for h in history:
@@ -159,8 +149,7 @@ def run_fl(jax, dry: bool, fused: bool, rounds: int, eval_every: int,
 
 
 def leg_a(jax, dry: bool) -> dict:
-    r = run_fl(jax, dry, fused=False, rounds=4 if dry else 6,
-               eval_every=2 if dry else 3)
+    r = run_fl(jax, dry, rounds=4 if dry else 6, eval_every=2 if dry else 3)
     log(f"leg A: losses {[round(h['train_loss'], 4) for h in r['history']]} "
         f"test_acc {r['history'][-1]['test_acc']:.4f}")
     return r
@@ -174,7 +163,7 @@ def leg_c(jax, dry: bool, ref_a: dict | None) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from fedml_tpu.ops.pallas import backend, fused_block as fb, noise, quantize as q
+    from fedml_tpu.ops.pallas import backend, noise, quantize as q
 
     interp = dry  # explicit, never derived: compiled on the chip
     check(backend.resolve_interpret(None) == dry,
@@ -206,70 +195,7 @@ def leg_c(jax, dry: bool, ref_a: dict | None) -> dict:
         np.asarray(noise.apply_gaussian_noise_reference(x, key, 0.37)),
         rtol=1e-6, atol=1e-6, err_msg="apply_gaussian_noise")
     log("leg C: apply_gaussian_noise ok")
-
-    # fused BN(+residual)+ReLU, forward and backward, at the three stage
-    # shapes of one client batch, in the flagship dtype
-    batch = 2 if dry else 128
-    f32 = lambda a: np.asarray(a, np.float32)
-    for hw, ch in ((32, 16), (16, 32), (8, 64)):
-        shape = (batch, hw, hw, ch)
-        ks = jax.random.split(jax.random.fold_in(key, ch), 5)
-        y, r, g = (jax.random.normal(k, shape, jnp.bfloat16) for k in ks[:3])
-        s, b = (jax.random.normal(k, (ch,), jnp.float32) for k in ks[3:])
-
-        def both(kernel_res, kernel_plain):
-            def f(y, s, b, r, g):
-                out, pull = jax.vjp(kernel_res, y, s, b, r)
-                out2, pull2 = jax.vjp(kernel_plain, y, s, b)
-                return out, pull(g), out2, pull2(g)
-            return jax.jit(f)(y, s, b, r, g)
-
-        got = both(
-            lambda y, s, b, r: fb.fused_bn_residual_relu(y, s, b, r, interpret=interp),
-            lambda y, s, b: fb.fused_bn_relu(y, s, b, interpret=interp))
-        want = both(fb.fused_block_reference, fb.fused_block_reference)
-        for a, e in zip(jax.tree_util.tree_leaves(got),
-                        jax.tree_util.tree_leaves(want)):
-            # elementwise outputs are bf16; d(scale)/d(shift) sum ~1e5 terms
-            scale = max(1.0, float(np.abs(f32(e)).max()))
-            np.testing.assert_allclose(f32(a), f32(e), rtol=1e-2, atol=1e-2 * scale,
-                                       err_msg=f"fused epilogue {shape}")
-        log(f"leg C: fused_bn_relu / fused_bn_residual_relu fwd+bwd {shape} ok")
-    kernels_s = time.perf_counter() - t0
-
-    # the kernel as it is actually used: vmapped over clients, inside the
-    # scanned chunk, under custom_vjp — one chunk of Leg A's recipe
-    rounds = 2
-    mesh_shape = ""
-    if jax.device_count() > 1 and not dry:
-        # GSPMD cannot shard a compiled Mosaic kernel, so on several chips
-        # the engine must REFUSE the fused recipe on the default mesh (never
-        # run it interpreted, replicated or unfused) ...
-        try:
-            run_fl(jax, dry, fused=True, rounds=rounds, eval_every=0)
-        except NotImplementedError as e:
-            check("cannot be automatically partitioned" in str(e), str(e))
-            log(f"leg C: fused_blocks on {jax.device_count()} chips refused, "
-                "as it must be")
-        else:
-            raise AssertionError(
-                "fused_blocks ran on a multi-chip mesh: how was the kernel "
-                "partitioned?")
-        mesh_shape = "clients:1"  # ... and the chunk runs on one of them
-    r = run_fl(jax, dry, fused=True, rounds=rounds, eval_every=0,
-               mesh_shape=mesh_shape)
-    fused_losses = [h["train_loss"] for h in r["history"]]
-    if ref_a is not None:
-        want = [h["train_loss"] for h in ref_a["history"][:rounds]]
-        np.testing.assert_allclose(
-            fused_losses, want, rtol=2e-2,
-            err_msg="fused_blocks chunk disagrees with the unfused Leg A")
-    log(f"leg C: fused_blocks chunk losses {[round(l, 4) for l in fused_losses]}"
-        + ("" if ref_a is not None else " (no Leg A reference to compare)"))
-    return {"kernels_s": kernels_s, "compile_s": r["compile_s"],
-            # its only chunk compiled: steady = chunk wall minus the compile
-            "steady_s": r["steady_s"] - r["compile_s"],
-            "chunk_rounds": rounds, "memory": r["memory"]}
+    return {"kernels_s": time.perf_counter() - t0, "memory": memory(jax)}
 
 
 # ---------------------------------------------------------------------------
@@ -394,12 +320,13 @@ def main() -> int:
         t0 = time.perf_counter()
         try:
             results[name] = r = leg()
-            log(f"{tag}leg {name} PASSED in {time.perf_counter() - t0:.1f}s: "
-                f"compile {r['compile_s']:.1f}s, steady {r['steady_s']:.3f}s"
-                + (f" per {r['chunk_rounds']}-round chunk" if "chunk_rounds" in r
-                   else " per step")
-                + (f", kernels {r['kernels_s']:.1f}s" if "kernels_s" in r else "")
-                + f", memory in-use/peak per device: {fmt_memory(r['memory'])}")
+            if "kernels_s" in r:
+                took = f"kernels {r['kernels_s']:.1f}s"
+            else:
+                per = f"{r['chunk_rounds']}-round chunk" if "chunk_rounds" in r else "step"
+                took = f"compile {r['compile_s']:.1f}s, steady {r['steady_s']:.3f}s per {per}"
+            log(f"{tag}leg {name} PASSED in {time.perf_counter() - t0:.1f}s: {took}, "
+                f"memory in-use/peak per device: {fmt_memory(r['memory'])}")
         except Exception:
             failed.append(name)
             traceback.print_exc()

@@ -9,7 +9,6 @@ and the sharded MESH backend because it is pure functions over pytrees.
 from __future__ import annotations
 
 from .. import constants as C
-from ..core.flags import cfg_extra
 from ..fl.algorithm import FedAlgorithm
 from ..fl.types import HParams
 from .fedavg import FedAvg, FedAvgSeq
@@ -69,5 +68,4 @@ def hparams_from_config(cfg, steps_per_epoch: int = 0) -> HParams:
         steps_per_epoch=steps_per_epoch,
         step_mode=getattr(cfg, "step_mode", "match"),
         compute_dtype=cfg.compute_dtype,
-        fused_blocks=bool(cfg_extra(cfg, "fused_blocks")),
     )

@@ -4,7 +4,7 @@ idioms to ``cfg_extra(cfg, name, default)``.
 GL001 flags three legacy read idioms; this module REWRITES the one that has a
 semantics-preserving mechanical form — the ``.get`` call::
 
-    cfg.extra.get("fused_blocks")                     -> cfg_extra(cfg, 'fused_blocks', None)
+    cfg.extra.get("aot_programs")                     -> cfg_extra(cfg, 'aot_programs', None)
     (getattr(cfg, "extra", {}) or {}).get("k", 3)     -> cfg_extra(cfg, 'k', 3)
     extra = cfg.extra; ... extra.get("silo_dp", True) -> cfg_extra(cfg, 'silo_dp', True)
     x = extra.setdefault("k", 3)                      -> x = cfg_extra(cfg, 'k', 3)

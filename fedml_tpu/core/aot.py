@@ -16,7 +16,7 @@ everything that affects tracing —
 
     (site, topology/config, mesh shape + axis names, the argument pytree's
      structure/shapes/dtypes [which subsumes the model variable tree],
-     hparams, chunk size / donation gating / fused-kernel + codec flags,
+     hparams, chunk size / donation gating / codec flags,
      jax + jaxlib version, backend + device kind + device count)
 
 A warm process **deserializes the lowered StableHLO instead of re-tracing**,
@@ -194,7 +194,7 @@ _VOLATILE_CFG_KEYS = {
 
 def config_signature(cfg: Any) -> Optional[dict]:
     """The run config minus volatile per-run values, canonicalized.  Broad on
-    purpose: hparams, topology knobs, codec / fused-kernel / trust flags all
+    purpose: hparams, topology knobs, codec / trust flags all
     change the traced program and must key it."""
     if cfg is None:
         return None

@@ -53,9 +53,6 @@ def _specs(*specs: FlagSpec) -> dict[str, FlagSpec]:
 
 FLAGS: dict[str, FlagSpec] = _specs(
     # -- training / model ----------------------------------------------------
-    FlagSpec("fused_blocks", "bool", False,
-             "Route CIFAR-ResNet conv epilogues through the fused Pallas "
-             "BasicBlock kernel (BN scale/shift + residual + ReLU in one pass)."),
     FlagSpec("mlp_hidden", "int", 128,
              "Hidden width of the synthetic `mlp` model (comm benches widen it "
              "past the compression block size)."),

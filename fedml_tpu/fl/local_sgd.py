@@ -25,14 +25,6 @@ Ragged client shards (SURVEY.md §7 hard part 1) are handled by:
 Algorithm customisation is via two pure hooks (closed over at build time):
 ``loss_extra(params, global_params, ctx)`` (FedProx/FedDyn terms) and
 ``grad_hook(grads, ctx)`` (SCAFFOLD/Mime corrections).
-
-With ``hp.fused_blocks`` recipes the model's conv epilogues run through the
-fused Pallas kernel (``ops/pallas/fused_block.py``), whose ``custom_vjp``
-saves the conv output + activation as backward residuals.  Those residuals
-are INTRA-step: ``value_and_grad`` consumes them inside one ``step`` body, so
-they never enter the scan carry and are dead by the time the carry is
-donated — the fused path composes with ``jit(scan)`` + donation unchanged
-(the parity tests and the MeshSimulator fused smoke test pin this down).
 """
 
 from __future__ import annotations

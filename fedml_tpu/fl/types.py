@@ -39,10 +39,6 @@ class HParams:
     step_mode: str = "match"  # match reference per-client step counts | fixed
     compute_dtype: str = "float32"
     loss: str = "cross_entropy"
-    # fused Pallas conv epilogues (ops/pallas/fused_block.py); the model
-    # factory reads the same flag from cfg extra — carried here so the local
-    # step and bench can report which kernel path a recipe ran
-    fused_blocks: bool = False
 
     @property
     def local_steps(self) -> int:

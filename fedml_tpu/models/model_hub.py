@@ -23,16 +23,6 @@ def create(cfg: Config, output_dim: int) -> Any:
     # compute dtype threads into the conv/matmul path (params stay f32);
     # without this the whole CNN zoo silently runs f32 on the MXU's slow path
     dtype = jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else jnp.float32
-    # extra.fused_blocks routes the CIFAR-ResNet conv epilogues through the
-    # fused Pallas kernel (ops/pallas/fused_block.py); cfg_extra also honors
-    # a direct cfg attribute, so a recipe-level `fused_blocks: true` lands
-    # here without a dedicated field.  Any other model has no fused path,
-    # and asking for one is an error rather than a silently unfused run.
-    fused = bool(cfg_extra(cfg, "fused_blocks"))
-    if fused and name not in ("resnet20", "resnet32", "resnet44", "resnet56"):
-        raise ValueError(
-            f"extra.fused_blocks applies to the BatchNorm CIFAR ResNets "
-            f"(resnet20/32/44/56); model {cfg.model!r} has no fused path")
     if name in ("lr", "logistic_regression"):
         return simple.LogisticRegression(num_classes=output_dim)
     if name in ("cnn", "cnn_dropout"):
@@ -46,13 +36,13 @@ def create(cfg: Config, output_dim: int) -> Any:
         return simple.MLP(num_classes=output_dim,
                           hidden=int(cfg_extra(cfg, "mlp_hidden")))
     if name == "resnet20":
-        return resnet.resnet20(output_dim, norm, dtype, fused=fused)
+        return resnet.resnet20(output_dim, norm, dtype)
     if name == "resnet32":
-        return resnet.resnet32(output_dim, norm, dtype, fused=fused)
+        return resnet.resnet32(output_dim, norm, dtype)
     if name == "resnet44":
-        return resnet.resnet44(output_dim, norm, dtype, fused=fused)
+        return resnet.resnet44(output_dim, norm, dtype)
     if name == "resnet56":
-        return resnet.resnet56(output_dim, norm, dtype, fused=fused)
+        return resnet.resnet56(output_dim, norm, dtype)
     if name in ("resnet18_gn", "resnet_gn"):
         # BN-free escape hatch (reference model/cv/resnet_gn.py)
         return resnet.resnet20(output_dim, "group", dtype)

@@ -4,19 +4,13 @@ Current kernels:
 - ``quantize.quantize_int8_stochastic`` / ``dequantize_int8`` — fused
   block-scaled stochastic int8 gradient quantization for the FedSGD
   compression path.
-- ``fused_block.fused_bn_relu`` / ``fused_bn_residual_relu`` — the fused
-  BasicBlock epilogue (BN scale/shift apply + residual add + ReLU, with a
-  fused custom-VJP backward) behind the ``fused_blocks`` recipe flag.
+- ``noise.apply_gaussian_noise`` — the DP noise of secure aggregation's
+  finalize step, scale-and-add in one pass.
 
 Every eager kernel invocation is recorded into the process-global
 ``pallas_kernel_seconds`` histogram (``timing.py``).
 """
 
-from .fused_block import (
-    fused_bn_relu,
-    fused_bn_residual_relu,
-    fused_block_reference,
-)
 from .quantize import (
     dequantize_int8,
     qsgd_int8,
@@ -27,9 +21,6 @@ from .timing import PALLAS_KERNEL_TIME, kernel_time_summary
 
 __all__ = [
     "dequantize_int8",
-    "fused_bn_relu",
-    "fused_bn_residual_relu",
-    "fused_block_reference",
     "kernel_time_summary",
     "PALLAS_KERNEL_TIME",
     "qsgd_int8",

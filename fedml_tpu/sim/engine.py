@@ -58,7 +58,6 @@ from ..obs import otlp as obsotlp, registry as obsreg
 from ..obs.metrics import MetricsLogger
 from ..obs.trace import XLA_COUNTERS, traced
 from ..ops import flops as flopslib
-from ..ops.pallas.backend import resolve_interpret
 
 # measurement substrate for perf work (ISSUE 1): compile vs execute split,
 # program-cache hit rate, round/eval wall time — all scrapable via /metrics
@@ -83,11 +82,6 @@ CHUNK_CACHE = obsreg.REGISTRY.counter(
     "Scanned-chunk program cache lookups; jit cache hits are the "
     "hit/miss delta over time.",
     labels=("result",),
-)
-FUSED_BLOCKS = obsreg.REGISTRY.gauge(
-    "fedml_sim_fused_blocks",
-    "1 when the simulator's model routes conv epilogues through the fused "
-    "Pallas BasicBlock kernel (extra.fused_blocks), else 0.",
 )
 ACHIEVED_FLOPS = obsreg.REGISTRY.gauge(
     "fedml_sim_achieved_flops_per_sec",
@@ -174,26 +168,7 @@ class MeshSimulator(RoundCheckpointMixin):
         self.hp = hparams_from_config(cfg, steps_per_epoch=steps_per_epoch)
         self.algorithm = (algorithm or create_algorithm(cfg, self.hp)).build(model)
 
-        # which kernel path this run's model uses (fused Pallas epilogues vs
-        # plain XLA loop fusions) — scrapable next to the round timings so an
-        # A/B pair of runs is attributable from /metrics alone
-        fused = bool(getattr(model, "fused", False))
-        FUSED_BLOCKS.set(1.0 if fused else 0.0)
-
         self.mesh = mesh if mesh is not None else meshlib.mesh_from_config(cfg)
-        if (fused and self.mesh.devices.size > 1
-                and self.backend != C.SIMULATION_BACKEND_SP
-                and not resolve_interpret()):
-            # found on a four-chip v5e host (PR 21): lowering the vmapped
-            # kernel with its client dim sharded fails in jax with exactly
-            # the quoted message.  Refuse here, before any data is placed.
-            raise NotImplementedError(
-                f"extra.fused_blocks on a {self.mesh.devices.size}-device mesh: "
-                "GSPMD cannot shard a compiled Pallas kernel (jax: \"Mosaic "
-                "kernels cannot be automatically partitioned. Please wrap "
-                "the call in a shard_map.\").  Run fused recipes on one chip "
-                "(mesh_shape: 'clients:1') until the vmapped client step is "
-                "shard_mapped.")
         # Client-axis padding (SURVEY §7 hard-part 2): stacks whose leading
         # (client) dim is not a multiple of the mesh axis would REPLICATE
         # (shard_leading_axis's correctness fallback) and serialize all client
@@ -716,7 +691,7 @@ class MeshSimulator(RoundCheckpointMixin):
         """Program-store fingerprint for one of this simulator's traced
         programs: mesh + argument tree signatures + hparams + the full
         (volatile-stripped) config, so any knob that changes tracing — chunk
-        size, fused_blocks, codec/trust flags, donation gating — changes the
+        size, codec/trust flags, donation gating — changes the
         key (see core/aot.py)."""
         return aotlib.program_key(
             site,

@@ -85,30 +85,3 @@ def test_chip_smoke_dry_run_is_explicit_and_labelled():
                     "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
     for leg in ("A", "C", "B"):
         assert f"dry_run leg {leg} PASSED" in res.stdout
-
-
-def test_fused_blocks_refused_on_a_multi_device_mesh_when_compiled(
-        eight_devices, make_tiny_config, monkeypatch):
-    """GSPMD cannot shard a compiled Mosaic kernel (seen on a four-chip v5e
-    host): where the kernels would compile, the engine refuses the fused
-    recipe on a multi-device mesh up front — and still accepts it where they
-    are interpreted (this suite) or on one device."""
-    import jax
-
-    from fedml_tpu.data import loader
-    from fedml_tpu.models import resnet
-    from fedml_tpu.parallel import mesh as meshlib
-    from fedml_tpu.sim import engine
-
-    cfg = make_tiny_config(
-        dataset="cifar10", model="resnet20", client_num_in_total=2,
-        client_num_per_round=2, batch_size=8, synthetic_train_size=16,
-        synthetic_test_size=32, frequency_of_the_test=0)
-    ds = loader.load(cfg)
-    model = resnet.CifarResNet(num_blocks=1, num_classes=ds.class_num, fused=True)
-    two = meshlib.make_mesh((meshlib.AXIS_CLIENTS,), (2,), jax.devices()[:2])
-    one = meshlib.make_mesh((meshlib.AXIS_CLIENTS,), (1,), jax.devices()[:1])
-    monkeypatch.setattr(engine, "resolve_interpret", lambda *a: False)
-    with pytest.raises(NotImplementedError, match="cannot be automatically partitioned"):
-        engine.MeshSimulator(cfg, ds, model, mesh=two)
-    engine.MeshSimulator(cfg, ds, model, mesh=one)  # one device: accepted

@@ -1352,8 +1352,8 @@ def test_cfg_extra_resolution_order_and_undeclared_rejection():
     assert cfg_extra(cfg, "seg_base") == 8             # registry default
     assert cfg_extra(cfg, "seg_base", 99) == 99        # explicit default wins
     assert cfg_extra(None, "seg_base") == 8            # cfg=None short-circuit
-    cfg.fused_blocks = True
-    assert cfg_extra(cfg, "fused_blocks") is True      # direct attr wins
+    cfg.silo_dp = False
+    assert cfg_extra(cfg, "silo_dp") is False          # direct attr wins (default True)
     with pytest.raises(KeyError):
         cfg_extra(cfg, "not_a_flag")
     assert all(s.name == n for n, s in FLAGS.items())

@@ -24,7 +24,7 @@ FLAGS_FIXTURE = """
             pass
 
     FLAGS = {
-        "fused_blocks": FlagSpec("fused_blocks", "bool", False, "doc"),
+        "aot_programs": FlagSpec("aot_programs", "bool", False, "doc"),
         "mlp_hidden": FlagSpec("mlp_hidden", "int", 128, "doc"),
         "silo_dp": FlagSpec("silo_dp", "bool", True, "doc"),
         "comm_topk_ratio": FlagSpec("comm_topk_ratio", "float", None, "doc"),
@@ -38,7 +38,7 @@ LEGACY_MOD = '''
 
 
     def f(cfg):
-        a = cfg.extra.get("fused_blocks")
+        a = cfg.extra.get("aot_programs")
         b = (getattr(cfg, "extra", {}) or {}).get("mlp_hidden", 64)
         extra = cfg.extra
         c = extra.get("silo_dp", True)
@@ -55,7 +55,7 @@ def test_fix_rewrites_all_idioms_and_is_idempotent():
     assert skipped == []
     assert "from fedml_tpu.core.flags import cfg_extra" in fixed
     assert ".get(" not in fixed
-    assert "cfg_extra(cfg, 'fused_blocks', None)" in fixed
+    assert "cfg_extra(cfg, 'aot_programs', None)" in fixed
     assert "cfg_extra(cfg, 'mlp_hidden', 64)" in fixed
     assert "cfg_extra(cfg, 'silo_dp', True)" in fixed
     assert "cfg_extra(cfg, 'comm_topk_ratio', cfg_extra(cfg, 'comm_compress_min_size', 0.01))" in fixed
@@ -75,7 +75,7 @@ def test_fix_preserves_runtime_semantics():
     exec(compile(src, "orig.py", "exec"), orig_ns)
     exec(compile(fixed, "fixed.py", "exec"), fixed_ns)
     for extra in ({}, {"mlp_hidden": 256, "silo_dp": False},
-                  {"fused_blocks": True, "comm_compress_min_size": 0.5}):
+                  {"aot_programs": True, "comm_compress_min_size": 0.5}):
         cfg = Config(dataset="synthetic", model="lr", extra=dict(extra))
         assert fixed_ns["f"](cfg) == orig_ns["f"](cfg), extra
 
@@ -92,7 +92,7 @@ def test_fix_skips_manual_sites_and_suppressions(tmp_path):
 
 
         def g(cfg):  # graftlint: disable=GL001(deliberate raw read)
-            return cfg.extra.get("fused_blocks")
+            return cfg.extra.get("aot_programs")
     '''))
     before = (tmp_path / "mod.py").read_text()
     res = fix_tree(tmp_path)
@@ -102,7 +102,7 @@ def test_fix_skips_manual_sites_and_suppressions(tmp_path):
     assert "setdefault" in notes and "statement-position extra[...]" in notes
     assert "membership test with a non-literal name" in notes
     assert notes.count("literal flag name") == 2  # .get(name) + extra[name]
-    assert "fused_blocks" not in notes  # suppressed site: no nag either
+    assert "aot_programs" not in notes  # suppressed site: no nag either
 
 
 def test_fix_rewrites_value_position_subscript(tmp_path):
@@ -115,7 +115,7 @@ def test_fix_rewrites_value_position_subscript(tmp_path):
             a = cfg.extra["mlp_hidden"]
             extra = cfg.extra
             b = extra["silo_dp"]
-            if cfg.extra["fused_blocks"]:
+            if cfg.extra["aot_programs"]:
                 a += 1
             cfg.extra["comm_topk_ratio"]  # statement position: report-only
             cfg.extra["mlp_hidden"] = 3   # write target: blessed-write rewrite
@@ -125,7 +125,7 @@ def test_fix_rewrites_value_position_subscript(tmp_path):
     assert n == 4, fixed
     assert "cfg_extra(cfg, 'mlp_hidden', None)" in fixed
     assert "cfg_extra(cfg, 'silo_dp', None)" in fixed
-    assert "cfg_extra(cfg, 'fused_blocks', None)" in fixed
+    assert "cfg_extra(cfg, 'aot_programs', None)" in fixed
     assert 'cfg.extra["comm_topk_ratio"]' in fixed  # statement form survives
     assert "set_cfg_extra(cfg, 'mlp_hidden', 3)" in fixed  # store: rewritten
     assert "from fedml_tpu.core.flags import cfg_extra, set_cfg_extra" in fixed
@@ -166,7 +166,7 @@ def test_fix_rewrites_value_position_setdefault(tmp_path):
             a = cfg.extra.setdefault("mlp_hidden", 64)
             extra = cfg.extra
             b = extra.setdefault("silo_dp")
-            if extra.setdefault("fused_blocks", False):
+            if extra.setdefault("aot_programs", False):
                 a += 1
             cfg.extra.setdefault("comm_topk_ratio", 0.1)  # statement form
             return a, b
@@ -175,7 +175,7 @@ def test_fix_rewrites_value_position_setdefault(tmp_path):
     assert n == 4, fixed
     assert "cfg_extra(cfg, 'mlp_hidden', 64)" in fixed
     assert "cfg_extra(cfg, 'silo_dp', None)" in fixed
-    assert "cfg_extra(cfg, 'fused_blocks', False)" in fixed
+    assert "cfg_extra(cfg, 'aot_programs', False)" in fixed
     # the statement-position seed becomes an explicit seed through the
     # registry-checked write (ISSUE 20: set_cfg_extra replaces the raw store)
     assert ("set_cfg_extra(cfg, 'comm_topk_ratio', "
@@ -264,7 +264,7 @@ def test_fix_rewrites_membership_tests():
             a = "mlp_hidden" in cfg.extra
             extra = cfg.extra
             b = "silo_dp" not in extra
-            if "fused_blocks" in (getattr(cfg, "extra", {}) or {}):
+            if "aot_programs" in (getattr(cfg, "extra", {}) or {}):
                 a = not a
             return a, b
     ''')
@@ -274,7 +274,7 @@ def test_fix_rewrites_membership_tests():
     assert "from fedml_tpu.core.flags import cfg_extra_present" in fixed
     assert "a = cfg_extra_present(cfg, 'mlp_hidden')" in fixed
     assert "b = (not cfg_extra_present(cfg, 'silo_dp'))" in fixed
-    assert "if cfg_extra_present(cfg, 'fused_blocks'):" in fixed
+    assert "if cfg_extra_present(cfg, 'aot_programs'):" in fixed
     compile(fixed, "mod.py", "exec")
     again, n2, _ = fix_source(fixed, "mod.py")
     assert n2 == 0 and again == fixed  # idempotent

@@ -4,7 +4,6 @@ exercised on the real chip by the round driver's bench/verify runs)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 
 def test_quantize_kernel_matches_reference(eight_devices):
@@ -52,212 +51,27 @@ def test_quantize_kernel_edge_shapes(eight_devices):
         assert float(jnp.abs(back - x).max()) <= float(s.max()) + 1e-6
 
 
-# -- fused BasicBlock epilogue kernel (ops/pallas/fused_block.py) ------------
-
-def _fused_inputs(shape, dtype=jnp.float32, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    y = jax.random.normal(ks[0], shape, dtype)
-    r = jax.random.normal(ks[1], shape, dtype)
-    s = jax.random.normal(ks[2], (shape[-1],), jnp.float32)
-    b = jax.random.normal(ks[3], (shape[-1],), jnp.float32)
-    g = jax.random.normal(ks[4], shape, dtype)
-    return y, r, s, b, g
-
-
-# 3024 elements (padded tail), exact block multiple, and the three flagship
-# channel widths
-_FUSED_SHAPES = [(3, 7, 9, 16), (4, 8, 8, 32), (2, 5, 5, 64)]
-
-
-def test_fused_block_fwd_bitwise_f32(eight_devices):
-    """Jitted interpret-mode kernel == jitted pure-jnp reference, bitwise.
-
-    Both sides jitted: eager-vs-jitted comparison differs in the final ulp
-    because XLA contracts mul+add to FMA only when it compiles the whole
-    expression — the production paths (local SGD scan, eval) are always
-    jitted, so that is the contract worth pinning."""
-    from functools import partial
-
-    from fedml_tpu.ops.pallas import (
-        fused_block_reference, fused_bn_relu, fused_bn_residual_relu,
-    )
-
-    for shape in _FUSED_SHAPES:
-        y, r, s, b, _ = _fused_inputs(shape)
-        out = jax.jit(partial(fused_bn_residual_relu, interpret=True))(y, s, b, r)
-        ref = jax.jit(fused_block_reference)(y, s, b, r)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-        out2 = jax.jit(partial(fused_bn_relu, interpret=True))(y, s, b)
-        ref2 = jax.jit(fused_block_reference)(y, s, b)
-        np.testing.assert_array_equal(np.asarray(out2), np.asarray(ref2))
-
-
-def test_fused_block_grad_parity_f32(eight_devices):
-    """Fused custom-VJP backward vs autodiff of the reference: the
-    elementwise cotangents (dy, dresidual) are bitwise; the per-channel
-    reductions (dscale, dshift) accumulate blockwise in the kernel vs one
-    flat XLA reduce in the reference — different f32 association, so those
-    are pinned to 1e-5."""
-    from functools import partial
-
-    from fedml_tpu.ops.pallas import fused_block_reference, fused_bn_residual_relu
-
-    for shape in _FUSED_SHAPES:
-        y, r, s, b, g = _fused_inputs(shape)
-
-        def loss_k(y, s, b, r):
-            return jnp.sum(fused_bn_residual_relu(y, s, b, r, interpret=True) * g)
-
-        def loss_r(y, s, b, r):
-            return jnp.sum(fused_block_reference(y, s, b, r) * g)
-
-        dy, ds, db, dr = jax.jit(jax.grad(loss_k, argnums=(0, 1, 2, 3)))(y, s, b, r)
-        dyr, dsr, dbr, drr = jax.jit(jax.grad(loss_r, argnums=(0, 1, 2, 3)))(y, s, b, r)
-        np.testing.assert_array_equal(np.asarray(dy), np.asarray(dyr))
-        np.testing.assert_array_equal(np.asarray(dr), np.asarray(drr))
-        np.testing.assert_allclose(np.asarray(ds), np.asarray(dsr), rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(db), np.asarray(dbr), rtol=1e-5, atol=1e-5)
-
-
-def test_fused_block_bf16_tolerance(eight_devices):
-    """bf16 activations: kernel computes the epilogue in f32 internally and
-    casts once at the end, so it is at least as accurate as the reference's
-    own bf16 output — compare both to the f32 ground truth."""
-    from functools import partial
-
-    from fedml_tpu.ops.pallas import fused_block_reference, fused_bn_residual_relu
-
-    shape = (4, 8, 8, 32)
-    y32, r32, s, b, g = _fused_inputs(shape)
-    y16, r16 = y32.astype(jnp.bfloat16), r32.astype(jnp.bfloat16)
-    out = jax.jit(partial(fused_bn_residual_relu, interpret=True))(y16, s, b, r16)
-    assert out.dtype == jnp.bfloat16
-    truth = fused_block_reference(y16.astype(jnp.float32), s, b, r16.astype(jnp.float32))
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(truth), rtol=1e-2, atol=1e-2
-    )
-    # grads exist and are finite in bf16
-    dy = jax.jit(jax.grad(lambda yy: jnp.sum(
-        fused_bn_residual_relu(yy, s, b, r16, interpret=True).astype(jnp.float32))))(y16)
-    assert dy.dtype == jnp.bfloat16
-    assert bool(jnp.isfinite(dy.astype(jnp.float32)).all())
-
-
-def test_fused_block_vmap(eight_devices):
-    """vmap (the local-SGD client dimension) must agree with per-example
-    calls — in particular the bwd accumulator tile must stay per-example
-    under the pallas batching rule's prepended grid axis."""
-    from functools import partial
-
-    from fedml_tpu.ops.pallas import fused_bn_residual_relu
-
-    y, r, s, b, g = _fused_inputs((3, 7, 9, 16))
-    yv = jnp.stack([y, y * 0.5, -y])
-    rv = jnp.stack([r, -r, r * 2.0])
-    gv = jnp.stack([g, g, g])
-
-    def one(y, r, g):
-        out, pull = jax.vjp(
-            lambda yy, rr: fused_bn_residual_relu(yy, s, b, rr, interpret=True), y, r)
-        return out, pull(g)
-
-    outs, (dys, drs) = jax.jit(jax.vmap(one))(yv, rv, gv)
-    for i in range(3):
-        out_i, (dy_i, dr_i) = jax.jit(one)(yv[i], rv[i], gv[i])
-        np.testing.assert_array_equal(np.asarray(outs[i]), np.asarray(out_i))
-        np.testing.assert_array_equal(np.asarray(dys[i]), np.asarray(dy_i))
-        np.testing.assert_array_equal(np.asarray(drs[i]), np.asarray(dr_i))
-
-
-def test_fused_resnet_tree_identical_and_close(eight_devices):
-    """The fused model is a drop-in: identical variable tree (names, shapes,
-    init values) and numerically equivalent forward/backward."""
-    from fedml_tpu.models import resnet
-
-    x = jax.random.normal(jax.random.PRNGKey(3), (4, 32, 32, 3), jnp.float32)
-    k = jax.random.PRNGKey(0)
-    m_u = resnet.CifarResNet(num_blocks=1)
-    m_f = resnet.CifarResNet(num_blocks=1, fused=True)
-    v_u = m_u.init({"params": k, "dropout": k}, x, train=True)
-    v_f = m_f.init({"params": k, "dropout": k}, x, train=True)
-    assert jax.tree_util.tree_structure(v_u) == jax.tree_util.tree_structure(v_f)
-    for a, b in zip(jax.tree_util.tree_leaves(v_u), jax.tree_util.tree_leaves(v_f)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    lu, su = jax.jit(lambda v: m_u.apply(v, x, train=True, mutable=["batch_stats"]))(v_u)
-    lf, sf = jax.jit(lambda v: m_f.apply(v, x, train=True, mutable=["batch_stats"]))(v_f)
-    np.testing.assert_allclose(np.asarray(lu), np.asarray(lf), rtol=1e-5, atol=1e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(su), jax.tree_util.tree_leaves(sf)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
-
-    def loss(m, v):
-        logits, _ = m.apply(v, x, train=True, mutable=["batch_stats"])
-        return jnp.mean((logits.astype(jnp.float32) - 1.0) ** 2)
-
-    gu = jax.jit(jax.grad(lambda p: loss(m_u, {"params": p, "batch_stats": v_u["batch_stats"]})))(v_u["params"])
-    gf = jax.jit(jax.grad(lambda p: loss(m_f, {"params": p, "batch_stats": v_f["batch_stats"]})))(v_f["params"])
-    for a, b in zip(jax.tree_util.tree_leaves(gu), jax.tree_util.tree_leaves(gf)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-5)
-
-
-def test_fused_sim_smoke_loss_parity(eight_devices, make_tiny_config):
-    """One MeshSimulator round, fused vs unfused, identical recipe/seed: the
-    losses and the post-round global params must agree — the end-to-end pin
-    that the fused custom-VJP composes with vmapped clients, the step scan
-    and the round program."""
-    import fedml_tpu
-    from fedml_tpu.data import loader
-    from fedml_tpu.models import resnet
-    from fedml_tpu.parallel import mesh as meshlib
-    from fedml_tpu.sim.engine import MeshSimulator
-
-    cfg = make_tiny_config(
-        dataset="cifar10", model="resnet20", client_num_in_total=4,
-        client_num_per_round=2, batch_size=8, synthetic_train_size=64,
-        synthetic_test_size=64, frequency_of_the_test=0,
-    )
-    fedml_tpu.init(cfg)
-    mesh = meshlib.make_mesh((meshlib.AXIS_CLIENTS,), (2,), jax.devices()[:2])
-    ds = loader.load(cfg)
-    results = {}
-    for fused in (False, True):
-        model = resnet.CifarResNet(num_blocks=1, num_classes=ds.class_num, fused=fused)
-        sim = MeshSimulator(cfg, ds, model, mesh=mesh)
-        metrics = sim.run_round()
-        results[fused] = (metrics, jax.device_get(sim.global_vars))
-    mu, vu = results[False]
-    mf, vf = results[True]
-    assert np.isfinite(mu["train_loss"]) and np.isfinite(mf["train_loss"])
-    np.testing.assert_allclose(mu["train_loss"], mf["train_loss"], rtol=1e-4)
-    for a, b in zip(jax.tree_util.tree_leaves(vu), jax.tree_util.tree_leaves(vf)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=1e-5)
-
-
 def test_pallas_kernel_seconds_histogram(eight_devices):
     """Eager kernel invocations land in the process-global
     ``fedml_pallas_kernel_seconds`` histogram (labels=kernel) and surface in
     both the Prometheus rendering and the bench summary helper."""
     from fedml_tpu.obs.registry import REGISTRY
-    from fedml_tpu.ops.pallas import (
-        fused_bn_relu, kernel_time_summary, quantize_int8_stochastic,
-    )
+    from fedml_tpu.ops.pallas import kernel_time_summary, quantize_int8_stochastic
 
     hist = REGISTRY.get("fedml_pallas_kernel_seconds")
     assert hist is not None
-    before = hist.count(kernel="fused_bn_relu")
-    y, _, s, b, _ = _fused_inputs((2, 4, 4, 16))
-    fused_bn_relu(y, s, b, interpret=True)  # eager -> observed
-    assert hist.count(kernel="fused_bn_relu") == before + 1
-    quantize_int8_stochastic(jnp.ones(2048), jax.random.PRNGKey(0), interpret=True)
-    assert hist.count(kernel="quantize_int8_stochastic") >= 1
+    before = hist.count(kernel="quantize_int8_stochastic")
+    x, key = jnp.ones(2048), jax.random.PRNGKey(0)
+    quantize_int8_stochastic(x, key, interpret=True)  # eager -> observed
+    assert hist.count(kernel="quantize_int8_stochastic") == before + 1
     summary = kernel_time_summary()
-    assert summary["fused_bn_relu"]["count"] >= 1
+    assert summary["quantize_int8_stochastic"]["count"] >= 1
     assert "fedml_pallas_kernel_seconds_bucket" in REGISTRY.render()
     # traced invocations are NOT host-timed (wall clock there measures
     # tracing, not the kernel)
-    n = hist.count(kernel="fused_bn_relu")
-    jax.jit(lambda yy: fused_bn_relu(yy, s, b, interpret=True))(y)
-    assert hist.count(kernel="fused_bn_relu") == n
+    n = hist.count(kernel="quantize_int8_stochastic")
+    jax.jit(lambda xx: quantize_int8_stochastic(xx, key, interpret=True)[:2])(x)
+    assert hist.count(kernel="quantize_int8_stochastic") == n
 
 
 def test_pallas_kernel_sink_and_report_section(eight_devices):
@@ -265,23 +79,21 @@ def test_pallas_kernel_sink_and_report_section(eight_devices):
     client ships them as metric records), and ``obs report`` renders those
     records as a per-kernel summary table."""
     from fedml_tpu.obs import report as obs_report
-    from fedml_tpu.ops.pallas import fused_bn_relu
-    from fedml_tpu.ops.pallas import timing
+    from fedml_tpu.ops.pallas import quantize_int8_stochastic, timing
 
     records = []
     sink = timing.add_sink(lambda k, s: records.append(
         {"kind": "metric", "metric": "pallas_kernel_seconds", "kernel": k, "value": s}))
     try:
-        y, _, s, b, _ = _fused_inputs((2, 4, 4, 16))
-        fused_bn_relu(y, s, b, interpret=True)
+        quantize_int8_stochastic(jnp.ones(2048), jax.random.PRNGKey(0), interpret=True)
     finally:
         timing.remove_sink(sink)
-    assert records and records[0]["kernel"] == "fused_bn_relu"
+    assert records and records[0]["kernel"] == "quantize_int8_stochastic"
     stats = obs_report.pallas_kernel_stats(records)
-    assert stats[0]["kernel"] == "fused_bn_relu" and stats[0]["n"] == len(records)
+    assert stats[0]["kernel"] == "quantize_int8_stochastic" and stats[0]["n"] == len(records)
     trail = records + [{"kind": "span", "name": "round", "trace_id": "t",
                         "span_id": "s1", "round_idx": 0, "ts": 1.0, "dur_s": 1.0}]
     text = obs_report.render_report(trail)
-    assert "pallas kernels" in text and "fused_bn_relu" in text
+    assert "pallas kernels" in text and "quantize_int8_stochastic" in text
     # a trail with no kernel records renders no (empty) kernel section
     assert "pallas kernels" not in obs_report.render_report(trail[-1:])

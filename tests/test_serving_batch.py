@@ -48,6 +48,14 @@ def _batcher(pred, **kw):
     return MicroBatcher(pred, **kw)
 
 
+def _await(cond, what, deadline_s=5.0):
+    """Poll the batcher's own state instead of sleeping a guessed interval."""
+    end = time.monotonic() + deadline_s
+    while not cond():
+        assert time.monotonic() < end, f"timed out waiting for {what}"
+        time.sleep(0.002)
+
+
 # ---------------------------------------------------------------------------
 # micro-batcher semantics
 # ---------------------------------------------------------------------------
@@ -98,11 +106,12 @@ def test_backpressure_queue_overflow_is_explicit():
     retry-after hint — bounded memory, explicit 503, never silent growth."""
     from fedml_tpu.serving.batcher import QueueOverflow
 
-    pred = StubPredictor(1.0, max_batch=1, delay_s=0.2)
+    pred = StubPredictor(1.0, max_batch=1, delay_s=0.5)
     b = _batcher(pred, max_batch=1, max_queue=2, flush_ms=0.0)
     try:
         b.submit(np.zeros((1, 4)))  # occupies the device
-        time.sleep(0.05)            # let the dispatcher pick it up
+        _await(lambda: pred.calls == 1 and b.stats()["queue_depth"] == 0,
+               "the dispatcher to pick it up")
         b.submit(np.zeros((1, 4)))
         b.submit(np.zeros((1, 4)))
         with pytest.raises(QueueOverflow) as exc:
@@ -124,7 +133,7 @@ def test_http_backpressure_maps_to_503_retry_after(eight_devices):
     from fedml_tpu.serving.inference import FedMLInferenceRunner
     from fedml_tpu.serving.publisher import HotSwapController
 
-    pred = StubPredictor(3.0, max_batch=1, delay_s=0.3)
+    pred = StubPredictor(3.0, max_batch=1, delay_s=1.0)
     ctl = HotSwapController(pred, version=5)
     b = _batcher(pred, controller=ctl, max_batch=1, max_queue=1, flush_ms=0.0)
     runner = FedMLInferenceRunner(pred, port=0, batcher=b, stats_fn=b.stats)
@@ -139,9 +148,11 @@ def test_http_backpressure_maps_to_503_retry_after(eight_devices):
 
         first = threading.Thread(target=lambda: post().read())
         first.start()
-        time.sleep(0.05)
+        _await(lambda: pred.calls == 1 and b.stats()["queue_depth"] == 0,
+               "the dispatcher to take the first request")
         threading.Thread(target=lambda: post().read(), daemon=True).start()
-        time.sleep(0.05)
+        _await(lambda: b.stats()["queue_depth"] == b.stats()["max_queue"],
+               "the second request to fill the queue")
         with pytest.raises(urllib.error.HTTPError) as exc:
             post()
         assert exc.value.code == 503
