@@ -307,6 +307,31 @@ def make_full_grad_fn(model, hp: HParams):
     return full_grad
 
 
+# Elements ONE evaluation step may take in and put out: 512 CIFAR-10 samples,
+# each 32 x 32 x 3 of input and 10 logits.  Chosen on the chip (PERF.md section
+# 6, PR 32): ResNet-20 over 10,000 test images takes as long as the 128-sample
+# tiles its steps fill, so 313 x 32 reads 61.4 ms and 79 x 128 13.1; 20 x 504
+# (12.4-12.7 ms) is the smallest batch within 5% of the best (10 x 1,000, 12.3),
+# and from 1,432 up the step's activations leave fast memory (5 x 2,000: 14.6).
+EVAL_STEP_ELEMENTS = 512 * (32 * 32 * 3 + 10)
+
+
+def eval_batch_size(n_test: int, sample_elements: int, floor: int, lanes: int = 1) -> int:
+    """Batch of the evaluation scan (``make_eval_fn``) over ``n_test`` samples
+    of ``sample_elements`` each (a sample's input elements plus its logits':
+    a token is small going in and a vocabulary wide coming out): the fewest
+    EQUAL steps that each stay under ``EVAL_STEP_ELEMENTS``, the batch
+    rounded up to a multiple of 8 so that ``pad_eval_set`` adds a few samples
+    and not a step, and never under ``floor``.  ``lanes`` counts the model
+    copies a vmapped evaluation scores in one step.  What ``evaluate``
+    returns does not depend on its batch (running statistics, a masked sum
+    over the count); its time does."""
+    most = max(EVAL_STEP_ELEMENTS // (sample_elements * lanes), 1)
+    steps = -(-n_test // most)
+    batch = -(-n_test // steps)
+    return max(-(-batch // 8) * 8, floor)
+
+
 def make_eval_fn(model, hp: HParams, batch_size: int = 256):
     """Global test eval: batched scan over a (padded) test set with a
     validity mask; returns (loss, accuracy) — the TPU form of

@@ -52,7 +52,7 @@ from ..arguments import Config
 from ..core import aot as aotlib, pytree as pt, rng
 from ..core.flags import cfg_extra
 from ..data.dataset import FederatedDataset, StackedClientData, pad_eval_set, stack_clients
-from ..fl.local_sgd import make_eval_fn, own_step_budget
+from ..fl.local_sgd import eval_batch_size, make_eval_fn, own_step_budget
 from ..parallel import mesh as meshlib
 from ..obs import otlp as obsotlp, registry as obsreg
 from ..obs.metrics import MetricsLogger
@@ -218,7 +218,13 @@ class MeshSimulator(RoundCheckpointMixin):
             self.client_states = None
 
         # ---- test data (tiled to eval batch multiple) ----
-        eval_bs = min(256, max(32, cfg.test_batch_size))
+        # the batch follows the test set and what a sample is to the model (its
+        # input and its logits); the clients' batch is only its floor
+        probe = jax.ShapeDtypeStruct((1,) + dataset.test_x.shape[1:], dataset.test_x.dtype)
+        logits = jax.eval_shape(lambda v, x: model.apply(v, x, train=False), self.global_vars, probe)
+        eval_bs = eval_batch_size(
+            dataset.test_x.shape[0], math.prod(probe.shape) + math.prod(logits.shape),
+            floor=int(np.clip(cfg.test_batch_size, 32, 256)), lanes=self._eval_lanes())
         tx, ty, n_test = pad_eval_set(dataset.test_x, dataset.test_y, eval_bs)
         # placed like global_vars (replicated over the mesh): left on the
         # default device, every evaluate() would re-stage the test set from
@@ -234,7 +240,8 @@ class MeshSimulator(RoundCheckpointMixin):
                 self._eval_fn = self._aot.cached_jit(
                     eval_fn, (self.global_vars, *self._test),
                     key=self._aot_key("sim.eval", trees={
-                        "global_vars": self.global_vars, "test": self._test}),
+                        "global_vars": self.global_vars, "test": self._test},
+                        extra={"eval_batch": eval_bs}),
                 )
             else:
                 self._eval_fn = jax.jit(eval_fn)
@@ -287,6 +294,11 @@ class MeshSimulator(RoundCheckpointMixin):
         axis = (meshlib.AXIS_CLIENTS if meshlib.AXIS_CLIENTS in self.mesh.shape
                 else self.mesh.axis_names[0])
         return axis, int(self.mesh.shape[axis])
+
+    def _eval_lanes(self) -> int:
+        """Model copies ONE step of an evaluation program scores (the engine
+        evaluates the global model; MyAvg also vmaps every personal one)."""
+        return 1
 
     def _bucket_count(self, counts) -> int:
         """How many lane buckets the round program runs one after another;
@@ -960,8 +972,9 @@ class MeshSimulator(RoundCheckpointMixin):
     # ------------------------------------------------------------------
     def evaluate(self) -> dict:
         t0 = time.perf_counter()
-        with traced("sim.eval", counters=XLA_COUNTERS,
-                    round_idx=self.round_idx, sink=self._otlp_sink):
+        with traced("sim.eval", counters=XLA_COUNTERS, round_idx=self.round_idx,
+                    eval_batch=self._eval_bs, eval_steps=self._test[0].shape[0] // self._eval_bs,
+                    sink=self._otlp_sink):
             with traced("sim.eval.dispatch"):
                 res = self._eval_fn(self.global_vars, *self._test)
             with traced("sim.eval.sync"):

@@ -286,6 +286,11 @@ class MyAvgSimulator(MeshSimulator):
         self._multi_round_fns = {}
 
     # ------------------------------------------------------------------
+    def _eval_lanes(self) -> int:
+        # evaluate_personalized vmaps make_eval_fn over every real client's
+        # model at the engine's batch: a step scores lanes x batch samples
+        return self._n_real
+
     def _config_id(self, round_idx):
         """First ``agg_mod_list`` entry dividing ``round_idx`` wins; round 0
         always uses the default filter (``MyAvgAPI_7.py:242-247``)."""
