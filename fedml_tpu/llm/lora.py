@@ -31,9 +31,15 @@ import numpy as np
 from flax import traverse_util
 
 DEFAULT_TARGETS = r".*attn/w[qkvo]/kernel"
-#: kernels that contract all but their LAST dim ((heads, head_dim, d_model));
-#: every other kernel contracts its first
+#: the five projections of a latent-attention mixer (``MLAttention``)
+MLA_TARGETS = r".*attn/w(q_a|q_b|kv_a|kv_b|o)/kernel"
+#: kernels that contract all but their LAST dim ((heads, head_dim, d_model),
+#: the head_dim being the values' own under latent attention); every other
+#: kernel contracts its first
 _FAN_IN_ALL_BUT_LAST = r".*attn/wo/kernel"
+#: the held experts' stacked kernels (experts, in, out): one factor pair
+#: would read the number of experts as the fan-in
+_STACKED = r".*moe/experts/w_(gate|up|down)"
 
 
 def _fan_in(path: str, shape) -> int:
@@ -58,6 +64,11 @@ def init_lora(params, rank: int, key: jax.Array, targets: str = DEFAULT_TARGETS,
     """Adapter tree keyed by 'path/with/slashes' -> {a, b}."""
     lora = {}
     for i, (path, shape, _) in enumerate(_match_paths(params, targets)):
+        if re.fullmatch(_STACKED, path):
+            raise ValueError(
+                f"LoRA targets {targets!r} match {path!r}, a stack of expert kernels {tuple(shape)}: "
+                "an adapter pair per expert is not implemented, and one pair over the stack "
+                "would take the number of experts for the fan-in")
         d_in = _fan_in(path, shape)
         d_out = int(np.prod(shape)) // d_in
         ka = jax.random.fold_in(key, 2 * i)

@@ -30,9 +30,10 @@ import numpy as np
 import optax
 
 from ..core import rng
-from ..models.transformer import Transformer, TransformerConfig
+from ..models.transformer import MOE_STATS, Transformer, TransformerConfig
 from ..obs.metrics import MetricsLogger
-from ..obs.trace import LLM_ATTENDED_KEYS, XLA_COUNTERS, install_xla_listener, traced
+from ..obs.trace import (LLM_ATTENDED_KEYS, LLM_EXPERT_TOKENS, XLA_COUNTERS, install_xla_listener,
+                         traced)
 from ..parallel import mesh as meshlib, sharding
 from . import lora as lora_lib
 
@@ -58,6 +59,9 @@ class LLMTrainArgs:
     lora_rank: int = 0
     lora_alpha: float = 16.0
     lora_targets: str = lora_lib.DEFAULT_TARGETS
+    # weight of the multi-token-prediction loss, where the model has such a
+    # module (``TransformerConfig.mtp_layers``): loss = main + mtp_weight * mtp
+    mtp_weight: float = 0.3
 
 
 class LLMTrainer:
@@ -138,8 +142,13 @@ class LLMTrainer:
                            {k: scalar_sh for k in self._metric_names()}),
         )
 
+    def _sown_names(self) -> tuple:
+        """What the model's layers sow into collection ``stats``."""
+        return ((ATTENDED if self.cfg.has_sparse_layers else ())
+                + (MOE_STATS if self.cfg.has_expert_layers else ()))
+
     def _metric_names(self) -> tuple:
-        return ("loss", "ppl") + (ATTENDED if self.cfg.has_sparse_layers else ())
+        return ("loss", "ppl") + self._sown_names() + (("mtp_loss",) if self.cfg.mtp_layers else ())
 
     def _make_train_step(self):
         """``(params, opt_state, tokens, targets)``, or with adapters
@@ -148,8 +157,10 @@ class LLMTrainer:
         model = self.model
         opt = self.opt
         args = self.args
-        attended = self.cfg.has_sparse_layers
-        chunked = self.cfg.loss_chunk > 0
+        sown_names = self._sown_names()
+        mtp = self.cfg.mtp_layers > 0
+        # the MTP module's loss needs the targets inside the model too
+        chunked = self.cfg.loss_chunk > 0 or mtp
 
         def loss_fn(trained, base, tokens, targets):
             variables = {"params": trained} if base is None else {
@@ -159,13 +170,17 @@ class LLMTrainer:
             # with a loss_chunk the model takes the targets and returns the
             # per-token losses: the whole logits matrix never exists
             kw = {"targets": targets} if chunked else {}
-            if attended:  # the sparse layers sow what they attended
+            if sown_names:  # sparse layers sow what they attended, expert layers their routing
                 out, sown = model.apply(variables, tokens, train=True, mutable=["stats"], **kw)
-                for name in ATTENDED:
+                for name in sown_names:
                     stats[name] = sum(v for path, v in jax.tree_util.tree_leaves_with_path(sown)
                                       if path[-1].key == name)
             else:
                 out = model.apply(variables, tokens, train=True, **kw)
+            if mtp:  # the last position has no token after next: the mean is over the others
+                out, after_next = out
+                stats["mtp_loss"] = after_next.sum() / (after_next.size - after_next.shape[0])
+                return out.mean() + args.mtp_weight * stats["mtp_loss"], stats
             if chunked:
                 return out.mean(), stats
             with jax.named_scope("llm.head_loss"):
@@ -182,7 +197,8 @@ class LLMTrainer:
             with jax.named_scope("llm.optimizer"):
                 updates, opt_state = opt.update(grads, opt_state, trained)
                 trained = optax.apply_updates(trained, updates)
-            return trained, opt_state, {"loss": loss, "ppl": jnp.exp(loss), **stats}
+            main = loss - args.mtp_weight * stats["mtp_loss"] if mtp else loss
+            return trained, opt_state, {"loss": loss, "ppl": jnp.exp(main), **stats}
 
         if self.lora is None:
             return lambda params, opt_state, tokens, targets: update(
@@ -216,7 +232,9 @@ class LLMTrainer:
         ``llm.dispatch``, ``llm.sync``) and ``llm.log``.  A model with
         block-sparse layers also says what they attended: ``sparse_kept`` and
         ``sparse_causal`` in each history entry, as attributes of ``llm.step``
-        and in ``fedml_llm_attended_keys_total``."""
+        and in ``fedml_llm_attended_keys_total``; one with expert layers how
+        its tokens were routed: ``moe_assignments``, ``moe_held`` and
+        ``moe_max_load`` likewise, and ``fedml_llm_expert_tokens_total``."""
         history = []
         steps = steps or self.args.total_steps
         batches = iter(batch_iter)
@@ -235,6 +253,10 @@ class LLMTrainer:
                         span.attrs.update({k: m[k] for k in ATTENDED})
                         for name, kind in zip(ATTENDED, ("kept", "causal")):
                             LLM_ATTENDED_KEYS.inc(m[name], kind=kind)
+                    if MOE_STATS[0] in m:
+                        span.attrs.update({k: m[k] for k in MOE_STATS})
+                        for name, kind in zip(MOE_STATS, ("routed", "held")):
+                            LLM_EXPERT_TOKENS.inc(m[name], kind=kind)
                 with traced("llm.log"):
                     self.logger.log(m)
                 history.append(m)

@@ -12,9 +12,18 @@ softmax GQA ``Attention`` above, ``LightningAttention`` (linear attention with
 a per-head decay, ``ops/lightning_attention.py``) or ``SparseAttention``
 (InfLLM-v2 block-sparse softmax attention, ``ops/sparse_attention.py``), with
 QK-norm, output gates, an output norm and the muP scalars of the MiniCPM
-family (embedding, residual branches, logits).  Every mixer names its
+family (embedding, residual branches, logits).  Those mixers name their
 projections ``attn/wq|wk|wv|wo/kernel``, so the LoRA targets and the sharding
-rules are the same for all.  The defaults build the plain model.
+rules are the same for all.  A fourth mixer, ``MLAttention``, is the latent
+attention of the DeepSeek-V3 / openPangu-Ultra family (two low-rank paths with
+their own norms, a rotary part beside a non-rotary one, a value width of its
+own: ``attn/wq_a|wq_b|wkv_a|wkv_b|wo/kernel``).  Layers from ``first_k_dense``
+on may have a sparse expert layer (``MoE``, ``ops/moe.py``: a router over all
+``n_routed_experts``, the ``experts_held`` this chip holds, shared experts) in
+the dense SwiGLU's place; ``sandwich_norm`` norms each branch's output as well
+as its input; ``mtp_layers`` adds a multi-token-prediction module (``MTP``)
+whose loss ``Transformer.__call__(targets=...)`` returns beside the main one.
+The defaults build the plain model.
 """
 
 from __future__ import annotations
@@ -74,6 +83,39 @@ class TransformerConfig:
     # given the targets and returns the per-token loss (0 = all of them): a
     # 16k x 73k float32 logits matrix alone is 4.8 GB
     loss_chunk: int = 0
+    # "mla": latent attention (``MLAttention``); the head widths of its
+    # non-rotary and rotary query/key parts and of its values
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # sparse expert layers (``MoE``) from layer first_k_dense on, where
+    # n_routed_experts > 0: the router has n_routed_experts outputs and picks
+    # top_k of them; this chip holds experts first_expert .. first_expert +
+    # experts_held - 1 (0 held = all of them) and adds their part alone
+    first_k_dense: int = 0
+    n_routed_experts: int = 0
+    experts_held: int = 0
+    first_expert: int = 0
+    top_k: int = 0
+    n_shared_experts: int = 0
+    moe_d_ff: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = False
+    # x + N2(mixer(N1(x))), x + N4(ffn(N3(x))): a norm after each branch too
+    sandwich_norm: bool = False
+    # multi-token-prediction modules after the last layer (0 or 1)
+    mtp_layers: int = 0
+
+    def has_experts(self, layer: int) -> bool:
+        return self.n_routed_experts > 0 and layer >= self.first_k_dense
+
+    @property
+    def has_expert_layers(self) -> bool:
+        """Any expert layer, the MTP module's block included (it follows the
+        last layer, so it is one wherever the model has any)."""
+        return self.n_routed_experts > 0 and (self.mtp_layers > 0 or self.first_k_dense < self.n_layers)
 
     def mixer(self, layer: int) -> str:
         kind = self.mixer_types[layer] if self.mixer_types else "attention"
@@ -256,21 +298,142 @@ class SparseAttention(nn.Module):
         return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
 
 
-MIXERS = {"attention": Attention, "lightning-attn": LightningAttention, "minicpm4": SparseAttention}
+#: heads a latent-attention mixer attends at a time: the blockwise pass's
+#: float32 accumulators and chunked copies of q, k, v scale with the heads it
+#: is given (2.6 GB for 128 heads x 8,192 tokens, a quarter of that for 32)
+MLA_HEAD_GROUP = 32
+
+
+def _by_head_groups(fn, group: int, q, k, v):
+    """``fn(q, k, v)`` over (b, s, heads, d) operands, ``group`` heads at a
+    time, one group after another."""
+    h = q.shape[2]
+    if h <= group:
+        return fn(q, k, v)
+    return jnp.concatenate([fn(*(t[:, :, i: i + group] for t in (q, k, v)))
+                            for i in range(0, h, group)], axis=2)
+
+
+class MLAttention(nn.Module):
+    """Multi-head latent attention in its expanded (training) form: queries
+    through a low-rank path ``c_q = N_q(x W_dq)``, ``[q_n | q_r] = c_q W_uq``;
+    keys and values through another, ``[c_kv | k_r] = x W_dkv``, ``[k_n | v] =
+    N_kv(c_kv) W_ukv``; RoPE on ``q_r`` and on the ONE ``k_r`` all heads share;
+    causal softmax attention of ``[q_n | q_r]`` over ``[k_n | k_r]`` scaled by
+    the whole query width, values of their own width; ``W_o`` over (heads x
+    v_head_dim).  Blockwise (``ops/sparse_attention.block_sparse_attention``,
+    every block kept): 128 heads' (s, s) scores are never whole."""
+
+    cfg: TransformerConfig
+    mesh: Optional[Any] = None
+    seq_axis: Optional[str] = None
+
+    @nn.compact
+    def __call__(self, x, positions):
+        from ..ops.sparse_attention import CHUNK, block_sparse_attention
+
+        _no_seq_axis(self)
+        cfg = self.cfg
+        h, nope, rot, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        c_q = RMSNorm(cfg.norm_eps, name="q_a_norm")(_project(self, "wq_a", x, cfg.q_lora_rank))
+        q = _project(self, "wq_b", c_q.astype(cfg.dtype), (h, nope + rot))
+        kv_a = _project(self, "wkv_a", x, cfg.kv_lora_rank + rot)
+        c_kv = RMSNorm(cfg.norm_eps, name="kv_a_norm")(kv_a[..., : cfg.kv_lora_rank])
+        kv = _project(self, "wkv_b", c_kv.astype(cfg.dtype), (h, nope + dv))
+        k_r = rope(kv_a[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)   # (b, s, 1, rot)
+        q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_r, k_r.shape[:2] + (h, rot))], -1)
+        attend = lambda q, k, v: block_sparse_attention(q, k, v, None, q_chunk=CHUNK, k_chunk=CHUNK,
+                                                        scale=(nope + rot) ** -0.5)
+        with jax.named_scope("llm.mixer.mla"):
+            out = _by_head_groups(attend, MLA_HEAD_GROUP, q, k, kv[..., nope:])
+        return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
+
+
+MIXERS = {"attention": Attention, "lightning-attn": LightningAttention, "minicpm4": SparseAttention,
+          "mla": MLAttention}
 
 
 class MLP(nn.Module):
+    cfg: TransformerConfig
+    d_ff: int = 0  # 0 = cfg.d_ff
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        d_ff = self.d_ff or cfg.d_ff
+        with jax.named_scope("llm.mlp"):
+            gate = nn.Dense(d_ff, use_bias=False, dtype=cfg.dtype, name="w_gate")(x)
+            up = nn.Dense(d_ff, use_bias=False, dtype=cfg.dtype, name="w_up")(x)
+            return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype, name="w_down")(
+                nn.silu(gate) * up
+            )
+
+
+#: what a model with expert layers sows into collection ``stats``, each summed
+#: over its expert layers: the tokens' assignments (tokens x top_k), those that
+#: landed on experts held here, and the busiest held expert's tokens
+MOE_STATS = ("moe_assignments", "moe_held", "moe_max_load")
+
+
+class Router(nn.Module):
+    """``route`` over a kernel of its own (``router/kernel``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.moe import route
+
+        cfg = self.cfg
+        kernel = self.param("kernel", nn.initializers.lecun_normal(), (x.shape[-1], cfg.n_routed_experts))
+        return route(x, kernel.astype(x.dtype), cfg.top_k, cfg.routed_scaling_factor, cfg.norm_topk_prob)
+
+
+class Experts(nn.Module):
+    """The held experts' SwiGLU kernels, stacked (``experts/w_gate|w_up``:
+    (held, d_model, moe_d_ff); ``experts/w_down``: (held, moe_d_ff, d_model)),
+    and their part of the result (``ops/moe.expert_ffn``)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, idx, gates):
+        from ..ops.moe import expert_ffn, round_rows
+
+        cfg = self.cfg
+        held, d, f = cfg.experts_held or cfg.n_routed_experts, x.shape[-1], cfg.moe_d_ff
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
+        w = [self.param(n, init, shape).astype(cfg.dtype) for n, shape in
+             (("w_gate", (held, d, f)), ("w_up", (held, d, f)), ("w_down", (held, f, d)))]
+        return expert_ffn(x, idx, gates, *w, cfg.first_expert,
+                          round_rows(x.shape[0], cfg.top_k, cfg.n_routed_experts))
+
+
+class MoE(nn.Module):
+    """A sparse expert layer as one expert-parallel rank runs it: every token
+    is routed over ALL ``n_routed_experts``; the result is the shared experts'
+    SwiGLU (whole, on every rank) plus the held experts' gated outputs for the
+    tokens routed to them.  Sows ``MOE_STATS`` into collection ``stats``."""
+
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        with jax.named_scope("llm.mlp"):
-            gate = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype, name="w_gate")(x)
-            up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype, name="w_up")(x)
-            return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype, name="w_down")(
-                nn.silu(gate) * up
-            )
+        flat = x.reshape(-1, x.shape[-1])
+        with jax.named_scope("llm.moe.router"):
+            idx, gates, counts = Router(cfg, name="router")(flat)
+        with jax.named_scope("llm.moe.experts"):
+            y = Experts(cfg, name="experts")(flat, idx, gates).reshape(x.shape)
+        if cfg.n_shared_experts:
+            with jax.named_scope("llm.moe.shared"):
+                y = y + MLP(cfg, cfg.n_shared_experts * cfg.moe_d_ff, name="shared")(x)
+        held = counts[cfg.first_expert: cfg.first_expert + (cfg.experts_held or cfg.n_routed_experts)]
+        for name, value in zip(MOE_STATS, (idx.size, jnp.sum(held), jnp.max(held))):
+            self.sow("stats", name, jnp.float32(value), init_fn=lambda: jnp.float32(0),
+                     reduce_fn=lambda a, b: a + b)
+        return y
 
 
 class Block(nn.Module):
@@ -278,6 +441,7 @@ class Block(nn.Module):
     mesh: Optional[Any] = None
     seq_axis: Optional[str] = None
     mixer: str = "attention"
+    experts: bool = False  # a sparse expert layer (``moe``) in the dense SwiGLU's (``mlp``) place
 
     @nn.compact
     def __call__(self, x, positions):
@@ -287,11 +451,37 @@ class Block(nn.Module):
             # in float32: a bfloat16 1.4 / sqrt(32) is 0.17% short, on every branch alike
             r = cfg.scale_depth / (cfg.mup_depth or cfg.n_layers) ** 0.5
             branch = lambda y: (y.astype(jnp.float32) * r).astype(x.dtype)
-        x = x + branch(MIXERS[self.mixer](cfg, self.mesh, self.seq_axis, name="attn")(
+        after = lambda name, y: y
+        if cfg.sandwich_norm:  # the branch's output is normed before it joins the stream
+            after = lambda name, y: RMSNorm(cfg.norm_eps, name=name)(y).astype(x.dtype)
+        x = x + branch(after("post_attn_norm", MIXERS[self.mixer](
+            cfg, self.mesh, self.seq_axis, name="attn")(
             RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions
-        ))
-        x = x + branch(MLP(cfg, name="mlp")(RMSNorm(cfg.norm_eps, name="mlp_norm")(x)))
+        )))
+        ffn = MoE(cfg, name="moe") if self.experts else MLP(cfg, name="mlp")
+        x = x + branch(after("post_mlp_norm", ffn(RMSNorm(cfg.norm_eps, name="mlp_norm")(x))))
         return x
+
+
+class MTP(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3's): ``h' = [N_e(E[t_{i+1}])
+    | N_h(h_i)] W_p``, one block of the model's last kind, a final norm of its
+    own.  ``h``: the last layer's output before the final norm; ``emb_next``:
+    the embedding of each position's NEXT token.  Returns the hidden the
+    shared head reads the token after next from."""
+
+    cfg: TransformerConfig
+    block: Any
+    mixer: str = "attention"
+
+    @nn.compact
+    def __call__(self, h, emb_next, positions):
+        cfg = self.cfg
+        both = jnp.concatenate([RMSNorm(cfg.norm_eps, name="enorm")(emb_next).astype(cfg.dtype),
+                                RMSNorm(cfg.norm_eps, name="hnorm")(h).astype(cfg.dtype)], -1)
+        x = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype, name="proj")(both)
+        x = self.block(cfg, None, None, self.mixer, cfg.n_routed_experts > 0, name="block")(x, positions)
+        return RMSNorm(cfg.norm_eps, name="final_norm")(x)
 
 
 def block_remat_policy(cfg: TransformerConfig):
@@ -319,11 +509,16 @@ class Transformer(nn.Module):
         """Logits (b, s, vocab); or, given ``targets`` (b, s), the float32
         next-token loss of each position (b, s), the head and the softmax
         taken ``cfg.loss_chunk`` positions at a time and rematerialised in the
-        backward pass, so that the whole logits matrix never exists."""
+        backward pass, so that the whole logits matrix never exists.  With
+        ``cfg.mtp_layers`` and the targets: that and the MTP module's loss of
+        each position (b, s) against the token AFTER its target, through the
+        same embedding, head and chunks; the last position has no such token
+        and reads 0."""
         cfg = self.cfg
         b, s = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="embed")(tokens)
+        embed = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="embed")
+        x = embed(tokens)
         if cfg.scale_emb != 1.0:
             x = (x.astype(jnp.float32) * cfg.scale_emb).astype(cfg.dtype)
         block = Block
@@ -331,7 +526,15 @@ class Transformer(nn.Module):
             policy = block_remat_policy(cfg)
             block = nn.remat(Block, static_argnums=(), policy=policy)
         for i in range(cfg.n_layers):
-            x = block(cfg, self.mesh, self.seq_axis, cfg.mixer(i), name=f"layer_{i}")(x, positions)
+            x = block(cfg, self.mesh, self.seq_axis, cfg.mixer(i), cfg.has_experts(i),
+                      name=f"layer_{i}")(x, positions)
+        # the module runs where its loss is asked for (and where its parameters are made)
+        if cfg.mtp_layers and (targets is not None or self.is_initializing()):
+            if cfg.mtp_layers != 1 or cfg.scale_emb != 1.0 or cfg.dim_model_base:
+                raise NotImplementedError("one MTP module, on a model without the muP scalars")
+            with jax.named_scope("llm.mtp"):
+                x_mtp = MTP(cfg, block, cfg.mixer(cfg.n_layers - 1), name="mtp")(
+                    x, embed(tokens if targets is None else targets), positions)
         with jax.named_scope("llm.head_loss"):  # the trainer's loss carries the same name
             x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
             if cfg.dim_model_base:
@@ -347,6 +550,14 @@ class Transformer(nn.Module):
             n = cfg.loss_chunk or s
             if s % n:
                 raise ValueError(f"loss_chunk {n} does not divide the sequence length {s}")
-            return jnp.concatenate(
-                [nn.remat(chunk_loss)(head, x[:, i: i + n], targets[:, i: i + n])
-                 for i in range(0, s, n)], axis=1)
+
+            def in_chunks(x, targets):
+                return jnp.concatenate(
+                    [nn.remat(chunk_loss)(head, x[:, i: i + n], targets[:, i: i + n])
+                     for i in range(0, s, n)], axis=1)
+
+            if not cfg.mtp_layers:
+                return in_chunks(x, targets)
+            # position i of the module predicts the target of position i + 1
+            after_next = jnp.roll(targets, -1, axis=1)
+            return in_chunks(x, targets), in_chunks(x_mtp, after_next) * (jnp.arange(s) < s - 1)

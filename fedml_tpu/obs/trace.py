@@ -42,7 +42,7 @@ from .registry import REGISTRY
 __all__ = [
     "Span", "traced", "activate", "current", "start_span",
     "inject", "extract", "new_id", "recent", "clear_recent",
-    "install_xla_listener", "XLA_COUNTERS", "LLM_ATTENDED_KEYS",
+    "install_xla_listener", "XLA_COUNTERS", "LLM_ATTENDED_KEYS", "LLM_EXPERT_TOKENS",
 ]
 
 #: finished spans, oldest first; a window of some thousand steps fits, and
@@ -281,6 +281,15 @@ LLM_ATTENDED_KEYS = REGISTRY.counter(
     "per-query block selection kept (tokens at or before the query inside its "
     "kept blocks), kind=causal what plain causal attention would attend.  "
     "kept/causal is the share of the past a sparse layer reads.",
+    labels=("kind",),
+)
+LLM_EXPERT_TOKENS = REGISTRY.counter(
+    "fedml_llm_expert_tokens_total",
+    "Expert assignments of an LLM step's tokens, summed over its sparse expert "
+    "layers: kind=routed is every assignment the routers made (tokens x "
+    "experts per token), kind=held those that landed on experts this "
+    "expert-parallel rank holds and computes.  held/routed is the share of the "
+    "layer's work that is done here.",
     labels=("kind",),
 )
 

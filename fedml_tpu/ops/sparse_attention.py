@@ -26,7 +26,9 @@ Two stages, both plain ``jax.numpy``/``lax``, and the entry that joins them:
     that skips dropped blocks.  The backward pass is written out (the
     flash-attention one): the forward keeps the output and each row's
     log-sum-exp, the backward recomputes each pair of chunks' probabilities
-    once; no score matrix is kept and nothing is rematerialised twice.
+    once; no score matrix is kept and nothing is rematerialised twice.  The
+    values may have a width of their own (latent attention's 192-wide keys
+    over 128-wide values), and ``keep=None`` is plain causal attention.
 """
 
 from __future__ import annotations
@@ -137,16 +139,24 @@ def select_blocks(q, k, *, kernel_size: int, kernel_stride: int, block_size: int
 
 def _chunks(q, k, v, keep, block_size: int, cq: int, ck: int):
     """The operands cut into chunks, the chunk index leading: q (nq, b, cq,
-    kv, g, d), k and v (nk, b, ck, kv, d), and the mask by pair of chunks
-    (nq, nk, b, kv, cq, ck // block_size)."""
+    kv, g, d), k (nk, b, ck, kv, d), v (nk, b, ck, kv, dv), and the mask by
+    pair of chunks (nq, nk, b, kv or 1, cq, ck // block_size)."""
     b, s, h, d = q.shape
     kv = k.shape[2]
     nq, nk = s // cq, s // ck
     qs = jnp.moveaxis(q.reshape(b, nq, cq, kv, h // kv, d), 1, 0)
     ks = jnp.moveaxis(k.reshape(b, nk, ck, kv, d), 1, 0)
-    vs = jnp.moveaxis(v.reshape(b, nk, ck, kv, d), 1, 0)
-    keeps = jnp.transpose(keep.reshape(b, kv, nq, cq, nk, ck // block_size), (2, 4, 0, 1, 3, 5))
+    vs = jnp.moveaxis(v.reshape(b, nk, ck, kv, v.shape[-1]), 1, 0)
+    keeps = jnp.transpose(keep.reshape(b, keep.shape[1], nq, cq, nk, ck // block_size),
+                          (2, 4, 0, 1, 3, 5))
     return qs, ks, vs, keeps
+
+
+def _zeros_for(ks, vs):
+    """Float32 zeros shaped as keys and as values: one array for both where
+    the two widths are equal."""
+    zk = jnp.zeros(ks.shape, jnp.float32)
+    return zk, (zk if vs.shape == ks.shape else jnp.zeros(vs.shape, jnp.float32))
 
 
 def _masked_logits(qc, kc, keep_qk, iq, ik, block_size: int, scale: float):
@@ -191,13 +201,13 @@ def _attend_fwd(q, k, v, keep, block_size, cq, ck, scale):
             return jax.lax.cond(ik * ck <= iq * cq + cq - 1, attend, lambda c: c, carry), None
 
         init = (jnp.full((b, kv, h // kv, cq), NEG_INF, f32), jnp.zeros((b, kv, h // kv, cq), f32),
-                jnp.zeros((b, cq, kv, h // kv, d), f32))
+                jnp.zeros((b, cq, kv, h // kv, v.shape[-1]), f32))
         (m, l, o), _ = jax.lax.scan(one_key_chunk, init, (ks, vs, keep_q, jnp.arange(s // ck)))
         l = jnp.maximum(l, 1e-30)
         return (o / jnp.moveaxis(l, 3, 1)[..., None]).astype(q.dtype), m + jnp.log(l)
 
     out, lse = jax.lax.map(one_query_chunk, (qs, keeps, jnp.arange(s // cq)))
-    out = jnp.moveaxis(out, 0, 1).reshape(b, s, h, d)
+    out = jnp.moveaxis(out, 0, 1).reshape(b, s, h, v.shape[-1])
     return out, (q, k, v, keep, out, lse)
 
 
@@ -208,7 +218,7 @@ def _attend_bwd(block_size, cq, ck, scale, saved, d_out):
     b, s, h, d = q.shape
     kv, f32 = k.shape[2], jnp.float32
     qs, ks, vs, keeps = _chunks(q, k, v, keep, block_size, cq, ck)
-    dos = jnp.moveaxis(d_out.reshape(b, s // cq, cq, kv, h // kv, d), 1, 0)
+    dos = jnp.moveaxis(d_out.reshape(b, s // cq, cq, kv, h // kv, v.shape[-1]), 1, 0)
     # delta_t = sum_j p_tj dp_tj = do_t . o_t
     deltas = jnp.moveaxis(jnp.sum(d_out.astype(f32) * out.astype(f32), -1)
                           .reshape(b, s // cq, cq, kv, h // kv), 1, 0)
@@ -230,16 +240,15 @@ def _attend_bwd(block_size, cq, ck, scale, saved, d_out):
                 dk = jnp.einsum("bkgqt,bqkgd->btkd", ds, qc, preferred_element_type=f32)
                 return dq, (dk, dv)
 
-            nothing = jnp.zeros(kc.shape, f32)
+            nothing = _zeros_for(kc, vc)
             return jax.lax.cond(ik * ck <= iq * cq + cq - 1, attend,
-                                lambda dq: (dq, (nothing, nothing)), dq)
+                                lambda dq: (dq, nothing), dq)
 
         dq, (dk, dv) = jax.lax.scan(one_key_chunk, jnp.zeros(qc.shape, f32),
                                     (ks, vs, keep_q, jnp.arange(s // ck)))
         return (dkv[0] + dk, dkv[1] + dv), dq.astype(q.dtype)
 
-    zeros = jnp.zeros(ks.shape, f32)
-    (dk, dv), dq = jax.lax.scan(one_query_chunk, (zeros, zeros),
+    (dk, dv), dq = jax.lax.scan(one_query_chunk, _zeros_for(ks, vs),
                                 (qs, dos, keeps, lse, deltas, jnp.arange(s // cq)))
     unchunk = lambda t, like: jnp.moveaxis(t, 0, 1).reshape(like.shape).astype(like.dtype)
     return unchunk(dq, q), unchunk(dk, k), unchunk(dv, v), None
@@ -250,14 +259,16 @@ _attend.defvjp(_attend_fwd, _attend_bwd)
 
 def block_sparse_attention(q, k, v, keep=None, *, block_size: int = 64, q_chunk: int = 1024,
                            k_chunk: int = 1024, scale=None):
-    """q: (b, s, h, d); k, v: (b, s, kv, d); keep: (b, kv, s, s // block_size)
-    bool or None (every block) -> softmax attention of each query over the
-    tokens ``j <= t`` of its kept blocks, (b, s, h, d) in q's dtype."""
+    """q: (b, s, h, d); k: (b, s, kv, d); v: (b, s, kv, dv), a value width of
+    its own allowed (latent attention: 192-wide queries and keys, 128-wide
+    values); keep: (b, kv, s, s // block_size) bool, or (b, 1, ...) for all KV
+    heads alike, or None (every block) -> softmax attention of each query over
+    the tokens ``j <= t`` of its kept blocks, (b, s, h, dv) in q's dtype."""
     b, s, h, d = q.shape
     scale = d ** -0.5 if scale is None else scale
-    if keep is None:
-        block_size = _chunk(s, block_size)
-        keep = jnp.ones((b, k.shape[2], s, s // block_size), bool)
+    if keep is None:  # any block size will do: one that divides a chunk of keys
+        block_size = _chunk(_chunk(s, k_chunk), block_size)
+        keep = jnp.ones((b, 1, s, s // block_size), bool)
     return _attend(q, k, v, keep, block_size, _chunk(s, q_chunk),
                    _chunk(s, k_chunk, block_size), float(scale))
 

@@ -30,9 +30,23 @@ TRANSFORMER_RULES = [
     (r".*attn/wo/kernel", lambda dp, tp: P(tp, None, dp)),
     # a mixer's output gate (d_model -> heads x head_dim), laid out like wq
     (r".*attn/wg/kernel", lambda dp, tp: P(dp, tp, None)),
-    # mlp: gate/up shard out over model; down shards in over model
-    (r".*mlp/w_(gate|up)/kernel", lambda dp, tp: P(dp, tp)),
-    (r".*mlp/w_down/kernel", lambda dp, tp: P(tp, dp)),
+    # latent attention: the down-projections to the two latents shard their
+    # input over data only (a latent is whole on every model shard); the
+    # up-projections from them shard heads over model, like wq
+    (r".*attn/w(q|kv)_a/kernel", lambda dp, tp: P(dp, None)),
+    (r".*attn/w(q|kv)_b/kernel", lambda dp, tp: P(dp, tp, None)),
+    # mlp: gate/up shard out over model; down shards in over model; an expert
+    # layer's shared experts (moe/shared) are an mlp
+    (r".*(mlp|moe/shared)/w_(gate|up)/kernel", lambda dp, tp: P(dp, tp)),
+    (r".*(mlp|moe/shared)/w_down/kernel", lambda dp, tp: P(tp, dp)),
+    # the held experts' stacked kernels (experts, in, out): each expert laid
+    # out like an mlp; the leading axis is kept whole until a mesh has an
+    # expert axis to spread it over.  The router is small and replicated.
+    (r".*moe/experts/w_(gate|up)", lambda dp, tp: P(None, dp, tp)),
+    (r".*moe/experts/w_down", lambda dp, tp: P(None, tp, dp)),
+    (r".*moe/router/kernel", lambda dp, tp: P()),
+    # the MTP module's (2 d_model -> d_model) projection, like an mlp's down
+    (r".*mtp/proj/kernel", lambda dp, tp: P(tp, dp)),
     # embeddings / head: vocab over model axis
     (r".*embed/embedding", lambda dp, tp: P(tp, dp)),
     (r".*lm_head/kernel", lambda dp, tp: P(dp, tp)),

@@ -7,12 +7,20 @@ mode.  A default configuration (and the Mistral-shaped one of cell
 step it traced to before: the text of ``LLMTrainer``'s step jaxpr was taken
 on the parent commit (9fb43aa) with this file's own ``step_text`` and its
 SHA-256 is pinned below.  Named scopes are not part of that text.
+
+PR 33 gave the blockwise attention of ``ops/sparse_attention.py`` a value
+width of its own and ``Block``, ``Transformer`` and the trainer an expert
+layer, sandwich norms and a second prediction head.  ``minicpm_sala_d4`` (the
+configuration whose mixer that PR edits) at its rehearsal sizes in adapter
+mode, as ``benchmark/sala.py`` builds it, is pinned the same way, taken on
+that PR's parent commit (ca052ac).
 """
 
 import hashlib
 import json
 import os
 import re
+import sys
 
 import pytest
 
@@ -23,6 +31,8 @@ PARENT = {
     "tiny_default": ("a3479b82cda26460699053245d449ce75bb69fcc9ef314898a7d488d3c4928a4", 135677),
     "mistral_7b_d2_rehearsal": (
         "ce71fee9833b59eef19333cd00b85a55f3221a8b601b7ca876449abb61bfe953", 138446),
+    "minicpm_sala_d4_rehearsal_adapters": (
+        "83102dad2337370934b4d6fcead3d89413457caa84beb5e1cc01587fa428d77f", 407280),
 }
 
 
@@ -40,8 +50,28 @@ def _configs():
         d_ff=c["intermediate_size"], max_seq_len=32, rope_theta=c["rope_theta"],
         norm_eps=c["rms_norm_eps"], dtype=jnp.bfloat16, remat=True, remat_policy="dots",
         logits_dtype=jnp.bfloat16)
-    return {"tiny_default": (TransformerConfig.tiny(vocab_size=256), 2, 16),
-            "mistral_7b_d2_rehearsal": (mistral, 4, 32)}
+    return {"tiny_default": (TransformerConfig.tiny(vocab_size=256), 2, 16, {}),
+            "mistral_7b_d2_rehearsal": (mistral, 4, 32, {}),
+            "minicpm_sala_d4_rehearsal_adapters": _sala()}
+
+
+def _sala():
+    """(cfg, batch, seq, the job's adapters) as ``benchmark/sala.py`` builds
+    the cell at its rehearsal sizes."""
+    bench = os.path.join(ROOT, "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        import sala
+    finally:
+        sys.path.remove(bench)
+    with open(os.path.join(bench, "configs", "minicpm_sala_d4.json")) as fh:
+        c = json.load(fh)
+    with open(os.path.join(bench, "traffic", "lora_sft_16k_b1.json")) as fh:
+        t = json.load(fh)
+    c, t = {**c, **c["rehearsal"]}, {**t, **t["rehearsal"]}
+    cfg = sala.transformer_config(c, t["seq_len"], t["remat_policy"], **t["program"])
+    job = {k: t["train_args"][k] for k in ("lora_rank", "lora_alpha", "lora_targets")}
+    return cfg, t["batch_size"], t["seq_len"], job
 
 
 def step_text(name: str) -> str:
@@ -49,10 +79,16 @@ def step_text(name: str) -> str:
     import jax.numpy as jnp
     from fedml_tpu.llm.train import LLMTrainArgs, LLMTrainer
 
-    cfg, batch, seq = _configs()[name]
-    tr = LLMTrainer(cfg, LLMTrainArgs(batch_size=batch, seq_len=seq, total_steps=10, warmup_steps=2))
+    from fedml_tpu.parallel import mesh as meshlib
+
+    cfg, batch, seq, job = _configs()[name]
+    # a batch of one row needs a mesh of one device (the default takes all eight)
+    mesh = meshlib.make_mesh((meshlib.AXIS_DATA,), devices=jax.devices()[:1]) if batch == 1 else None
+    tr = LLMTrainer(cfg, LLMTrainArgs(batch_size=batch, seq_len=seq, total_steps=10, warmup_steps=2,
+                                      **job), mesh=mesh)
     tok = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
-    text = str(jax.make_jaxpr(tr._make_train_step())(tr.params, tr.opt_state, tok, tok))
+    state = (tr.params, tr.opt_state) if tr.lora is None else (tr.lora, tr.opt_state, tr.params)
+    text = str(jax.make_jaxpr(tr._make_train_step())(*state, tok, tok))
     return re.sub(r"0x[0-9a-f]+", "0x", text)  # addresses of callables differ from run to run
 
 
@@ -64,8 +100,6 @@ def test_step_program_is_the_parents(name, eight_devices):
 
 
 if __name__ == "__main__":  # prints what to pin, on whatever tree it runs
-    import sys
-
     sys.path.insert(0, ROOT)
     for n in sorted(PARENT):
         t = step_text(n)

@@ -142,6 +142,30 @@ def test_sparse_layer_is_the_per_query_selection(seq, chunk, bench):
         np.testing.assert_allclose(a, b, atol=1e-5 * max(1.0, float(np.abs(b).max())))
 
 
+def test_block_attention_with_equal_widths_is_the_parents_program():
+    """PR 33 gave ``block_sparse_attention`` a value width of its own.  With
+    values as wide as the keys it must still be the program it was: the jaxpr
+    of its output and three gradients at this file's sizes (two KV heads, a
+    block mask by KV head, chunks of 16), taken on that PR's parent commit
+    (ca052ac) with this very function, is pinned by SHA-256, so outputs and
+    gradients are the parent's to the bit on any backend."""
+    import hashlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.ops.sparse_attention import block_sparse_attention
+
+    q = jax.ShapeDtypeStruct((1, 64, 4, 16), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 64, 2, 16), jnp.bfloat16)
+    keep = jax.ShapeDtypeStruct((1, 2, 64, 8), jnp.bool_)
+    f = lambda q, k, v, keep: jnp.sum(block_sparse_attention(
+        q, k, v, keep, block_size=8, q_chunk=16, k_chunk=16).astype(jnp.float32) ** 2)
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2)))(q, k, k, keep)))
+    assert (hashlib.sha256(text.encode()).hexdigest(), len(text)) == (
+        "9fd155ef6b291aa953c9c4e562a8e6209757d60a2ad7ad12e566e5c7fe9789f2", 21302)
+
+
 def _top_k_form(score, candidate, topk):
     """The selection ``best_of`` replaced (PR 30), kept here as its oracle:
     ``lax.top_k`` (equal values: lower index first) and a one-hot of what it
