@@ -10,6 +10,9 @@ Drives, through the entry points a user calls and at the flagship widths:
          -> scanned chunks with a donated carry + ``evaluate``);
   Leg C  every Pallas kernel compiled by Mosaic (``interpret=False``) against
          the jnp reference beside it;
+  Leg D  latent attention (``MLAttention``) at a small shape that tiles: the
+         fused flash kernel must be the path taken, and agree with the
+         blockwise ``lax`` pass in the output and the input's gradient;
   Leg B  the 542M-parameter LLM train step (``LLMTrainer.fit``), on every mesh
          the visible devices allow.
 
@@ -199,6 +202,56 @@ def leg_c(jax, dry: bool, ref_a: dict | None) -> dict:
 
 
 # ---------------------------------------------------------------------------
+# Leg D — the flash kernel under the module that calls it
+# ---------------------------------------------------------------------------
+
+def leg_d(jax, dry: bool) -> dict:
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.models.transformer import MLAttention, TransformerConfig
+    from fedml_tpu.ops.sparse_attention import attention_sites
+    from fedml_tpu.parallel import mesh as meshlib
+
+    t0 = time.perf_counter()
+    seq = 128 if dry else 1024
+    cfg = TransformerConfig(
+        vocab_size=256, d_model=512, n_layers=1, n_heads=8, n_kv_heads=8, max_seq_len=seq,
+        mixer_types=("mla",), q_lora_rank=256, kv_lora_rank=128, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, seq, cfg.d_model), cfg.dtype)
+    probe = jax.random.normal(jax.random.PRNGKey(4), x.shape, cfg.dtype)
+    pos = jnp.broadcast_to(jnp.arange(seq), (2, seq))
+    params = jax.tree_util.tree_map(
+        lambda p: p.astype(cfg.dtype), MLAttention(cfg).init(jax.random.PRNGKey(5), x, pos)["params"])
+
+    def run(module):
+        """The module's output and the gradient to its input under ``probe``."""
+        def both(x):
+            y, back = jax.vjp(lambda x: module.apply({"params": params}, x, pos), x)
+            return y, back(probe)[0]
+        return jax.jit(both)(x)
+
+    before = attention_sites()
+    got = run(MLAttention(cfg))
+    taken = {p: n - before[p] for p, n in attention_sites().items()}
+    # a module that holds a mesh stays on the lax pass, whatever the backend
+    want = run(MLAttention(cfg, mesh=meshlib.make_mesh((meshlib.AXIS_DATA,), devices=jax.devices()[:1])))
+    if not dry:
+        check(jax.default_backend() == "tpu", f"backend is {jax.default_backend()}")
+        check(taken == {"kernel": 1, "blockwise": 0}, f"the kernel was not the path taken: {taken}")
+    gaps = [float(jnp.linalg.norm((g - w).astype(jnp.float32).ravel())
+                  / jnp.linalg.norm(w.astype(jnp.float32).ravel())) for g, w in zip(got, want)]
+    # bfloat16 probabilities rounded under other running maxima (tiles of
+    # 1,024 against chunks of 512) differ by about 1e-3; a wrong tile by 1
+    check(all(np.isfinite(gaps)) and max(gaps) < 1e-2,
+          f"kernel against the lax pass: output and input gradient differ by {gaps}")
+    log(f"leg D: MLAttention {seq} tokens x 8 heads x 192|128, sites {taken}, "
+        f"gaps to the lax pass (output, input gradient) {gaps}")
+    return {"kernels_s": time.perf_counter() - t0, "memory": memory(jax)}
+
+
+# ---------------------------------------------------------------------------
 # Leg B — LLM train step (bench.py's llm shape)
 # ---------------------------------------------------------------------------
 
@@ -311,6 +364,7 @@ def main() -> int:
     n = device["count"]
     legs = [("A", lambda: leg_a(jax, dry)),
             ("C", lambda: leg_c(jax, dry, results.get("A"))),
+            ("D", lambda: leg_d(jax, dry)),
             ("B", lambda: leg_b(jax, dry, None, compiles))]
     if n >= 4 and n % 2 == 0:
         legs.append(("B data x model", lambda: leg_b(

@@ -34,6 +34,7 @@ from ..models.transformer import MOE_STATS, Transformer, TransformerConfig
 from ..obs.metrics import MetricsLogger
 from ..obs.trace import (LLM_ATTENDED_KEYS, LLM_EXPERT_TOKENS, XLA_COUNTERS, install_xla_listener,
                          traced)
+from ..ops.sparse_attention import ATTENTION_PATHS, attention_sites
 from ..parallel import mesh as meshlib, sharding
 from . import lora as lora_lib
 
@@ -76,7 +77,10 @@ class LLMTrainer:
             mesh = meshlib.make_mesh((meshlib.AXIS_DATA,))
         self.mesh = mesh
         self.seq_axis = seq_axis if (seq_axis and seq_axis in mesh.shape and mesh.shape[seq_axis] > 1) else None
-        self.model = Transformer(cfg, mesh=mesh if self.seq_axis else None, seq_axis=self.seq_axis)
+        # a model that may compute on sharded arrays holds the mesh: its
+        # mixers then stay off kernels that jax cannot partition
+        self.model = Transformer(cfg, mesh=mesh if self.seq_axis or mesh.size > 1 else None,
+                                 seq_axis=self.seq_axis)
         self.logger = logger or MetricsLogger()
 
         k0 = rng.root_key(args.seed)
@@ -130,6 +134,9 @@ class LLMTrainer:
             self.opt_state = jax.jit(self.opt.init, out_shardings=opt_shardings)(trained)
         self.data_sharding = sharding.batch_sharding(mesh, seq_axis=self.seq_axis)
         self.step_idx = 0
+        #: the step program's blockwise-attention call sites by path, known
+        #: once the program has been traced (its first call)
+        self.attention_sites = dict.fromkeys(ATTENTION_PATHS, 0)
         # Pin the step's output shardings to the input shardings: with
         # donation and unspecified out_shardings, XLA may pick different
         # layouts for the outputs, and the SECOND call then recompiles
@@ -190,10 +197,13 @@ class LLMTrainer:
                 return losses.mean(), stats
 
         def update(trained, opt_state, base, tokens, targets):
+            # runs while the program is traced: the sites counted meanwhile are its own
+            before = attention_sites()
             # the scopes name each op's phase in a device profile (XProf)
             with jax.named_scope("llm.fwd_bwd"):
                 (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                     trained, base, tokens, targets)
+            self.attention_sites = {p: n - before[p] for p, n in attention_sites().items()}
             with jax.named_scope("llm.optimizer"):
                 updates, opt_state = opt.update(grads, opt_state, trained)
                 trained = optax.apply_updates(trained, updates)
@@ -234,11 +244,14 @@ class LLMTrainer:
         ``sparse_causal`` in each history entry, as attributes of ``llm.step``
         and in ``fedml_llm_attended_keys_total``; one with expert layers how
         its tokens were routed: ``moe_assignments``, ``moe_held`` and
-        ``moe_max_load`` likewise, and ``fedml_llm_expert_tokens_total``."""
+        ``moe_max_load`` likewise, and ``fedml_llm_expert_tokens_total``.
+        ``llm.fit`` says on which path the step program's blockwise-attention
+        call sites were built: ``attn_kernel_sites``, ``attn_blockwise_sites``
+        (``fedml_llm_attention_sites_total`` counts every traced program's)."""
         history = []
         steps = steps or self.args.total_steps
         batches = iter(batch_iter)
-        with traced("llm.fit", counters=XLA_COUNTERS):
+        with traced("llm.fit", counters=XLA_COUNTERS) as fit_span:
             for i in range(steps + 1):
                 with traced("llm.next_batch"):
                     batch = next(batches, None)
@@ -260,6 +273,7 @@ class LLMTrainer:
                 with traced("llm.log"):
                     self.logger.log(m)
                 history.append(m)
+            fit_span.attrs.update({f"attn_{p}_sites": n for p, n in self.attention_sites.items()})
         return history
 
     def n_params(self) -> int:

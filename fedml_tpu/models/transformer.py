@@ -290,7 +290,7 @@ class SparseAttention(nn.Module):
         v = _project(self, "wv", x, (cfg.n_kv_heads, hd))
         q, k = _qk_normed(cfg, q, k)
         with jax.named_scope("llm.mixer.sparse"):
-            out, kept, causal = sparse_attention(q, k, v, **cfg.sparse_selection)
+            out, kept, causal = sparse_attention(q, k, v, mesh=self.mesh, **cfg.sparse_selection)
         add = lambda a, b: a + b
         self.sow("stats", "sparse_kept", kept, init_fn=lambda: jnp.float32(0), reduce_fn=add)
         self.sow("stats", "sparse_causal", causal, init_fn=lambda: jnp.float32(0), reduce_fn=add)
@@ -298,20 +298,11 @@ class SparseAttention(nn.Module):
         return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
 
 
-#: heads a latent-attention mixer attends at a time: the blockwise pass's
-#: float32 accumulators and chunked copies of q, k, v scale with the heads it
-#: is given (2.6 GB for 128 heads x 8,192 tokens, a quarter of that for 32)
+#: heads the blockwise ``lax`` pass takes at a time under a latent-attention
+#: mixer: its float32 accumulators and chunked copies of q, k, v scale with
+#: the heads it is given (2.6 GB for 128 heads x 8,192 tokens, a quarter of
+#: that for 32).  The fused kernel has neither and takes all heads at once.
 MLA_HEAD_GROUP = 32
-
-
-def _by_head_groups(fn, group: int, q, k, v):
-    """``fn(q, k, v)`` over (b, s, heads, d) operands, ``group`` heads at a
-    time, one group after another."""
-    h = q.shape[2]
-    if h <= group:
-        return fn(q, k, v)
-    return jnp.concatenate([fn(*(t[:, :, i: i + group] for t in (q, k, v)))
-                            for i in range(0, h, group)], axis=2)
 
 
 class MLAttention(nn.Module):
@@ -322,7 +313,9 @@ class MLAttention(nn.Module):
     causal softmax attention of ``[q_n | q_r]`` over ``[k_n | k_r]`` scaled by
     the whole query width, values of their own width; ``W_o`` over (heads x
     v_head_dim).  Blockwise (``ops/sparse_attention.block_sparse_attention``,
-    every block kept): 128 heads' (s, s) scores are never whole."""
+    every block kept): 128 heads' (s, s) scores are never whole, and where
+    that function finds it can (one TPU device, a sequence that tiles) no
+    pair of chunks' scores leaves the chip: the fused flash kernel."""
 
     cfg: TransformerConfig
     mesh: Optional[Any] = None
@@ -343,10 +336,10 @@ class MLAttention(nn.Module):
         k_r = rope(kv_a[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)   # (b, s, 1, rot)
         q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
         k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_r, k_r.shape[:2] + (h, rot))], -1)
-        attend = lambda q, k, v: block_sparse_attention(q, k, v, None, q_chunk=CHUNK, k_chunk=CHUNK,
-                                                        scale=(nope + rot) ** -0.5)
         with jax.named_scope("llm.mixer.mla"):
-            out = _by_head_groups(attend, MLA_HEAD_GROUP, q, k, kv[..., nope:])
+            out = block_sparse_attention(q, k, kv[..., nope:], None, q_chunk=CHUNK, k_chunk=CHUNK,
+                                         scale=(nope + rot) ** -0.5, mesh=self.mesh,
+                                         head_group=MLA_HEAD_GROUP)
         return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
 
 

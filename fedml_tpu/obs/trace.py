@@ -43,6 +43,7 @@ __all__ = [
     "Span", "traced", "activate", "current", "start_span",
     "inject", "extract", "new_id", "recent", "clear_recent",
     "install_xla_listener", "XLA_COUNTERS", "LLM_ATTENDED_KEYS", "LLM_EXPERT_TOKENS",
+    "LLM_ATTENTION_SITES",
 ]
 
 #: finished spans, oldest first; a window of some thousand steps fits, and
@@ -291,6 +292,16 @@ LLM_EXPERT_TOKENS = REGISTRY.counter(
     "expert-parallel rank holds and computes.  held/routed is the share of the "
     "layer's work that is done here.",
     labels=("kind",),
+)
+#: fed by ``ops/sparse_attention.attention_path`` while a program is traced
+LLM_ATTENTION_SITES = REGISTRY.counter(
+    "fedml_llm_attention_sites_total",
+    "Blockwise-attention call sites of the programs traced so far, by the "
+    "path each was built on: path=kernel is the fused Pallas flash kernel "
+    "(plain causal attention on one TPU device at shapes it tiles), "
+    "path=blockwise the lax pass (a block mask, a mesh, another backend or "
+    "other shapes).  Counted at build time, once a site a trace.",
+    labels=("path",),
 )
 
 _listener_lock = threading.Lock()

@@ -1,7 +1,9 @@
 """InfLLM-v2 block-sparse softmax attention: every query picks the key blocks
 it attends.
 
-Two stages, both plain ``jax.numpy``/``lax``, and the entry that joins them:
+Two stages in plain ``jax.numpy``/``lax`` and the entry that joins them; the
+second stage's plain-causal case (no block dropped) runs as one fused Pallas
+kernel where ``attention_path`` finds that it can:
 
 ``select_blocks``  scores key blocks per query and KV head without a gradient:
     compressed keys (the mean of ``kernel_size`` keys every ``kernel_stride``),
@@ -29,6 +31,11 @@ Two stages, both plain ``jax.numpy``/``lax``, and the entry that joins them:
     once; no score matrix is kept and nothing is rematerialised twice.  The
     values may have a width of their own (latent attention's 192-wide keys
     over 128-wide values), and ``keep=None`` is plain causal attention.
+
+``attention_path``  the ONE place that chooses between that pass and
+    ``ops/pallas/flash_attention.causal_attention``, the same algorithm with
+    each pair's scores held in VMEM from the first product to the last: from
+    ``keep``, the caller's mesh, the backend and the shapes, nothing else.
 """
 
 from __future__ import annotations
@@ -38,6 +45,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+from ..obs.trace import LLM_ATTENTION_SITES
+from .pallas import flash_attention
 
 NEG_INF = -1e30
 #: the name a selection's block mask carries, so that a remat policy can keep
@@ -257,28 +267,73 @@ def _attend_bwd(block_size, cq, ck, scale, saved, d_out):
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
+#: the paths a blockwise-attention call site is built on
+ATTENTION_PATHS = ("kernel", "blockwise")
+
+
+def attention_sites() -> dict:
+    """Call sites counted so far, by path (``fedml_llm_attention_sites_total``):
+    a program's own are what its tracing adds."""
+    return {path: int(LLM_ATTENTION_SITES.value(path=path)) for path in ATTENTION_PATHS}
+
+
+def attention_path(q, k, v, keep, mesh) -> str:
+    """``"kernel"`` where the fused flash kernel can stand for the blockwise
+    pass, else ``"blockwise"``: no block dropped (``keep is None``; a block
+    mask is the ``lax`` pass's), no mesh in the caller's hands (jax cannot
+    partition a Mosaic call), a TPU backend, and shapes the kernel tiles.
+    Counts the call site under the path it takes: called while a program is
+    traced, so once for each site the program has."""
+    kernel = (keep is None and mesh is None and jax.default_backend() == "tpu"
+              and flash_attention.tiles(q, k, v))
+    path = "kernel" if kernel else "blockwise"
+    LLM_ATTENTION_SITES.inc(1, path=path)
+    return path
+
+
+def _by_head_groups(fn, group: int, q, k, v):
+    """``fn(q, k, v)`` over (b, s, heads, d) operands with a key per head,
+    ``group`` heads at a time, one group after another."""
+    h = q.shape[2]
+    if h <= group:
+        return fn(q, k, v)
+    return jnp.concatenate([fn(*(t[:, :, i: i + group] for t in (q, k, v)))
+                            for i in range(0, h, group)], axis=2)
+
+
 def block_sparse_attention(q, k, v, keep=None, *, block_size: int = 64, q_chunk: int = 1024,
-                           k_chunk: int = 1024, scale=None):
+                           k_chunk: int = 1024, scale=None, mesh=None, head_group: int = 0):
     """q: (b, s, h, d); k: (b, s, kv, d); v: (b, s, kv, dv), a value width of
     its own allowed (latent attention: 192-wide queries and keys, 128-wide
     values); keep: (b, kv, s, s // block_size) bool, or (b, 1, ...) for all KV
     heads alike, or None (every block) -> softmax attention of each query over
-    the tokens ``j <= t`` of its kept blocks, (b, s, h, dv) in q's dtype."""
+    the tokens ``j <= t`` of its kept blocks, (b, s, h, dv) in q's dtype.
+    ``mesh`` is the mesh the calling module holds, if any: what it computes on
+    may be sharded, which keeps it on the ``lax`` pass (``attention_path``).
+    ``head_group``, with ``keep=None`` and a key per head: the heads the
+    ``lax`` pass takes at a time (its float32 accumulators scale with them);
+    0 is all of them."""
     b, s, h, d = q.shape
     scale = d ** -0.5 if scale is None else scale
-    if keep is None:  # any block size will do: one that divides a chunk of keys
-        block_size = _chunk(_chunk(s, k_chunk), block_size)
-        keep = jnp.ones((b, 1, s, s // block_size), bool)
-    return _attend(q, k, v, keep, block_size, _chunk(s, q_chunk),
-                   _chunk(s, k_chunk, block_size), float(scale))
+    if attention_path(q, k, v, keep, mesh) == "kernel":
+        return flash_attention.causal_attention(q, k, v, scale=scale)
+    cq, scale = _chunk(s, q_chunk), float(scale)
+    if keep is not None:
+        return _attend(q, k, v, keep, block_size, cq, _chunk(s, k_chunk, block_size), scale)
+    # any block size will do: one that divides a chunk of keys
+    ck = _chunk(s, k_chunk)
+    block_size = _chunk(ck, block_size)
+    keep = jnp.ones((b, 1, s, s // block_size), bool)
+    return _by_head_groups(lambda q, k, v: _attend(q, k, v, keep, block_size, cq, ck, scale),
+                           head_group or h, q, k, v)
 
 
-def sparse_attention(q, k, v, *, dense_len: int, chunk: int = 0, **selection):
+def sparse_attention(q, k, v, *, dense_len: int, chunk: int = 0, mesh=None, **selection):
     """The InfLLM-v2 mixer on projected q (b, s, h, d) and k, v (b, s, kv, d):
     plain causal attention for at most ``dense_len`` tokens, else attention
     over the blocks ``select_blocks(**selection)`` keeps -> (out, kept,
     causal), the counts as ``select_blocks`` gives them.  ``chunk`` 0 is
-    ``CHUNK``."""
+    ``CHUNK``; ``mesh`` as ``block_sparse_attention`` takes it."""
     b, s = q.shape[:2]
     chunk = chunk or CHUNK
     if s > dense_len:
@@ -288,5 +343,5 @@ def sparse_attention(q, k, v, *, dense_len: int, chunk: int = 0, **selection):
         keep = None
         kept = causal = jnp.float32(b * k.shape[2]) * (s * (s + 1) / 2)
     out = block_sparse_attention(q, k, v, keep, block_size=selection["block_size"],
-                                 q_chunk=chunk, k_chunk=chunk)
+                                 q_chunk=chunk, k_chunk=chunk, mesh=mesh)
     return out, kept, causal
