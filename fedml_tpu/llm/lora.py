@@ -33,6 +33,9 @@ from flax import traverse_util
 DEFAULT_TARGETS = r".*attn/w[qkvo]/kernel"
 #: the five projections of a latent-attention mixer (``MLAttention``)
 MLA_TARGETS = r".*attn/w(q_a|q_b|kv_a|kv_b|o)/kernel"
+#: the two projections of a Mamba-2 mixer (``Mamba``): ``in_proj`` (d_model ->
+#: z | xBC | dt) and ``out_proj`` (inner -> d_model), both plain (in, out) kernels
+MAMBA_TARGETS = r".*attn/(in|out)_proj/kernel"
 #: kernels that contract all but their LAST dim ((heads, head_dim, d_model),
 #: the head_dim being the values' own under latent attention); every other
 #: kernel contracts its first

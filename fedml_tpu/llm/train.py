@@ -15,6 +15,13 @@ with the parameters' shardings (2 bytes a parameter, no gradient, no moments),
 the adapters (``llm/lora.py``) and their AdamW state in float32; the step
 differentiates with respect to the adapters only and takes the base as an
 argument it neither donates nor returns.
+
+A batch is two arrays ``(tokens, targets)`` or, for packed documents
+(``llm/packing.py``), three: ``(tokens, targets, segments)``.  With
+``segments`` the model keeps every mixer inside a document and the loss is
+the mean over the positions whose target lies in their own document; that
+step is a program of its own, compiled on its first call, and two-array
+batches run the program they always ran.
 """
 
 from __future__ import annotations
@@ -30,10 +37,10 @@ import numpy as np
 import optax
 
 from ..core import rng
-from ..models.transformer import MOE_STATS, Transformer, TransformerConfig
+from ..models.transformer import MOE_STATS, Transformer, TransformerConfig, targets_in_document
 from ..obs.metrics import MetricsLogger
-from ..obs.trace import (LLM_ATTENDED_KEYS, LLM_EXPERT_TOKENS, XLA_COUNTERS, install_xla_listener,
-                         traced)
+from ..obs.trace import (LLM_ATTENDED_KEYS, LLM_EXPERT_TOKENS, LLM_LOSS_TOKENS, LLM_PACKED_DOCUMENTS,
+                         XLA_COUNTERS, install_xla_listener, traced)
 from ..ops.sparse_attention import ATTENTION_PATHS, attention_sites
 from ..parallel import mesh as meshlib, sharding
 from . import lora as lora_lib
@@ -41,6 +48,24 @@ from . import lora as lora_lib
 #: what a model with block-sparse layers reports beside its loss: the keys
 #: its sparse layers attended and those a causal layer would have
 ATTENDED = ("sparse_kept", "sparse_causal")
+#: what a step on packed rows reports beside its loss: the batch's documents,
+#: the positions its loss counts, the causal pairs (query, key at or before
+#: it) inside documents, and those of the whole rows
+PACKED = ("docs", "loss_tokens", "doc_pairs", "causal_pairs")
+
+
+def packed_stats(segments) -> dict:
+    """``PACKED`` of a batch's ``segments`` (b, s), summed on the device (a
+    row's sums in int32, the batch's in float32)."""
+    b, s = segments.shape
+    real = segments != 0
+    starts = real & jnp.pad(segments[:, 1:] != segments[:, :-1], ((0, 0), (1, 0)), constant_values=True)
+    at = jnp.arange(s, dtype=jnp.int32)
+    since_start = at - jax.lax.cummax(jnp.where(starts, at, 0), axis=1)
+    total = lambda t: jnp.sum(jnp.sum(t.astype(jnp.int32), axis=1).astype(jnp.float32))
+    return {"docs": total(starts), "loss_tokens": total(targets_in_document(segments)),
+            "doc_pairs": total(jnp.where(real, since_start + 1, 0)),
+            "causal_pairs": jnp.float32(b * (s * (s + 1) // 2))}
 
 
 @dataclass(frozen=True)
@@ -142,25 +167,31 @@ class LLMTrainer:
         # layouts for the outputs, and the SECOND call then recompiles
         # against the new input layouts (a silent ~80 s hit on real chips).
         scalar_sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
-        self._train_step = jax.jit(
+        jit_step = lambda packed: jax.jit(
             self._make_train_step(),
             donate_argnums=(0, 1),
             out_shardings=(trained_shardings, opt_shardings,
-                           {k: scalar_sh for k in self._metric_names()}),
+                           {k: scalar_sh for k in self._metric_names(packed)}),
         )
+        self._train_step = jit_step(False)
+        #: the step on packed rows (a third array, ``segments``)
+        self._train_step_packed = jit_step(True)
 
     def _sown_names(self) -> tuple:
         """What the model's layers sow into collection ``stats``."""
         return ((ATTENDED if self.cfg.has_sparse_layers else ())
                 + (MOE_STATS if self.cfg.has_expert_layers else ()))
 
-    def _metric_names(self) -> tuple:
-        return ("loss", "ppl") + self._sown_names() + (("mtp_loss",) if self.cfg.mtp_layers else ())
+    def _metric_names(self, packed: bool = False) -> tuple:
+        return (("loss", "ppl") + self._sown_names() + (("mtp_loss",) if self.cfg.mtp_layers else ())
+                + (PACKED if packed else ()))
 
     def _make_train_step(self):
         """``(params, opt_state, tokens, targets)``, or with adapters
         ``(lora, opt_state, base, tokens, targets)``; the first two are
-        donated and come back updated, with the step's metrics."""
+        donated and come back updated, with the step's metrics.  Packed rows
+        bring one more array at the end, ``segments``, and ``PACKED`` among
+        the metrics."""
         model = self.model
         opt = self.opt
         args = self.args
@@ -169,14 +200,17 @@ class LLMTrainer:
         # the MTP module's loss needs the targets inside the model too
         chunked = self.cfg.loss_chunk > 0 or mtp
 
-        def loss_fn(trained, base, tokens, targets):
+        def loss_fn(trained, base, tokens, targets, segments=None):
             variables = {"params": trained} if base is None else {
                 "params": base,
                 "lora": lora_lib.as_collection(trained, args.lora_alpha, args.lora_rank)}
             stats = {}
             # with a loss_chunk the model takes the targets and returns the
             # per-token losses: the whole logits matrix never exists
-            kw = {"targets": targets} if chunked else {}
+            kw = {"targets": targets} if chunked or segments is not None else {}
+            if segments is not None:   # the model's per-token losses are 0 where they do not count
+                kw["segments"] = segments
+                stats.update(packed_stats(segments))
             if sown_names:  # sparse layers sow what they attended, expert layers their routing
                 out, sown = model.apply(variables, tokens, train=True, mutable=["stats"], **kw)
                 for name in sown_names:
@@ -188,6 +222,8 @@ class LLMTrainer:
                 out, after_next = out
                 stats["mtp_loss"] = after_next.sum() / (after_next.size - after_next.shape[0])
                 return out.mean() + args.mtp_weight * stats["mtp_loss"], stats
+            if segments is not None:
+                return out.sum() / jnp.maximum(stats["loss_tokens"], 1.0), stats
             if chunked:
                 return out.mean(), stats
             with jax.named_scope("llm.head_loss"):
@@ -196,13 +232,13 @@ class LLMTrainer:
                 )
                 return losses.mean(), stats
 
-        def update(trained, opt_state, base, tokens, targets):
+        def update(trained, opt_state, base, tokens, targets, *segments):
             # runs while the program is traced: the sites counted meanwhile are its own
             before = attention_sites()
             # the scopes name each op's phase in a device profile (XProf)
             with jax.named_scope("llm.fwd_bwd"):
                 (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                    trained, base, tokens, targets)
+                    trained, base, tokens, targets, *segments)
             self.attention_sites = {p: n - before[p] for p, n in attention_sites().items()}
             with jax.named_scope("llm.optimizer"):
                 updates, opt_state = opt.update(grads, opt_state, trained)
@@ -211,26 +247,25 @@ class LLMTrainer:
             return trained, opt_state, {"loss": loss, "ppl": jnp.exp(main), **stats}
 
         if self.lora is None:
-            return lambda params, opt_state, tokens, targets: update(
-                params, opt_state, None, tokens, targets)
+            return lambda params, opt_state, *batch: update(params, opt_state, None, *batch)
         return update
 
-    def _dispatch(self, tokens, targets) -> dict:
-        """One call of the step program on the trainer's own state."""
+    def _dispatch(self, *batch) -> dict:
+        """One call of the step program on the trainer's own state: of the
+        packed one where the batch has ``segments``."""
+        step = self._train_step if len(batch) == 2 else self._train_step_packed
         if self.lora is None:
-            self.params, self.opt_state, metrics = self._train_step(
-                self.params, self.opt_state, tokens, targets)
+            self.params, self.opt_state, metrics = step(self.params, self.opt_state, *batch)
         else:
-            self.lora, self.opt_state, metrics = self._train_step(
-                self.lora, self.opt_state, self.params, tokens, targets)
+            self.lora, self.opt_state, metrics = step(self.lora, self.opt_state, self.params, *batch)
         return metrics
 
-    def step(self, tokens: jax.Array, targets: jax.Array) -> dict:
+    def step(self, tokens: jax.Array, targets: jax.Array, segments=None) -> dict:
+        batch = (tokens, targets) if segments is None else (tokens, targets, segments)
         with traced("llm.h2d"):
-            tokens = jax.device_put(tokens, self.data_sharding)
-            targets = jax.device_put(targets, self.data_sharding)
+            batch = tuple(jax.device_put(x, self.data_sharding) for x in batch)
         with traced("llm.dispatch"):
-            metrics = self._dispatch(tokens, targets)
+            metrics = self._dispatch(*batch)
         self.step_idx += 1
         with traced("llm.sync"):
             return {k: float(v) for k, v in metrics.items()}
@@ -247,10 +282,18 @@ class LLMTrainer:
         ``moe_max_load`` likewise, and ``fedml_llm_expert_tokens_total``.
         ``llm.fit`` says on which path the step program's blockwise-attention
         call sites were built: ``attn_kernel_sites``, ``attn_blockwise_sites``
-        (``fedml_llm_attention_sites_total`` counts every traced program's)."""
+        (``fedml_llm_attention_sites_total`` counts every traced program's).
+        A batch of three arrays is packed rows: ``docs``, ``loss_tokens``,
+        ``doc_pairs`` and ``causal_pairs`` in each history entry and on
+        ``llm.step``, ``fedml_llm_packed_documents_total``,
+        ``fedml_llm_loss_tokens_total`` and, for the model's softmax layers,
+        ``fedml_llm_attended_keys_total`` (kept = pairs inside documents)."""
         history = []
         steps = steps or self.args.total_steps
         batches = iter(batch_iter)
+        # a packed step's pairs are summed over KV heads and softmax layers, as the sparse layers' are
+        softmax_heads = self.cfg.n_kv_heads * sum(
+            self.cfg.mixer(i) == "attention" for i in range(self.cfg.n_layers))
         with traced("llm.fit", counters=XLA_COUNTERS) as fit_span:
             for i in range(steps + 1):
                 with traced("llm.next_batch"):
@@ -270,6 +313,13 @@ class LLMTrainer:
                         span.attrs.update({k: m[k] for k in MOE_STATS})
                         for name, kind in zip(MOE_STATS, ("routed", "held")):
                             LLM_EXPERT_TOKENS.inc(m[name], kind=kind)
+                    if PACKED[0] in m:
+                        span.attrs.update({k: m[k] for k in PACKED}, tokens=float(batch[0].size))
+                        LLM_PACKED_DOCUMENTS.inc(m["docs"])
+                        LLM_LOSS_TOKENS.inc(m["loss_tokens"], kind="counted")
+                        LLM_LOSS_TOKENS.inc(batch[0].size - m["loss_tokens"], kind="masked")
+                        LLM_ATTENDED_KEYS.inc(m["doc_pairs"] * softmax_heads, kind="kept")
+                        LLM_ATTENDED_KEYS.inc(m["causal_pairs"] * softmax_heads, kind="causal")
                 with traced("llm.log"):
                     self.logger.log(m)
                 history.append(m)

@@ -23,6 +23,15 @@ on may have a sparse expert layer (``MoE``, ``ops/moe.py``: a router over all
 the dense SwiGLU's place; ``sandwich_norm`` norms each branch's output as well
 as its input; ``mtp_layers`` adds a multi-token-prediction module (``MTP``)
 whose loss ``Transformer.__call__(targets=...)`` returns beside the main one.
+A fifth mixer, ``Mamba``, is the Mamba-2 state-space layer of the Granite
+4.0-H / Bamba family (``attn/in_proj|out_proj/kernel``, a depthwise causal
+convolution, the selective scan of ``ops/ssd.py``, a gated norm); beside it
+the plain ``Attention`` may go without RoPE (``attn_rope``), with a scale of
+its own (``attn_scale``) and, on packed rows, blockwise (no ``s x s``
+scores); ``tie_embeddings`` reads the logits off the embedding.
+``segments`` (document ids of packed rows) reach every mixer: state,
+convolution and attention stop at a document's start, and the loss counts
+the positions whose target lies in their own document.
 The defaults build the plain model.
 """
 
@@ -107,6 +116,21 @@ class TransformerConfig:
     sandwich_norm: bool = False
     # multi-token-prediction modules after the last layer (0 or 1)
     mtp_layers: int = 0
+    # "mamba": the Mamba-2 mixer (``Mamba``): heads x head width is its inner
+    # width; B and C (d_state wide) are shared by the heads of a group; taps of
+    # the depthwise causal convolution; tokens a step of the scan takes
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_d_state: int = 0
+    mamba_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk: int = 256
+    # the plain ``Attention`` mixer: rotary positions or none (NoPE), and the
+    # factor on its scores (0 = 1 / sqrt(head_dim))
+    attn_rope: bool = True
+    attn_scale: float = 0.0
+    # logits = hidden E^T: the embedding is the head (no ``lm_head``)
+    tie_embeddings: bool = False
 
     def has_experts(self, layer: int) -> bool:
         return self.n_routed_experts > 0 and layer >= self.first_k_dense
@@ -189,20 +213,49 @@ def _project(mdl: nn.Module, name: str, x, features, axis=-1):
     return y
 
 
+def _no_seq_axis(mdl) -> None:
+    if mdl.mesh is not None and mdl.seq_axis and mdl.mesh.shape[mdl.seq_axis] > 1:
+        raise NotImplementedError(f"{type(mdl).__name__} has no sequence-sharded form yet")
+
+
+def _no_segments(mdl, segments) -> None:
+    if segments is not None:
+        raise NotImplementedError(f"{type(mdl).__name__} does not take packed documents yet")
+
+
 class Attention(nn.Module):
+    """Grouped-query softmax attention: RoPE unless ``cfg.attn_rope`` is off,
+    scores times ``cfg.attn_scale`` (0: ``1 / sqrt(head_dim)``), causal, and
+    with ``segments`` within a document.  Unpacked rows build their scores
+    whole, or go round the ring on a ``seq`` mesh axis, as they always did;
+    packed rows go through ``ops/sparse_attention.block_sparse_attention``
+    (every block kept: no ``s x s`` tensor, at 32,768 tokens x 32 heads it
+    would be 137 GB), which takes the fused flash kernel where it can and has
+    no sequence-sharded form."""
+
     cfg: TransformerConfig
     mesh: Optional[Any] = None
     seq_axis: Optional[str] = None
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, segments=None):
         cfg = self.cfg
         hd = cfg.head_dim or cfg.d_model // cfg.n_heads
         q = _project(self, "wq", x, (cfg.n_heads, hd))
         k = _project(self, "wk", x, (cfg.n_kv_heads, hd))
         v = _project(self, "wv", x, (cfg.n_kv_heads, hd))
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        if cfg.attn_rope:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
+        scale = cfg.attn_scale or None
+        if segments is not None:
+            from ..ops.sparse_attention import CHUNK, block_sparse_attention
+
+            _no_seq_axis(self)
+            with jax.named_scope("llm.mixer.attention"):
+                out = block_sparse_attention(q, k, v, None, q_chunk=CHUNK, k_chunk=CHUNK,
+                                             scale=scale, mesh=self.mesh, segments=segments)
+            return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
         if cfg.n_kv_heads != cfg.n_heads:  # GQA: repeat kv heads
             rep = cfg.n_heads // cfg.n_kv_heads
             k = jnp.repeat(k, rep, axis=2)
@@ -211,6 +264,8 @@ class Attention(nn.Module):
             from ..ops.ring_attention import ring_attention
             from ..parallel.mesh import AXIS_DATA, AXIS_MODEL
 
+            if scale is not None:
+                raise NotImplementedError("ring attention scales its scores by 1 / sqrt(head_dim)")
             out = ring_attention(
                 q, k, v, self.mesh, axis=self.seq_axis, causal=True,
                 dp_axis=AXIS_DATA, tp_axis=AXIS_MODEL,
@@ -218,13 +273,8 @@ class Attention(nn.Module):
         else:
             from ..ops.ring_attention import dense_attention
 
-            out = dense_attention(q, k, v, causal=True)
+            out = dense_attention(q, k, v, causal=True, scale=scale)
         return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
-
-
-def _no_seq_axis(mdl) -> None:
-    if mdl.mesh is not None and mdl.seq_axis and mdl.mesh.shape[mdl.seq_axis] > 1:
-        raise NotImplementedError(f"{type(mdl).__name__} has no sequence-sharded form yet")
 
 
 def _qk_normed(cfg, q, k):
@@ -248,10 +298,11 @@ class LightningAttention(nn.Module):
     seq_axis: Optional[str] = None
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, segments=None):
         from ..ops.lightning_attention import decay_slopes, lightning_attention
 
         _no_seq_axis(self)
+        _no_segments(self, segments)
         cfg = self.cfg
         nh = cfg.lightning_heads or cfg.n_heads
         hd = cfg.lightning_head_dim or cfg.head_dim or cfg.d_model // cfg.n_heads
@@ -279,10 +330,11 @@ class SparseAttention(nn.Module):
     seq_axis: Optional[str] = None
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, segments=None):
         from ..ops.sparse_attention import sparse_attention
 
         _no_seq_axis(self)
+        _no_segments(self, segments)
         cfg = self.cfg
         hd = cfg.head_dim or cfg.d_model // cfg.n_heads
         q = _project(self, "wq", x, (cfg.n_heads, hd))
@@ -322,10 +374,11 @@ class MLAttention(nn.Module):
     seq_axis: Optional[str] = None
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, segments=None):
         from ..ops.sparse_attention import CHUNK, block_sparse_attention
 
         _no_seq_axis(self)
+        _no_segments(self, segments)
         cfg = self.cfg
         h, nope, rot, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
         c_q = RMSNorm(cfg.norm_eps, name="q_a_norm")(_project(self, "wq_a", x, cfg.q_lora_rank))
@@ -343,8 +396,80 @@ class MLAttention(nn.Module):
         return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
 
 
+def causal_conv(x, kernel, bias, segments=None):
+    """Depthwise causal convolution over the sequence: x (b, s, c), kernel
+    (taps, c), bias (c,) -> ``bias + sum_i kernel[i] * x[t - (taps - 1) + i]``
+    in float32, as ``taps`` shifted products; a tap that would reach before
+    the row's first token, or with ``segments`` (b, s) before its document's,
+    reads zero."""
+    taps, s = kernel.shape[0], x.shape[1]
+    x32, kernel = x.astype(jnp.float32), kernel.astype(jnp.float32)
+    y = bias.astype(jnp.float32) + kernel[-1] * x32
+    for back in range(1, min(taps, s)):
+        shifted = jnp.pad(x32[:, : s - back], ((0, 0), (back, 0), (0, 0)))
+        if segments is not None:   # documents are runs: the same id ``back`` tokens ago is the same document
+            same = jnp.pad(segments[:, back:] == segments[:, : s - back], ((0, 0), (back, 0)))
+            shifted = jnp.where(same[..., None], shifted, 0.0)
+        y = y + kernel[taps - 1 - back] * shifted
+    return y
+
+
+class Mamba(nn.Module):
+    """The Mamba-2 mixer as HF's ``GraniteMoeHybridMambaLayer`` / Bamba has
+    it: ``[z | xBC | dt] = u W_in``; ``xBC = silu(conv(xBC))`` (``causal_conv``,
+    with bias); ``xBC -> x`` (heads x head width), ``B``, ``C`` (groups x
+    state); ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the selective
+    scan ``ops/ssd.ssd`` with the ``D`` skip; ``RMSNorm(y * silu(z)) * w`` over
+    the whole inner width (the gate before the norm); ``W_out``.  With
+    ``segments`` neither the convolution nor the state crosses a document's
+    start."""
+
+    cfg: TransformerConfig
+    mesh: Optional[Any] = None
+    seq_axis: Optional[str] = None
+
+    @nn.compact
+    def __call__(self, x, positions, segments=None):
+        from ..ops.ssd import ssd
+
+        _no_seq_axis(self)
+        cfg, f32 = self.cfg, jnp.float32
+        h, p, n, g = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_d_state, cfg.mamba_groups
+        inner, bc = h * p, 2 * g * n
+        with jax.named_scope("llm.mixer.mamba"):
+            zxbcdt = _project(self, "in_proj", x, 2 * inner + bc + h)
+            z, xbc, dt = jnp.split(zxbcdt, (inner, 2 * inner + bc), axis=-1)
+            # HF's defaults: the convolution as torch's Conv1d draws it (uniform within
+            # 1 / sqrt(taps)), A = 1..heads, D = 1, dt's bias from a step in [1e-3, 1e-1]
+            conv_init = lambda key, shape: jax.random.uniform(
+                key, shape, f32, -1.0, 1.0) * cfg.mamba_d_conv ** -0.5
+            conv_kernel = self.param("conv_kernel", conv_init, (cfg.mamba_d_conv, inner + bc))
+            conv_bias = self.param("conv_bias", conv_init, (inner + bc,))
+            a_log = self.param("A_log", lambda key, shape: jnp.log(jnp.arange(1, shape[0] + 1, dtype=f32)), (h,))
+            d_skip = self.param("D", nn.initializers.ones, (h,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (h,))
+            with jax.named_scope("llm.mixer.mamba.conv"):
+                xbc = nn.silu(causal_conv(xbc, conv_kernel, conv_bias, segments)).astype(cfg.dtype)
+            xs, b_in, c_in = jnp.split(xbc, (inner, inner + g * n), axis=-1)
+            dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+            with jax.named_scope("llm.mixer.mamba.ssd"):
+                y = ssd(xs.reshape(*xs.shape[:2], h, p), dt, -jnp.exp(a_log.astype(f32)),
+                        b_in.reshape(*b_in.shape[:2], g, n), c_in.reshape(*c_in.shape[:2], g, n),
+                        d_skip, segments, cfg.mamba_chunk)
+            y = (y.reshape(*y.shape[:2], inner).astype(f32) * nn.silu(z.astype(f32))).astype(cfg.dtype)
+            y = RMSNorm(cfg.norm_eps, name="norm")(y).astype(cfg.dtype)
+            return _project(self, "out_proj", y, cfg.d_model)
+
+
+def _dt_bias_init(key, shape, dt_min: float = 1e-3, dt_max: float = 1e-1):
+    """``softplus^-1`` of a log-uniform step in [dt_min, dt_max] (Mamba-2's)."""
+    u = jax.random.uniform(key, shape, jnp.float32)
+    dt = jnp.maximum(jnp.exp(u * (jnp.log(dt_max) - jnp.log(dt_min)) + jnp.log(dt_min)), 1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 MIXERS = {"attention": Attention, "lightning-attn": LightningAttention, "minicpm4": SparseAttention,
-          "mla": MLAttention}
+          "mla": MLAttention, "mamba": Mamba}
 
 
 class MLP(nn.Module):
@@ -437,7 +562,7 @@ class Block(nn.Module):
     experts: bool = False  # a sparse expert layer (``moe``) in the dense SwiGLU's (``mlp``) place
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, segments=None):
         cfg = self.cfg
         branch = lambda y: y
         if cfg.scale_depth:  # muP: each residual branch times scale_depth / sqrt(depth)
@@ -449,7 +574,8 @@ class Block(nn.Module):
             after = lambda name, y: RMSNorm(cfg.norm_eps, name=name)(y).astype(x.dtype)
         x = x + branch(after("post_attn_norm", MIXERS[self.mixer](
             cfg, self.mesh, self.seq_axis, name="attn")(
-            RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions
+            RMSNorm(cfg.norm_eps, name="attn_norm")(x), positions,
+            *(() if segments is None else (segments,))
         )))
         ffn = MoE(cfg, name="moe") if self.experts else MLP(cfg, name="mlp")
         x = x + branch(after("post_mlp_norm", ffn(RMSNorm(cfg.norm_eps, name="mlp_norm")(x))))
@@ -492,13 +618,21 @@ def block_remat_policy(cfg: TransformerConfig):
     return policy
 
 
+def targets_in_document(segments):
+    """segments (b, s) -> (b, s) bool: the positions of a packed row whose
+    target (the row's next token) lies in their own document: not a
+    document's last token, not the row's last, not padding (id 0)."""
+    following = jnp.pad(segments[:, 1:], ((0, 0), (0, 1)))
+    return (segments == following) & (segments != 0)
+
+
 class Transformer(nn.Module):
     cfg: TransformerConfig
     mesh: Optional[Any] = None
     seq_axis: Optional[str] = None
 
     @nn.compact
-    def __call__(self, tokens, train: bool = True, targets=None):
+    def __call__(self, tokens, train: bool = True, targets=None, segments=None):
         """Logits (b, s, vocab); or, given ``targets`` (b, s), the float32
         next-token loss of each position (b, s), the head and the softmax
         taken ``cfg.loss_chunk`` positions at a time and rematerialised in the
@@ -506,7 +640,11 @@ class Transformer(nn.Module):
         ``cfg.mtp_layers`` and the targets: that and the MTP module's loss of
         each position (b, s) against the token AFTER its target, through the
         same embedding, head and chunks; the last position has no such token
-        and reads 0."""
+        and reads 0.  ``segments`` (b, s): the document id of every token of a
+        packed row (equal along a document, 0 for padding); no mixer then
+        reaches across a document's start, and with the targets the loss of a
+        position whose target is not in its own document reads 0
+        (``targets_in_document``)."""
         cfg = self.cfg
         b, s = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(s), (b, s))
@@ -518,13 +656,15 @@ class Transformer(nn.Module):
         if cfg.remat:
             policy = block_remat_policy(cfg)
             block = nn.remat(Block, static_argnums=(), policy=policy)
+        packed = () if segments is None else (segments,)
         for i in range(cfg.n_layers):
             x = block(cfg, self.mesh, self.seq_axis, cfg.mixer(i), cfg.has_experts(i),
-                      name=f"layer_{i}")(x, positions)
+                      name=f"layer_{i}")(x, positions, *packed)
         # the module runs where its loss is asked for (and where its parameters are made)
         if cfg.mtp_layers and (targets is not None or self.is_initializing()):
-            if cfg.mtp_layers != 1 or cfg.scale_emb != 1.0 or cfg.dim_model_base:
-                raise NotImplementedError("one MTP module, on a model without the muP scalars")
+            if cfg.mtp_layers != 1 or cfg.scale_emb != 1.0 or cfg.dim_model_base or segments is not None:
+                raise NotImplementedError("one MTP module, on a model without the muP scalars, "
+                                          "on rows that are not packed")
             with jax.named_scope("llm.mtp"):
                 x_mtp = MTP(cfg, block, cfg.mixer(cfg.n_layers - 1), name="mtp")(
                     x, embed(tokens if targets is None else targets), positions)
@@ -532,13 +672,17 @@ class Transformer(nn.Module):
             x = RMSNorm(cfg.norm_eps, name="final_norm")(x)
             if cfg.dim_model_base:
                 x = (x.astype(jnp.float32) / (cfg.d_model / cfg.dim_model_base)).astype(x.dtype)
-            head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.logits_dtype, name="lm_head")
+            if cfg.tie_embeddings:   # hidden E^T, in the embedding's dtype
+                head, logits = embed, lambda head, xc: head.attend(xc)
+            else:
+                head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.logits_dtype, name="lm_head")
+                logits = lambda head, xc: head(xc)
             if targets is None:
-                return head(x)
+                return logits(head, x)
 
             def chunk_loss(head, xc, yc):
                 return optax.softmax_cross_entropy_with_integer_labels(
-                    head(xc).astype(jnp.float32), yc)
+                    logits(head, xc).astype(jnp.float32), yc)
 
             n = cfg.loss_chunk or s
             if s % n:
@@ -549,6 +693,8 @@ class Transformer(nn.Module):
                     [nn.remat(chunk_loss)(head, x[:, i: i + n], targets[:, i: i + n])
                      for i in range(0, s, n)], axis=1)
 
+            if segments is not None:
+                return in_chunks(x, targets) * targets_in_document(segments)
             if not cfg.mtp_layers:
                 return in_chunks(x, targets)
             # position i of the module predicts the target of position i + 1

@@ -43,7 +43,7 @@ __all__ = [
     "Span", "traced", "activate", "current", "start_span",
     "inject", "extract", "new_id", "recent", "clear_recent",
     "install_xla_listener", "XLA_COUNTERS", "LLM_ATTENDED_KEYS", "LLM_EXPERT_TOKENS",
-    "LLM_ATTENTION_SITES",
+    "LLM_ATTENTION_SITES", "LLM_PACKED_DOCUMENTS", "LLM_LOSS_TOKENS",
 ]
 
 #: finished spans, oldest first; a window of some thousand steps fits, and
@@ -281,7 +281,9 @@ LLM_ATTENDED_KEYS = REGISTRY.counter(
     "over batch, KV heads, queries and sparse layers: kind=kept is what the "
     "per-query block selection kept (tokens at or before the query inside its "
     "kept blocks), kind=causal what plain causal attention would attend.  "
-    "kept/causal is the share of the past a sparse layer reads.",
+    "kept/causal is the share of the past a sparse layer reads.  A step on "
+    "packed rows feeds it for its softmax layers: kept is then the keys at or "
+    "before the query in its own document.",
     labels=("kind",),
 )
 LLM_EXPERT_TOKENS = REGISTRY.counter(
@@ -291,6 +293,19 @@ LLM_EXPERT_TOKENS = REGISTRY.counter(
     "experts per token), kind=held those that landed on experts this "
     "expert-parallel rank holds and computes.  held/routed is the share of the "
     "layer's work that is done here.",
+    labels=("kind",),
+)
+LLM_PACKED_DOCUMENTS = REGISTRY.counter(
+    "fedml_llm_packed_documents_total",
+    "Documents in the packed rows of the LLM steps run so far (a document that "
+    "crosses a row's end counts once in each row), summed on the device from "
+    "the batch's segment ids.",
+)
+LLM_LOSS_TOKENS = REGISTRY.counter(
+    "fedml_llm_loss_tokens_total",
+    "Positions of the packed rows an LLM step trained on: kind=counted are those "
+    "whose target lies in their own document (the loss is their mean), "
+    "kind=masked the others (a document's last token, a row's last, padding).",
     labels=("kind",),
 )
 #: fed by ``ops/sparse_attention.attention_path`` while a program is traced

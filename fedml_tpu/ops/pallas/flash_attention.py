@@ -26,6 +26,14 @@ product, no copy), and only the tiles on the diagonal are masked.
     through HBM, and no tile's scores are computed twice.  That is what bounds
     the sequence (``tiles``).
 
+With ``segments`` (packed documents: a document index per token that never
+falls along a row) a query attends the keys at or before it in ITS document:
+every tile that is run compares the two indices (a column of them against a
+row) beside the diagonal's causal mask, and a tile none of whose queries
+shares a document with any of its keys (the key block's last index is under
+the query block's first; both are scalars the grid is given ahead, in SMEM) is
+not run at all.  Without ``segments`` the kernels are PR 34's to the letter.
+
 The kernels take (batch, heads, seq, width); ``causal_attention`` takes and
 gives the model's (batch, seq, heads, width) and swaps the two axes around
 them.  Jax cannot partition a Mosaic call over a mesh: a caller that holds a
@@ -83,7 +91,11 @@ _PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scale, block):
+def _fwd_kernel(*refs, scale, block, segmented):
+    if segmented:   # first/last document of each block (SMEM), then a column and a row of indices
+        lo_ref, hi_ref, q_ref, k_ref, v_ref, col_ref, row_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = refs
     i, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(j == 0)
@@ -98,6 +110,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scal
         if masked:      # i == j: the tile's own rows against its own columns
             s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
                           >= jax.lax.broadcasted_iota(jnp.int32, s.shape, 1), s, NEG_INF)
+        if segmented:   # a query (row) and a key (column) of one document
+            s = jnp.where(col_ref[...] == row_ref[...], s, NEG_INF)
         m_prev = m_sc[...]
         m_next = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_next)
@@ -107,7 +121,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scal
         acc_sc[...] = alpha * acc_sc[...] + jnp.dot(
             p.astype(v_ref.dtype), v_ref[...], preferred_element_type=jnp.float32)
 
-    pl.when(j < i)(functools.partial(attend, False))
+    before = j < i
+    if segmented:   # ... and the key block's last document reaches the query block's first
+        b_ = pl.program_id(0)
+        before &= hi_ref[b_, j] >= lo_ref[b_, i]
+    pl.when(before)(functools.partial(attend, False))
 
     @pl.when(j == i)    # the query block's last tile
     def _():
@@ -120,35 +138,67 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, scal
         lse_ref[...] = lse.T[:1]
 
 
-def _forward(q, k, v, scale: float, interpret: bool):
-    """q (b, h, s, d), k (b, kv, s, d), v (b, kv, s, dv) -> out (b, h, s, dv)
-    in q's dtype, log-sum-exp (b, h, 1, s) float32."""
+def _segment_operands(doc, block: int):
+    """doc (b, s) int32, never falling along a row -> what the segmented
+    kernels take beside q, k, v: each block's first and last index (b, n) for
+    SMEM, and the indices as a column (b, s, 1) and as a row (b, 1, s)."""
+    b, s = doc.shape
+    by_block = doc.reshape(b, s // block, block)
+    return (by_block[:, :, 0], by_block[:, :, -1]), (doc[:, :, None], doc[:, None, :])
+
+
+def _call(kernel, name: str, grid, in_specs, out_specs, out_shape, scratch_shapes, interpret,
+          scalars=()):
+    """``pallas_call`` on ``grid``; with ``scalars`` they are prefetched to
+    SMEM and every index map is given them after the grid's indices."""
+    if scalars:
+        spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars), grid=grid, in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes))
+    else:
+        spec = dict(grid=grid, in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
+    return pl.pallas_call(kernel, out_shape=out_shape, compiler_params=_PARAMS, name=name,
+                          interpret=interpret, **spec)
+
+
+def _forward(q, k, v, doc, scale: float, interpret: bool):
+    """q (b, h, s, d), k (b, kv, s, d), v (b, kv, s, dv), doc (b, s) or None
+    -> out (b, h, s, dv) in q's dtype, log-sum-exp (b, h, 1, s) float32."""
     b, h, s, d = q.shape
     group, dv, block = h // k.shape[1], v.shape[-1], block_of(s)
     n = s // block
     # a tile in the query block's future is not run: it names the diagonal
     # tile's keys again, which copies nothing
-    kv_at = lambda b_, h_, i, j: (b_, h_ // group, jnp.minimum(i, j), 0)
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, block=block),
-        grid=(b, h, n, n),
-        in_specs=[pl.BlockSpec((None, None, block, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-                  pl.BlockSpec((None, None, block, d), kv_at),
-                  pl.BlockSpec((None, None, block, dv), kv_at)],
-        out_specs=[pl.BlockSpec((None, None, block, dv), lambda b_, h_, i, j: (b_, h_, i, 0)),
-                   pl.BlockSpec((None, None, 1, block), lambda b_, h_, i, j: (b_, h_, 0, i))],
-        out_shape=[jax.ShapeDtypeStruct((b, h, s, dv), q.dtype),
-                   jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32), pltpu.VMEM((block, 1), jnp.float32),
-                        pltpu.VMEM((block, dv), jnp.float32)],
-        compiler_params=_PARAMS,
-        name="fedml_causal_attention_fwd",
-        interpret=interpret,
-    )(q, k, v)
+    kv_at = lambda b_, h_, i, j, *_: (b_, h_ // group, jnp.minimum(i, j), 0)
+    q_at = lambda b_, h_, i, j, *_: (b_, h_, i, 0)
+    in_specs = [pl.BlockSpec((None, None, block, d), q_at),
+                pl.BlockSpec((None, None, block, d), kv_at),
+                pl.BlockSpec((None, None, block, dv), kv_at)]
+    scalars, operands = (), (q, k, v)
+    if doc is not None:
+        scalars, (col, row) = _segment_operands(doc, block)
+        in_specs += [pl.BlockSpec((None, block, 1), lambda b_, h_, i, j, *_: (b_, i, 0)),
+                     pl.BlockSpec((None, 1, block), lambda b_, h_, i, j, *_: (b_, 0, jnp.minimum(i, j)))]
+        operands += (col, row)
+    return _call(
+        functools.partial(_fwd_kernel, scale=scale, block=block, segmented=doc is not None),
+        "fedml_causal_attention_fwd", (b, h, n, n), in_specs,
+        [pl.BlockSpec((None, None, block, dv), q_at),
+         pl.BlockSpec((None, None, 1, block), lambda b_, h_, i, j, *_: (b_, h_, 0, i))],
+        [jax.ShapeDtypeStruct((b, h, s, dv), q.dtype),
+         jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
+        [pltpu.VMEM((block, 1), jnp.float32), pltpu.VMEM((block, 1), jnp.float32),
+         pltpu.VMEM((block, dv), jnp.float32)],
+        interpret, scalars)(*scalars, *operands)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                dq_sc, dk_sc, dv_sc, *, scale, block):
+def _bwd_kernel(*refs, scale, block, segmented):
+    if segmented:
+        (lo_ref, hi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, col_ref, row_ref,
+         dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+         dq_sc, dk_sc, dv_sc) = refs
     j, i = pl.program_id(2), pl.program_id(3)
     last = pl.num_programs(3) - 1
 
@@ -168,6 +218,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
         if masked:
             st = jnp.where(jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
                            >= jax.lax.broadcasted_iota(jnp.int32, st.shape, 0), st, NEG_INF)
+        if segmented:   # a key (row) and a query (column) of one document
+            st = jnp.where(col_ref[...] == row_ref[...], st, NEG_INF)
         pt = jnp.exp(st - lse_ref[...])
         dv_sc[...] += jnp.dot(pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
         dpt = jax.lax.dot_general(v_ref[...], do, _NT, preferred_element_type=jnp.float32)
@@ -177,7 +229,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
         dq_sc[rows, :] += jax.lax.dot_general(dst, k, _TN, preferred_element_type=jnp.float32)
 
     pl.when(i == j)(functools.partial(attend, True))
-    pl.when(i > j)(functools.partial(attend, False))
+    after = i > j
+    if segmented:
+        b_ = pl.program_id(0)
+        after &= hi_ref[b_, j] >= lo_ref[b_, i]
+    pl.when(after)(functools.partial(attend, False))
 
     @pl.when(i == last)
     def _():
@@ -189,7 +245,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
         dq_ref[...] = dq_sc[...].astype(dq_ref.dtype)
 
 
-def _backward(q, k, v, out, lse, d_out, scale: float, interpret: bool):
+def _backward(q, k, v, doc, out, lse, d_out, scale: float, interpret: bool):
     b, h, s, d = q.shape
     kv, dv, block = k.shape[1], v.shape[-1], block_of(s)
     group, n = h // kv, s // block
@@ -200,62 +256,68 @@ def _backward(q, k, v, out, lse, d_out, scale: float, interpret: bool):
     part = k.dtype if group == 1 else jnp.float32
     # a tile in the key block's past is not run: it names the diagonal tile's
     # queries again
-    q_at = lambda b_, h_, j, i: (b_, h_, jnp.maximum(i, j), 0)
-    row_at = lambda b_, h_, j, i: (b_, h_, 0, jnp.maximum(i, j))
-    kv_at = lambda b_, h_, j, i: (b_, h_ // group, j, 0)
-    dkv_at = lambda b_, h_, j, i: (b_, h_, j, 0)
-    dq, dk, dv_ = pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=scale, block=block),
-        grid=(b, h, n, n),
-        in_specs=[pl.BlockSpec((None, None, block, d), q_at),
-                  pl.BlockSpec((None, None, block, d), kv_at),
-                  pl.BlockSpec((None, None, block, dv), kv_at),
-                  pl.BlockSpec((None, None, block, dv), q_at),
-                  pl.BlockSpec((None, None, 1, block), row_at),
-                  pl.BlockSpec((None, None, 1, block), row_at)],
-        out_specs=[pl.BlockSpec((None, None, s, d), lambda b_, h_, j, i: (b_, h_, 0, 0)),
-                   pl.BlockSpec((None, None, block, d), dkv_at),
-                   pl.BlockSpec((None, None, block, dv), dkv_at)],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((b, h, s, d), part),
-                   jax.ShapeDtypeStruct((b, h, s, dv), part)],
-        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32), pltpu.VMEM((block, d), jnp.float32),
-                        pltpu.VMEM((block, dv), jnp.float32)],
-        compiler_params=_PARAMS,
-        name="fedml_causal_attention_bwd",
-        interpret=interpret,
-    )(q, k, v, d_out, lse, delta)
+    q_at = lambda b_, h_, j, i, *_: (b_, h_, jnp.maximum(i, j), 0)
+    row_at = lambda b_, h_, j, i, *_: (b_, h_, 0, jnp.maximum(i, j))
+    kv_at = lambda b_, h_, j, i, *_: (b_, h_ // group, j, 0)
+    dkv_at = lambda b_, h_, j, i, *_: (b_, h_, j, 0)
+    in_specs = [pl.BlockSpec((None, None, block, d), q_at),
+                pl.BlockSpec((None, None, block, d), kv_at),
+                pl.BlockSpec((None, None, block, dv), kv_at),
+                pl.BlockSpec((None, None, block, dv), q_at),
+                pl.BlockSpec((None, None, 1, block), row_at),
+                pl.BlockSpec((None, None, 1, block), row_at)]
+    scalars, operands = (), (q, k, v, d_out, lse, delta)
+    if doc is not None:     # transposed tiles: the keys' indices down, the queries' across
+        scalars, (col, row) = _segment_operands(doc, block)
+        in_specs += [pl.BlockSpec((None, block, 1), lambda b_, h_, j, i, *_: (b_, j, 0)),
+                     pl.BlockSpec((None, 1, block), lambda b_, h_, j, i, *_: (b_, 0, jnp.maximum(i, j)))]
+        operands += (col, row)
+    dq, dk, dv_ = _call(
+        functools.partial(_bwd_kernel, scale=scale, block=block, segmented=doc is not None),
+        "fedml_causal_attention_bwd", (b, h, n, n), in_specs,
+        [pl.BlockSpec((None, None, s, d), lambda b_, h_, j, i, *_: (b_, h_, 0, 0)),
+         pl.BlockSpec((None, None, block, d), dkv_at),
+         pl.BlockSpec((None, None, block, dv), dkv_at)],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((b, h, s, d), part),
+         jax.ShapeDtypeStruct((b, h, s, dv), part)],
+        [pltpu.VMEM((s, d), jnp.float32), pltpu.VMEM((block, d), jnp.float32),
+         pltpu.VMEM((block, dv), jnp.float32)],
+        interpret, scalars)(*scalars, *operands)
     if group > 1:
         dk = dk.reshape(b, kv, group, s, d).sum(2).astype(k.dtype)
         dv_ = dv_.reshape(b, kv, group, s, dv).sum(2).astype(v.dtype)
     return dq, dk, dv_
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _attention(q, k, v, scale, interpret):
-    return _forward(q, k, v, scale, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _attention(q, k, v, doc, scale, interpret):
+    return _forward(q, k, v, doc, scale, interpret)[0]
 
 
-def _attention_fwd(q, k, v, scale, interpret):
-    out, lse = _forward(q, k, v, scale, interpret)
-    return out, (q, k, v, out, lse)
+def _attention_fwd(q, k, v, doc, scale, interpret):
+    out, lse = _forward(q, k, v, doc, scale, interpret)
+    return out, (q, k, v, doc, out, lse)
 
 
 def _attention_bwd(scale, interpret, saved, d_out):
-    return _backward(*saved, d_out, scale, interpret)
+    return (*_backward(*saved, d_out, scale, interpret), None)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
-def causal_attention(q, k, v, *, scale, interpret=None):
+def causal_attention(q, k, v, *, scale, segments=None, interpret=None):
     """q: (b, s, h, d); k: (b, s, kv, d); v: (b, s, kv, dv), ``tiles(q, k, v)``
     -> softmax attention of each query over the tokens at or before it,
-    (b, s, h, dv) in q's dtype; differentiable in q, k and v.  ``interpret``
-    None derives from the backend (``backend.resolve_interpret``)."""
+    (b, s, h, dv) in q's dtype; differentiable in q, k and v.  ``segments``
+    (b, s) int32, a document index per token that never falls along a row
+    (``ops/segments.document_index``): over the tokens at or before it in its own
+    document.  ``interpret`` None derives from the backend
+    (``backend.resolve_interpret``)."""
     if not tiles(q, k, v):
         raise ValueError(f"the kernel does not tile {q.shape}, {k.shape}, {v.shape}")
     heads_first = lambda t: jnp.swapaxes(t, 1, 2)
-    out = _attention(heads_first(q), heads_first(k), heads_first(v), float(scale),
+    out = _attention(heads_first(q), heads_first(k), heads_first(v), segments, float(scale),
                      resolve_interpret(interpret))
     return heads_first(out)

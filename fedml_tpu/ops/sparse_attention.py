@@ -31,6 +31,8 @@ kernel where ``attention_path`` finds that it can:
     once; no score matrix is kept and nothing is rematerialised twice.  The
     values may have a width of their own (latent attention's 192-wide keys
     over 128-wide values), and ``keep=None`` is plain causal attention.
+    With ``segments`` (packed documents) a query attends its own document's
+    tokens alone: a token-level mask beside the blocks', on either path.
 
 ``attention_path``  the ONE place that chooses between that pass and
     ``ops/pallas/flash_attention.causal_attention``, the same algorithm with
@@ -48,6 +50,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.trace import LLM_ATTENTION_SITES
 from .pallas import flash_attention
+from .segments import document_index
 
 NEG_INF = -1e30
 #: the name a selection's block mask carries, so that a remat policy can keep
@@ -162,6 +165,17 @@ def _chunks(q, k, v, keep, block_size: int, cq: int, ck: int):
     return qs, ks, vs, keeps
 
 
+def _doc_chunks(doc, cq: int, ck: int):
+    """The tokens' document indices by query chunk (nq, b, cq) and by key
+    chunk (nk, b, ck); a pair of ``None`` where the rows are not packed (the
+    scans then carry nothing for them)."""
+    if doc is None:
+        return None, None
+    b, s = doc.shape
+    return (jnp.moveaxis(doc.reshape(b, s // cq, cq), 1, 0),
+            jnp.moveaxis(doc.reshape(b, s // ck, ck), 1, 0))
+
+
 def _zeros_for(ks, vs):
     """Float32 zeros shaped as keys and as values: one array for both where
     the two widths are equal."""
@@ -169,37 +183,43 @@ def _zeros_for(ks, vs):
     return zk, (zk if vs.shape == ks.shape else jnp.zeros(vs.shape, jnp.float32))
 
 
-def _masked_logits(qc, kc, keep_qk, iq, ik, block_size: int, scale: float):
+def _masked_logits(qc, kc, keep_qk, iq, ik, block_size: int, scale: float, docs=(None, None)):
     """Scores of one pair of chunks, (b, kv, g, cq, ck) float32, with their
-    mask: the kept blocks' tokens at or before each query."""
+    mask: the kept blocks' tokens at or before each query, and with ``docs``
+    (the chunks' document indices, (b, cq) and (b, ck)) in its document."""
     cq, ck = qc.shape[1], kc.shape[1]
     q_pos, k_pos = iq * cq + jnp.arange(cq), ik * ck + jnp.arange(ck)
     mask = (jnp.repeat(keep_qk, block_size, axis=-1) & (q_pos[:, None] >= k_pos[None, :]))[:, :, None]
+    if docs[0] is not None:
+        mask = mask & (docs[0][:, :, None] == docs[1][:, None, :])[:, None, None]
     logits = jnp.einsum("bqkgd,btkd->bkgqt", qc, kc, preferred_element_type=jnp.float32) * scale
     return jnp.where(mask, logits, NEG_INF), mask
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _attend(q, k, v, keep, block_size, cq, ck, scale):
-    return _attend_fwd(q, k, v, keep, block_size, cq, ck, scale)[0]
+def _attend(q, k, v, keep, block_size, cq, ck, scale, doc=None):
+    return _attend_fwd(q, k, v, keep, block_size, cq, ck, scale, doc)[0]
 
 
-def _attend_fwd(q, k, v, keep, block_size, cq, ck, scale):
+def _attend_fwd(q, k, v, keep, block_size, cq, ck, scale, doc=None):
     """Online softmax over key chunks for each query chunk; keeps the output
-    and each row's log-sum-exp for the backward pass, and no score."""
+    and each row's log-sum-exp for the backward pass, and no score.  ``doc``
+    (b, s): the tokens' document indices on packed rows."""
     b, s, h, d = q.shape
     kv, f32 = k.shape[2], jnp.float32
     qs, ks, vs, keeps = _chunks(q, k, v, keep, block_size, cq, ck)
+    doc_qs, doc_ks = _doc_chunks(doc, cq, ck)
 
     def one_query_chunk(args):
-        qc, keep_q, iq = args
+        qc, keep_q, iq, doc_q = args
 
         def one_key_chunk(carry, xs):
-            kc, vc, keep_qk, ik = xs
+            kc, vc, keep_qk, ik, doc_k = xs
 
             def attend(carry):
                 m, l, o = carry
-                logits, mask = _masked_logits(qc, kc, keep_qk, iq, ik, block_size, scale)
+                logits, mask = _masked_logits(qc, kc, keep_qk, iq, ik, block_size, scale,
+                                              (doc_q, doc_k))
                 m_new = jnp.maximum(m, logits.max(-1))
                 alpha = jnp.exp(m - m_new)
                 p = jnp.where(mask, jnp.exp(logits - m_new[..., None]), 0.0)
@@ -212,36 +232,39 @@ def _attend_fwd(q, k, v, keep, block_size, cq, ck, scale):
 
         init = (jnp.full((b, kv, h // kv, cq), NEG_INF, f32), jnp.zeros((b, kv, h // kv, cq), f32),
                 jnp.zeros((b, cq, kv, h // kv, v.shape[-1]), f32))
-        (m, l, o), _ = jax.lax.scan(one_key_chunk, init, (ks, vs, keep_q, jnp.arange(s // ck)))
+        (m, l, o), _ = jax.lax.scan(one_key_chunk, init,
+                                    (ks, vs, keep_q, jnp.arange(s // ck), doc_ks))
         l = jnp.maximum(l, 1e-30)
         return (o / jnp.moveaxis(l, 3, 1)[..., None]).astype(q.dtype), m + jnp.log(l)
 
-    out, lse = jax.lax.map(one_query_chunk, (qs, keeps, jnp.arange(s // cq)))
+    out, lse = jax.lax.map(one_query_chunk, (qs, keeps, jnp.arange(s // cq), doc_qs))
     out = jnp.moveaxis(out, 0, 1).reshape(b, s, h, v.shape[-1])
-    return out, (q, k, v, keep, out, lse)
+    return out, (q, k, v, keep, doc, out, lse)
 
 
 def _attend_bwd(block_size, cq, ck, scale, saved, d_out):
     """The flash-attention backward: each pair of chunks recomputes its
     probabilities from the saved log-sum-exp, once."""
-    q, k, v, keep, out, lse = saved                      # lse: (nq, b, kv, g, cq)
+    q, k, v, keep, doc, out, lse = saved                 # lse: (nq, b, kv, g, cq)
     b, s, h, d = q.shape
     kv, f32 = k.shape[2], jnp.float32
     qs, ks, vs, keeps = _chunks(q, k, v, keep, block_size, cq, ck)
+    doc_qs, doc_ks = _doc_chunks(doc, cq, ck)
     dos = jnp.moveaxis(d_out.reshape(b, s // cq, cq, kv, h // kv, v.shape[-1]), 1, 0)
     # delta_t = sum_j p_tj dp_tj = do_t . o_t
     deltas = jnp.moveaxis(jnp.sum(d_out.astype(f32) * out.astype(f32), -1)
                           .reshape(b, s // cq, cq, kv, h // kv), 1, 0)
 
     def one_query_chunk(dkv, args):
-        qc, doc, keep_q, lse_q, delta_q, iq = args
+        qc, doc, keep_q, lse_q, delta_q, iq, doc_q = args
         delta_q = jnp.transpose(delta_q, (0, 2, 3, 1))   # (b, kv, g, cq)
 
         def one_key_chunk(dq, xs):
-            kc, vc, keep_qk, ik = xs
+            kc, vc, keep_qk, ik, doc_k = xs
 
             def attend(dq):
-                logits, mask = _masked_logits(qc, kc, keep_qk, iq, ik, block_size, scale)
+                logits, mask = _masked_logits(qc, kc, keep_qk, iq, ik, block_size, scale,
+                                              (doc_q, doc_k))
                 p = jnp.where(mask, jnp.exp(logits - lse_q[..., None]), 0.0)
                 dv = jnp.einsum("bkgqt,bqkgd->btkd", p.astype(doc.dtype), doc, preferred_element_type=f32)
                 dp = jnp.einsum("bqkgd,btkd->bkgqt", doc, vc, preferred_element_type=f32)
@@ -255,13 +278,13 @@ def _attend_bwd(block_size, cq, ck, scale, saved, d_out):
                                 lambda dq: (dq, nothing), dq)
 
         dq, (dk, dv) = jax.lax.scan(one_key_chunk, jnp.zeros(qc.shape, f32),
-                                    (ks, vs, keep_q, jnp.arange(s // ck)))
+                                    (ks, vs, keep_q, jnp.arange(s // ck), doc_ks))
         return (dkv[0] + dk, dkv[1] + dv), dq.astype(q.dtype)
 
     (dk, dv), dq = jax.lax.scan(one_query_chunk, _zeros_for(ks, vs),
-                                (qs, dos, keeps, lse, deltas, jnp.arange(s // cq)))
+                                (qs, dos, keeps, lse, deltas, jnp.arange(s // cq), doc_qs))
     unchunk = lambda t, like: jnp.moveaxis(t, 0, 1).reshape(like.shape).astype(like.dtype)
-    return unchunk(dq, q), unchunk(dk, k), unchunk(dv, v), None
+    return unchunk(dq, q), unchunk(dk, k), unchunk(dv, v), None, None
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
@@ -302,7 +325,8 @@ def _by_head_groups(fn, group: int, q, k, v):
 
 
 def block_sparse_attention(q, k, v, keep=None, *, block_size: int = 64, q_chunk: int = 1024,
-                           k_chunk: int = 1024, scale=None, mesh=None, head_group: int = 0):
+                           k_chunk: int = 1024, scale=None, mesh=None, head_group: int = 0,
+                           segments=None):
     """q: (b, s, h, d); k: (b, s, kv, d); v: (b, s, kv, dv), a value width of
     its own allowed (latent attention: 192-wide queries and keys, 128-wide
     values); keep: (b, kv, s, s // block_size) bool, or (b, 1, ...) for all KV
@@ -312,19 +336,21 @@ def block_sparse_attention(q, k, v, keep=None, *, block_size: int = 64, q_chunk:
     may be sharded, which keeps it on the ``lax`` pass (``attention_path``).
     ``head_group``, with ``keep=None`` and a key per head: the heads the
     ``lax`` pass takes at a time (its float32 accumulators scale with them);
-    0 is all of them."""
+    0 is all of them.  ``segments`` (b, s): document ids of packed rows,
+    equal along a document; a query then attends its own document alone."""
     b, s, h, d = q.shape
     scale = d ** -0.5 if scale is None else scale
+    doc = None if segments is None else document_index(segments)
     if attention_path(q, k, v, keep, mesh) == "kernel":
-        return flash_attention.causal_attention(q, k, v, scale=scale)
+        return flash_attention.causal_attention(q, k, v, scale=scale, segments=doc)
     cq, scale = _chunk(s, q_chunk), float(scale)
     if keep is not None:
-        return _attend(q, k, v, keep, block_size, cq, _chunk(s, k_chunk, block_size), scale)
+        return _attend(q, k, v, keep, block_size, cq, _chunk(s, k_chunk, block_size), scale, doc)
     # any block size will do: one that divides a chunk of keys
     ck = _chunk(s, k_chunk)
     block_size = _chunk(ck, block_size)
     keep = jnp.ones((b, 1, s, s // block_size), bool)
-    return _by_head_groups(lambda q, k, v: _attend(q, k, v, keep, block_size, cq, ck, scale),
+    return _by_head_groups(lambda q, k, v: _attend(q, k, v, keep, block_size, cq, ck, scale, doc),
                            head_group or h, q, k, v)
 
 

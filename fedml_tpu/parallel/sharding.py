@@ -35,6 +35,12 @@ TRANSFORMER_RULES = [
     # up-projections from them shard heads over model, like wq
     (r".*attn/w(q|kv)_a/kernel", lambda dp, tp: P(dp, None)),
     (r".*attn/w(q|kv)_b/kernel", lambda dp, tp: P(dp, tp, None)),
+    # a Mamba-2 mixer: in_proj (d_model -> z | xBC | dt) like an mlp's up,
+    # out_proj (inner -> d_model) like its down; the convolution's taps and
+    # the per-head scalars are small and replicated
+    (r".*attn/in_proj/kernel", lambda dp, tp: P(dp, tp)),
+    (r".*attn/out_proj/kernel", lambda dp, tp: P(tp, dp)),
+    (r".*attn/(conv_kernel|conv_bias|A_log|D|dt_bias)", lambda dp, tp: P()),
     # mlp: gate/up shard out over model; down shards in over model; an expert
     # layer's shared experts (moe/shared) are an mlp
     (r".*(mlp|moe/shared)/w_(gate|up)/kernel", lambda dp, tp: P(dp, tp)),

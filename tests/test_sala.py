@@ -353,10 +353,13 @@ def first_steps(bench):
     """The cell's driver at rehearsal sizes, in process: ``fit``'s first three
     steps and the float32 reference's, with the float8 control."""
     import jax
+    from fedml_tpu.obs import trace as obstrace
     from fedml_tpu.ops import lightning_attention, sparse_attention
 
     cell = {"name": CELL, "chips": 1}
     driver = bench["sala"].Driver(cell, bench["config"], bench["traffic"], 11, jax.devices()[:1])
+    # the counter is the process's: what other models' steps fed it is set aside
+    attended_before = {k: obstrace.LLM_ATTENDED_KEYS.value(kind=k) for k in ("kept", "causal")}
     with pytest.MonkeyPatch.context() as mp:
         # 64 tokens in chunks of 16, so that the step's scans carry their state across chunks
         mp.setattr(lightning_attention, "CHUNK", 16)
@@ -366,7 +369,7 @@ def first_steps(bench):
         driver.first_steps()
     after = jax.tree_util.tree_map(np.asarray, driver.trainer.params)
     return {"driver": driver, "base": base, "after": after, "reference": driver.reference(),
-            "control": driver.reference(control="fp8")}
+            "control": driver.reference(control="fp8"), "attended_before": attended_before}
 
 
 def test_model_follows_the_reference_and_the_control_does_not(first_steps, bench):
@@ -415,9 +418,10 @@ def test_adapter_spans_and_the_attended_keys_counter(first_steps, bench):
     assert len(steps) >= 4
     per_step = tuple(2 * x for x in bench["flops"].kept_keys(bench["config"], 64))
     assert {(s.attrs["sparse_kept"], s.attrs["sparse_causal"]) for s in steps} == {per_step}
-    assert obstrace.LLM_ATTENDED_KEYS.value(kind="kept") >= len(steps) * per_step[0]
-    ratio = obstrace.LLM_ATTENDED_KEYS.value(kind="kept") / obstrace.LLM_ATTENDED_KEYS.value(kind="causal")
-    assert abs(ratio - per_step[0] / per_step[1]) < 1e-6
+    kept, causal = (obstrace.LLM_ATTENDED_KEYS.value(kind=k) - first_steps["attended_before"][k]
+                    for k in ("kept", "causal"))
+    assert kept >= len(steps) * per_step[0]
+    assert abs(kept / causal - per_step[0] / per_step[1]) < 1e-6
 
 
 def test_full_fine_tuning_is_still_the_default(bench):
