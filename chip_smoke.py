@@ -13,6 +13,9 @@ Drives, through the entry points a user calls and at the flagship widths:
   Leg D  latent attention (``MLAttention``) at a small shape that tiles: the
          fused flash kernel must be the path taken, and agree with the
          blockwise ``lax`` pass in the output and the input's gradient;
+  Leg E  the Mamba-2 mixer (``Mamba``) on packed rows at a small shape that
+         tiles: the fused selective-scan kernel pair must be the path taken,
+         and agree with the ``lax.scan`` in the output and the input's gradient;
   Leg B  the 542M-parameter LLM train step (``LLMTrainer.fit``), on every mesh
          the visible devices allow.
 
@@ -202,8 +205,37 @@ def leg_c(jax, dry: bool, ref_a: dict | None) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Leg D — the flash kernel under the module that calls it
+# Legs D and E — a fused kernel under the module that calls it
 # ---------------------------------------------------------------------------
+
+def kernel_against_lax(jax, dry: bool, module_cls, cfg, sites, kernel_sites: dict, x, probe, *args):
+    """``module_cls(cfg)`` must take its fused kernel (``sites()`` grows by
+    ``kernel_sites``) and the same module holding a mesh its ``lax`` form,
+    whatever the backend -> (sites taken, relative gaps of the output and of
+    the input's gradient under ``probe``)."""
+    import jax.numpy as jnp
+
+    from fedml_tpu.parallel import mesh as meshlib
+
+    params = jax.tree_util.tree_map(
+        lambda p: p.astype(cfg.dtype), jax.jit(module_cls(cfg).init)(jax.random.PRNGKey(5), x, *args)["params"])
+
+    def run(module):
+        def both(x):
+            y, back = jax.vjp(lambda x: module.apply({"params": params}, x, *args), x)
+            return y, back(probe)[0]
+        return jax.jit(both)(x)
+
+    before = sites()
+    got = run(module_cls(cfg))
+    taken = {p: n - before[p] for p, n in sites().items()}
+    want = run(module_cls(cfg, mesh=meshlib.make_mesh((meshlib.AXIS_DATA,), devices=jax.devices()[:1])))
+    if not dry:
+        check(jax.default_backend() == "tpu", f"backend is {jax.default_backend()}")
+        check(taken == kernel_sites, f"the kernel was not the path taken: {taken}")
+    return taken, [float(jnp.linalg.norm((g - w).astype(jnp.float32).ravel())
+                         / jnp.linalg.norm(w.astype(jnp.float32).ravel())) for g, w in zip(got, want)]
+
 
 def leg_d(jax, dry: bool) -> dict:
     import jax.numpy as jnp
@@ -211,7 +243,6 @@ def leg_d(jax, dry: bool) -> dict:
 
     from fedml_tpu.models.transformer import MLAttention, TransformerConfig
     from fedml_tpu.ops.sparse_attention import attention_sites
-    from fedml_tpu.parallel import mesh as meshlib
 
     t0 = time.perf_counter()
     seq = 128 if dry else 1024
@@ -222,32 +253,43 @@ def leg_d(jax, dry: bool) -> dict:
     x = jax.random.normal(jax.random.PRNGKey(3), (2, seq, cfg.d_model), cfg.dtype)
     probe = jax.random.normal(jax.random.PRNGKey(4), x.shape, cfg.dtype)
     pos = jnp.broadcast_to(jnp.arange(seq), (2, seq))
-    params = jax.tree_util.tree_map(
-        lambda p: p.astype(cfg.dtype), MLAttention(cfg).init(jax.random.PRNGKey(5), x, pos)["params"])
-
-    def run(module):
-        """The module's output and the gradient to its input under ``probe``."""
-        def both(x):
-            y, back = jax.vjp(lambda x: module.apply({"params": params}, x, pos), x)
-            return y, back(probe)[0]
-        return jax.jit(both)(x)
-
-    before = attention_sites()
-    got = run(MLAttention(cfg))
-    taken = {p: n - before[p] for p, n in attention_sites().items()}
-    # a module that holds a mesh stays on the lax pass, whatever the backend
-    want = run(MLAttention(cfg, mesh=meshlib.make_mesh((meshlib.AXIS_DATA,), devices=jax.devices()[:1])))
-    if not dry:
-        check(jax.default_backend() == "tpu", f"backend is {jax.default_backend()}")
-        check(taken == {"kernel": 1, "blockwise": 0}, f"the kernel was not the path taken: {taken}")
-    gaps = [float(jnp.linalg.norm((g - w).astype(jnp.float32).ravel())
-                  / jnp.linalg.norm(w.astype(jnp.float32).ravel())) for g, w in zip(got, want)]
+    taken, gaps = kernel_against_lax(jax, dry, MLAttention, cfg, attention_sites,
+                                     {"kernel": 1, "blockwise": 0}, x, probe, pos)
     # bfloat16 probabilities rounded under other running maxima (tiles of
     # 1,024 against chunks of 512) differ by about 1e-3; a wrong tile by 1
     check(all(np.isfinite(gaps)) and max(gaps) < 1e-2,
           f"kernel against the lax pass: output and input gradient differ by {gaps}")
     log(f"leg D: MLAttention {seq} tokens x 8 heads x 192|128, sites {taken}, "
         f"gaps to the lax pass (output, input gradient) {gaps}")
+    return {"kernels_s": time.perf_counter() - t0, "memory": memory(jax)}
+
+
+def leg_e(jax, dry: bool) -> dict:
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.models.transformer import Mamba, TransformerConfig
+    from fedml_tpu.ops.ssd import scan_sites
+
+    t0 = time.perf_counter()
+    seq, chunk = (256, 128) if dry else (2048, 256)
+    cfg = TransformerConfig(
+        vocab_size=256, d_model=256, n_layers=1, n_heads=4, n_kv_heads=4, max_seq_len=seq,
+        mixer_types=("mamba",), mamba_heads=16, mamba_head_dim=64, mamba_d_state=128, mamba_chunk=chunk)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, seq, cfg.d_model), cfg.dtype)
+    probe = jax.random.normal(jax.random.PRNGKey(4), x.shape, cfg.dtype)
+    pos = jnp.broadcast_to(jnp.arange(seq), (2, seq))
+    # documents that start inside chunks, and one that is a row's last token
+    cuts = np.array([[seq // 3, seq // 2 + 5], [7, seq - 1]])
+    segments = jnp.asarray((np.arange(seq)[None, :, None] >= cuts[:, None, :]).sum(-1), jnp.int32)
+    taken, gaps = kernel_against_lax(jax, dry, Mamba, cfg, scan_sites, {"kernel": 1, "scan": 0},
+                                     x, probe, pos, segments)
+    # bfloat16 tiles cast before other products differ by about 3e-3; a wrong
+    # reset or a wrong state by 0.1 and more
+    check(all(np.isfinite(gaps)) and max(gaps) < 2e-2,
+          f"kernel pair against the lax scan: output and input gradient differ by {gaps}")
+    log(f"leg E: Mamba {seq} tokens x 16 heads x 64, state 128, chunk {chunk}, sites {taken}, "
+        f"gaps to the lax scan (output, input gradient) {gaps}")
     return {"kernels_s": time.perf_counter() - t0, "memory": memory(jax)}
 
 
@@ -365,6 +407,7 @@ def main() -> int:
     legs = [("A", lambda: leg_a(jax, dry)),
             ("C", lambda: leg_c(jax, dry, results.get("A"))),
             ("D", lambda: leg_d(jax, dry)),
+            ("E", lambda: leg_e(jax, dry)),
             ("B", lambda: leg_b(jax, dry, None, compiles))]
     if n >= 4 and n % 2 == 0:
         legs.append(("B data x model", lambda: leg_b(

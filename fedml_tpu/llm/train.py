@@ -42,6 +42,7 @@ from ..obs.metrics import MetricsLogger
 from ..obs.trace import (LLM_ATTENDED_KEYS, LLM_EXPERT_TOKENS, LLM_LOSS_TOKENS, LLM_PACKED_DOCUMENTS,
                          XLA_COUNTERS, install_xla_listener, traced)
 from ..ops.sparse_attention import ATTENTION_PATHS, attention_sites
+from ..ops.ssd import SCAN_PATHS, scan_sites
 from ..parallel import mesh as meshlib, sharding
 from . import lora as lora_lib
 
@@ -162,6 +163,8 @@ class LLMTrainer:
         #: the step program's blockwise-attention call sites by path, known
         #: once the program has been traced (its first call)
         self.attention_sites = dict.fromkeys(ATTENTION_PATHS, 0)
+        #: ... and its selective-scan call sites, likewise
+        self.scan_sites = dict.fromkeys(SCAN_PATHS, 0)
         # Pin the step's output shardings to the input shardings: with
         # donation and unspecified out_shardings, XLA may pick different
         # layouts for the outputs, and the SECOND call then recompiles
@@ -234,12 +237,13 @@ class LLMTrainer:
 
         def update(trained, opt_state, base, tokens, targets, *segments):
             # runs while the program is traced: the sites counted meanwhile are its own
-            before = attention_sites()
+            before, scans_before = attention_sites(), scan_sites()
             # the scopes name each op's phase in a device profile (XProf)
             with jax.named_scope("llm.fwd_bwd"):
                 (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                     trained, base, tokens, targets, *segments)
             self.attention_sites = {p: n - before[p] for p, n in attention_sites().items()}
+            self.scan_sites = {p: n - scans_before[p] for p, n in scan_sites().items()}
             with jax.named_scope("llm.optimizer"):
                 updates, opt_state = opt.update(grads, opt_state, trained)
                 trained = optax.apply_updates(trained, updates)
@@ -282,7 +286,9 @@ class LLMTrainer:
         ``moe_max_load`` likewise, and ``fedml_llm_expert_tokens_total``.
         ``llm.fit`` says on which path the step program's blockwise-attention
         call sites were built: ``attn_kernel_sites``, ``attn_blockwise_sites``
-        (``fedml_llm_attention_sites_total`` counts every traced program's).
+        (``fedml_llm_attention_sites_total`` counts every traced program's),
+        and its selective-scan sites: ``scan_kernel_sites``, ``scan_scan_sites``
+        (``fedml_llm_scan_sites_total``).
         A batch of three arrays is packed rows: ``docs``, ``loss_tokens``,
         ``doc_pairs`` and ``causal_pairs`` in each history entry and on
         ``llm.step``, ``fedml_llm_packed_documents_total``,
@@ -324,6 +330,7 @@ class LLMTrainer:
                     self.logger.log(m)
                 history.append(m)
             fit_span.attrs.update({f"attn_{p}_sites": n for p, n in self.attention_sites.items()})
+            fit_span.attrs.update({f"scan_{p}_sites": n for p, n in self.scan_sites.items()})
         return history
 
     def n_params(self) -> int:

@@ -455,7 +455,7 @@ class Mamba(nn.Module):
             with jax.named_scope("llm.mixer.mamba.ssd"):
                 y = ssd(xs.reshape(*xs.shape[:2], h, p), dt, -jnp.exp(a_log.astype(f32)),
                         b_in.reshape(*b_in.shape[:2], g, n), c_in.reshape(*c_in.shape[:2], g, n),
-                        d_skip, segments, cfg.mamba_chunk)
+                        d_skip, segments, cfg.mamba_chunk, self.mesh)
             y = (y.reshape(*y.shape[:2], inner).astype(f32) * nn.silu(z.astype(f32))).astype(cfg.dtype)
             y = RMSNorm(cfg.norm_eps, name="norm")(y).astype(cfg.dtype)
             return _project(self, "out_proj", y, cfg.d_model)

@@ -43,7 +43,7 @@ __all__ = [
     "Span", "traced", "activate", "current", "start_span",
     "inject", "extract", "new_id", "recent", "clear_recent",
     "install_xla_listener", "XLA_COUNTERS", "LLM_ATTENDED_KEYS", "LLM_EXPERT_TOKENS",
-    "LLM_ATTENTION_SITES", "LLM_PACKED_DOCUMENTS", "LLM_LOSS_TOKENS",
+    "LLM_ATTENTION_SITES", "LLM_SCAN_SITES", "LLM_PACKED_DOCUMENTS", "LLM_LOSS_TOKENS",
 ]
 
 #: finished spans, oldest first; a window of some thousand steps fits, and
@@ -316,6 +316,16 @@ LLM_ATTENTION_SITES = REGISTRY.counter(
     "(plain causal attention on one TPU device at shapes it tiles), "
     "path=blockwise the lax pass (a block mask, a mesh, another backend or "
     "other shapes).  Counted at build time, once a site a trace.",
+    labels=("path",),
+)
+#: fed by ``ops/ssd.scan_path`` while a program is traced
+LLM_SCAN_SITES = REGISTRY.counter(
+    "fedml_llm_scan_sites_total",
+    "Selective-scan (Mamba-2 SSD) call sites of the programs traced so far, by "
+    "the path each was built on: path=kernel is the fused Pallas kernel pair "
+    "(one TPU device, shapes it tiles), path=scan the lax.scan over chunks (a "
+    "mesh, another backend or other shapes).  Counted at build time, once a "
+    "site a trace.",
     labels=("path",),
 )
 

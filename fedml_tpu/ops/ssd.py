@@ -27,10 +27,19 @@ head underflows to 0 and not to inf.  Products take their operands in the
 inputs' dtype (bfloat16 on the chip) into float32 accumulators; ``dt``, the
 decays and the state (also as an operand of ``C S_in``) are float32.
 
-The backward pass is the scan's own, with each chunk rematerialised
-(``jax.checkpoint`` around the body): what a chunk builds per head and pair
-of tokens (``L``, 16.8 MB in float32 at 64 heads x 256 x 256) is not kept for
-128 chunks, only the carried states are (2 MB each).
+Two paths, one algorithm; ``scan_path`` chooses, from what the program can
+observe.  ``"scan"`` is the ``lax.scan`` below, whose backward pass is the
+scan's own with each chunk rematerialised (``jax.checkpoint`` around the
+body): what a chunk builds per head and pair of tokens (``L``, 16.8 MB in
+float32 at 64 heads x 256 x 256) is not kept for 128 chunks, only the carried
+states are (2 MB each); every piece of a chunk is a fusion with its operands
+in HBM.  ``"kernel"`` is the fused Pallas pair of ``ops/pallas/ssd.py``, for
+one TPU device and shapes it tiles: the tile and the carried state stay in
+VMEM; between forward and backward it keeps the operands and the state
+ENTERING each chunk (what the scan's checkpoint keeps), and the backward
+rebuilds ``C B^T``, the decay tile and ``W`` a chunk at a time, once.  The
+scan is the path of every mesh and of the CPU, and the form the kernel is
+held to (``tests/test_ssd_kernel.py``).
 """
 
 from __future__ import annotations
@@ -38,6 +47,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import LLM_SCAN_SITES
+from .pallas import ssd as ssd_kernel
 from .segments import document_index
 
 #: tokens a step of the scan takes where the caller names none (Mamba-2's own
@@ -45,13 +56,42 @@ from .segments import document_index
 CHUNK = 256
 
 
-def ssd(x, dt, a, b_in, c_in, d_skip, segments=None, chunk: int = 0):
+#: the paths a selective-scan call site is built on
+SCAN_PATHS = ("kernel", "scan")
+
+
+def scan_sites() -> dict:
+    """Call sites counted so far, by path (``fedml_llm_scan_sites_total``): a
+    program's own are what its tracing adds."""
+    return {path: int(LLM_SCAN_SITES.value(path=path)) for path in SCAN_PATHS}
+
+
+def scan_path(x, b_in, chunk: int, mesh) -> str:
+    """``"kernel"`` where the fused Pallas pair can stand for the ``lax.scan``,
+    else ``"scan"``: no mesh in the caller's hands (jax cannot partition a
+    Mosaic call), a TPU backend, and shapes the kernel tiles (whole chunks
+    among them: a ragged tail is the scan's).  Counts the call site under the
+    path it takes: called while a program is traced, so once for each site
+    the program has."""
+    kernel = (mesh is None and jax.default_backend() == "tpu"
+              and ssd_kernel.tiles(x, b_in, min(chunk or CHUNK, x.shape[1])))
+    path = "kernel" if kernel else "scan"
+    LLM_SCAN_SITES.inc(1, path=path)
+    return path
+
+
+def ssd(x, dt, a, b_in, c_in, d_skip, segments=None, chunk: int = 0, mesh=None):
     """x: (b, s, h, p); dt: (b, s, h) float32, positive (after its softplus);
     a: (h,) float32, negative; b_in, c_in: (b, s, g, n) with ``h % g == 0``;
     d_skip: (h,); segments: (b, s) document ids or None (one document a row)
     -> (b, s, h, p) in x's dtype.  ``chunk`` 0 is ``CHUNK``.  ``s`` need not
     be a multiple of it: the tail is padded with ``dt = 0`` (a token that
-    neither decays nor feeds the state) and cut off again."""
+    neither decays nor feeds the state) and cut off again.  ``mesh`` is the
+    mesh the calling module holds, if any: what it computes on may be sharded,
+    which keeps it on the ``lax.scan`` (``scan_path``)."""
+    if scan_path(x, b_in, chunk, mesh) == "kernel":
+        doc = jnp.zeros(x.shape[:2], jnp.int32) if segments is None else document_index(segments)
+        return ssd_kernel.ssd(x, dt, a, b_in, c_in, d_skip, doc, min(chunk or CHUNK, x.shape[1]))
     b, s, h, p = x.shape
     g, n = b_in.shape[2:]
     c = min(chunk or CHUNK, s)

@@ -83,5 +83,5 @@ def test_chip_smoke_dry_run_is_explicit_and_labelled():
     last = json.loads(res.stdout.strip().splitlines()[-1])
     assert last == {"ok": True, "dry_run": True,
                     "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
-    for leg in ("A", "C", "D", "B"):
+    for leg in ("A", "C", "D", "E", "B"):
         assert f"dry_run leg {leg} PASSED" in res.stdout

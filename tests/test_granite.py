@@ -12,6 +12,7 @@ attention | mamba, rows of 64 tokens packed from documents of 23, 17, 13 and
 documents, so that states are carried and boundaries fall inside chunks.
 """
 
+import contextlib
 import dataclasses
 import os
 import sys
@@ -748,13 +749,29 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@contextlib.contextmanager
+def _no_compile_cache():
+    """A compile for an absent chip cannot be read back from the persistent
+    cache: off around it, and as it was after."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+
+
 def test_segmented_flash_kernel_compiles_for_the_chip_at_the_cells_size(one_chip):
     """Mosaic takes the kernel pair with segment ids at 32,768 tokens, 32
     query over 8 KV heads of 64: the column-against-row compare of document
     indices, the two SMEM scalars a tile, a head's dq in VMEM."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.compilation_cache import compilation_cache
     from fedml_tpu.ops.pallas import flash_attention as fa
 
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -762,15 +779,36 @@ def test_segmented_flash_kernel_compiles_for_the_chip_at_the_cells_size(one_chip
     assert fa.tiles(q, kv, kv)
     loss = lambda q, k, v, doc: jnp.sum(fa.causal_attention(
         q, k, v, scale=1 / 64, segments=doc, interpret=False).astype(jnp.float32))
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)   # a compile for an absent chip cannot be read back
-    compilation_cache.reset_cache()
-    try:
+    with _no_compile_cache():
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             q, kv, kv, spec((1, 32768), jnp.int32)).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cached)
-        compilation_cache.reset_cache()
     text = compiled.as_text()
     assert "fedml_causal_attention_fwd" in text and "fedml_causal_attention_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+def test_scan_kernel_pair_compiles_for_the_chip_at_the_cells_size(one_chip):
+    """Mosaic takes the selective scan's forward and backward kernels at 32,768
+    tokens, 64 heads of 64 on one group, a state of 128, chunks of 256, with
+    document indices: a head's columns of ``dt`` and ``l`` by static lane
+    slices, two 64-wide heads a register, the states of all heads in VMEM.
+    No loop over the chunks is left in the program, and beside the operands
+    it holds the saved entry states (268 MB), the cotangents and little else."""
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.ops.pallas import ssd as kernel
+
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    b, s, h, p, g, n = 1, 32768, 64, 64, 1, 128
+    x, bc = spec((b, s, h, p), jnp.bfloat16), spec((b, s, g, n), jnp.bfloat16)
+    assert kernel.tiles(x, bc, 256)
+    loss = lambda x, dt, a, b_in, c_in, d_skip, doc: jnp.sum(kernel.ssd(
+        x, dt, a, b_in, c_in, d_skip, doc, 256, interpret=False).astype(jnp.float32))
+    with _no_compile_cache():
+        compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+            x, spec((b, s, h), jnp.float32), spec((h,), jnp.float32), bc, bc, spec((h,), jnp.float32),
+            spec((b, s), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "fedml_ssd_fwd" in text and "fedml_ssd_bwd" in text
+    assert " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
