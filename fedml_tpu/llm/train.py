@@ -38,6 +38,7 @@ import optax
 
 from ..core import rng
 from ..models.transformer import MOE_STATS, Transformer, TransformerConfig, targets_in_document
+from ..obs import scopes
 from ..obs.metrics import MetricsLogger
 from ..obs.trace import (LLM_ATTENDED_KEYS, LLM_EXPERT_TOKENS, LLM_LOSS_TOKENS, LLM_PACKED_DOCUMENTS,
                          XLA_COUNTERS, install_xla_listener, traced)
@@ -165,6 +166,8 @@ class LLMTrainer:
         self.attention_sites = dict.fromkeys(ATTENTION_PATHS, 0)
         #: ... and its selective-scan call sites, likewise
         self.scan_sites = dict.fromkeys(SCAN_PATHS, 0)
+        #: the step program last published to ``obs/scopes.py`` (``_dispatch``)
+        self._noted_step = None
         # Pin the step's output shardings to the input shardings: with
         # donation and unspecified out_shardings, XLA may pick different
         # layouts for the outputs, and the SECOND call then recompiles
@@ -198,6 +201,9 @@ class LLMTrainer:
         model = self.model
         opt = self.opt
         args = self.args
+        # the step writes its sites into the trainer's two dicts, not into the
+        # trainer: the jitted step outlives it (``obs/scopes.py`` keeps the step)
+        attn_sites, scans = self.attention_sites, self.scan_sites
         sown_names = self._sown_names()
         mtp = self.cfg.mtp_layers > 0
         # the MTP module's loss needs the targets inside the model too
@@ -242,8 +248,8 @@ class LLMTrainer:
             with jax.named_scope("llm.fwd_bwd"):
                 (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                     trained, base, tokens, targets, *segments)
-            self.attention_sites = {p: n - before[p] for p, n in attention_sites().items()}
-            self.scan_sites = {p: n - scans_before[p] for p, n in scan_sites().items()}
+            attn_sites.update({p: n - before[p] for p, n in attention_sites().items()})
+            scans.update({p: n - scans_before[p] for p, n in scan_sites().items()})
             with jax.named_scope("llm.optimizer"):
                 updates, opt_state = opt.update(grads, opt_state, trained)
                 trained = optax.apply_updates(trained, updates)
@@ -258,10 +264,17 @@ class LLMTrainer:
         """One call of the step program on the trainer's own state: of the
         packed one where the batch has ``segments``."""
         step = self._train_step if len(batch) == 2 else self._train_step_packed
+        args = ((self.params, self.opt_state, *batch) if self.lora is None
+                else (self.lora, self.opt_state, self.params, *batch))
+        if step is not self._noted_step:  # once a program: which scope each of its device ops is in
+            self._noted_step = step
+            if hasattr(step, "lower"):
+                scopes.note_program("llm.step", step, args)
+        trained, self.opt_state, metrics = step(*args)
         if self.lora is None:
-            self.params, self.opt_state, metrics = step(self.params, self.opt_state, *batch)
+            self.params = trained
         else:
-            self.lora, self.opt_state, metrics = step(self.lora, self.opt_state, self.params, *batch)
+            self.lora = trained
         return metrics
 
     def step(self, tokens: jax.Array, targets: jax.Array, segments=None) -> dict:

@@ -38,6 +38,7 @@ The defaults build the plain model.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -213,6 +214,20 @@ def _project(mdl: nn.Module, name: str, x, features, axis=-1):
     return y
 
 
+def _scoped(name: str):
+    """A mixer's ``__call__`` inside ``jax.named_scope(name)``: every device
+    op of the module (projections, norms, RoPE, gates) carries
+    ``llm.mixer.<kind>`` in its ``op_name``, and what ``ops/`` does for it
+    ``llm.mixer.<kind>.core`` inside that (``obs/scopes.py`` reads both)."""
+    def wrap(call):
+        @functools.wraps(call)
+        def scoped(self, *args, **kwargs):
+            with jax.named_scope(name):
+                return call(self, *args, **kwargs)
+        return scoped
+    return wrap
+
+
 def _no_seq_axis(mdl) -> None:
     if mdl.mesh is not None and mdl.seq_axis and mdl.mesh.shape[mdl.seq_axis] > 1:
         raise NotImplementedError(f"{type(mdl).__name__} has no sequence-sharded form yet")
@@ -238,6 +253,7 @@ class Attention(nn.Module):
     seq_axis: Optional[str] = None
 
     @nn.compact
+    @_scoped("llm.mixer.attention")
     def __call__(self, x, positions, segments=None):
         cfg = self.cfg
         hd = cfg.head_dim or cfg.d_model // cfg.n_heads
@@ -252,7 +268,7 @@ class Attention(nn.Module):
             from ..ops.sparse_attention import CHUNK, block_sparse_attention
 
             _no_seq_axis(self)
-            with jax.named_scope("llm.mixer.attention"):
+            with jax.named_scope("llm.mixer.attention.core"):
                 out = block_sparse_attention(q, k, v, None, q_chunk=CHUNK, k_chunk=CHUNK,
                                              scale=scale, mesh=self.mesh, segments=segments)
             return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
@@ -266,14 +282,16 @@ class Attention(nn.Module):
 
             if scale is not None:
                 raise NotImplementedError("ring attention scales its scores by 1 / sqrt(head_dim)")
-            out = ring_attention(
-                q, k, v, self.mesh, axis=self.seq_axis, causal=True,
-                dp_axis=AXIS_DATA, tp_axis=AXIS_MODEL,
-            )
+            with jax.named_scope("llm.mixer.attention.core"):
+                out = ring_attention(
+                    q, k, v, self.mesh, axis=self.seq_axis, causal=True,
+                    dp_axis=AXIS_DATA, tp_axis=AXIS_MODEL,
+                )
         else:
             from ..ops.ring_attention import dense_attention
 
-            out = dense_attention(q, k, v, causal=True, scale=scale)
+            with jax.named_scope("llm.mixer.attention.core"):
+                out = dense_attention(q, k, v, causal=True, scale=scale)
         return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
 
 
@@ -298,6 +316,7 @@ class LightningAttention(nn.Module):
     seq_axis: Optional[str] = None
 
     @nn.compact
+    @_scoped("llm.mixer.lightning")
     def __call__(self, x, positions, segments=None):
         from ..ops.lightning_attention import decay_slopes, lightning_attention
 
@@ -309,7 +328,7 @@ class LightningAttention(nn.Module):
         q, k, v = (_project(self, n, x, (nh, hd)) for n in ("wq", "wk", "wv"))
         q, k = _qk_normed(cfg, q, k)
         q, k = rope(q, positions, cfg.rope_theta), rope(k, positions, cfg.rope_theta)
-        with jax.named_scope("llm.mixer.lightning"):
+        with jax.named_scope("llm.mixer.lightning.core"):
             out = lightning_attention(q, k, v, decay_slopes(nh))
         flat = out.reshape(out.shape[:-2] + (nh * hd,))
         out = RMSNorm(cfg.norm_eps, name="o_norm")(flat).reshape(out.shape).astype(out.dtype)
@@ -330,6 +349,7 @@ class SparseAttention(nn.Module):
     seq_axis: Optional[str] = None
 
     @nn.compact
+    @_scoped("llm.mixer.sparse")
     def __call__(self, x, positions, segments=None):
         from ..ops.sparse_attention import sparse_attention
 
@@ -341,7 +361,7 @@ class SparseAttention(nn.Module):
         k = _project(self, "wk", x, (cfg.n_kv_heads, hd))
         v = _project(self, "wv", x, (cfg.n_kv_heads, hd))
         q, k = _qk_normed(cfg, q, k)
-        with jax.named_scope("llm.mixer.sparse"):
+        with jax.named_scope("llm.mixer.sparse.core"):
             out, kept, causal = sparse_attention(q, k, v, mesh=self.mesh, **cfg.sparse_selection)
         add = lambda a, b: a + b
         self.sow("stats", "sparse_kept", kept, init_fn=lambda: jnp.float32(0), reduce_fn=add)
@@ -374,6 +394,7 @@ class MLAttention(nn.Module):
     seq_axis: Optional[str] = None
 
     @nn.compact
+    @_scoped("llm.mixer.mla")
     def __call__(self, x, positions, segments=None):
         from ..ops.sparse_attention import CHUNK, block_sparse_attention
 
@@ -389,7 +410,7 @@ class MLAttention(nn.Module):
         k_r = rope(kv_a[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)   # (b, s, 1, rot)
         q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
         k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_r, k_r.shape[:2] + (h, rot))], -1)
-        with jax.named_scope("llm.mixer.mla"):
+        with jax.named_scope("llm.mixer.mla.core"):
             out = block_sparse_attention(q, k, kv[..., nope:], None, q_chunk=CHUNK, k_chunk=CHUNK,
                                          scale=(nope + rot) ** -0.5, mesh=self.mesh,
                                          head_group=MLA_HEAD_GROUP)

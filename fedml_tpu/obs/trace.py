@@ -253,9 +253,13 @@ def extract(msg) -> Optional[dict]:
 #: duration is recorded around ``compile_or_get_cached``, so it fires for a
 #: backend compile AND for a load from the persistent cache; the second only
 #: for a load, and before the first.  A jit call that finds its program in
-#: memory fires neither.
+#: memory fires neither.  Before either, a program that jit has not seen is
+#: traced to a jaxpr and lowered to an MLIR module (``pjit.py``, ``pxla.py``):
+#: Python time that no compile cache returns.
 _BUILD_KEY = "/jax/core/compile/backend_compile_duration"
 _CACHE_LOAD_KEY = "/jax/compilation_cache/cache_retrieval_time_sec"
+_TRACE_KEY = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_KEY = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 XLA_COMPILES = REGISTRY.counter(
     "fedml_xla_compiles_total",
@@ -270,9 +274,17 @@ XLA_CACHE_LOADS = REGISTRY.counter(
 XLA_CACHE_LOAD_SECONDS = REGISTRY.counter(
     "fedml_xla_cache_load_seconds_total",
     "Wall seconds retrieving and loading programs from the persistent cache.")
+XLA_TRACE_SECONDS = REGISTRY.counter(
+    "fedml_xla_trace_seconds_total",
+    "Wall seconds jax spent tracing Python functions to jaxprs on their way "
+    "to a program (a nested jit's trace is inside its caller's and counts once).")
+XLA_LOWER_SECONDS = REGISTRY.counter(
+    "fedml_xla_lower_seconds_total",
+    "Wall seconds jax spent lowering jaxprs to MLIR modules for the compiler.")
 #: what a top-level span of a timed path notes (``traced(counters=...)``)
 XLA_COUNTERS = (XLA_COMPILES.name, XLA_COMPILE_SECONDS.name,
-                XLA_CACHE_LOADS.name, XLA_CACHE_LOAD_SECONDS.name)
+                XLA_CACHE_LOADS.name, XLA_CACHE_LOAD_SECONDS.name,
+                XLA_TRACE_SECONDS.name, XLA_LOWER_SECONDS.name)
 
 #: fed by ``LLMTrainer.fit`` from what the step program summed on the device
 LLM_ATTENDED_KEYS = REGISTRY.counter(
@@ -333,6 +345,22 @@ _listener_lock = threading.Lock()
 _listener_installed = False
 _build_subscribers: list[Callable[[float], None]] = []
 _loading = threading.local()  # set between a cache load's two events
+_traces = threading.local()   # .done: (start, seconds) of this thread's counted traces
+
+
+def _own_trace_seconds(duration_s: float) -> float:
+    """A finished trace's seconds less those of the traces nested in it: a
+    jitted function called while another is traced reports first, and its
+    seconds lie inside its caller's (the LLM step fires 700 such events)."""
+    done = getattr(_traces, "done", None)
+    if done is None:
+        done = _traces.done = collections.deque(maxlen=1024)
+    start = time.monotonic() - duration_s
+    inner = 0.0
+    while done and done[-1][0] >= start:
+        inner += done.pop()[1]
+    done.append((start, duration_s))
+    return max(duration_s - inner, 0.0)
 
 
 def _on_duration(key: str, duration_s: float, **_kw) -> None:
@@ -348,6 +376,10 @@ def _on_duration(key: str, duration_s: float, **_kw) -> None:
             XLA_COMPILE_SECONDS.inc(max(duration_s, 0.0))
         for fn in _build_subscribers:
             fn(duration_s)
+    elif key == _TRACE_KEY:
+        XLA_TRACE_SECONDS.inc(_own_trace_seconds(max(duration_s, 0.0)))
+    elif key == _LOWER_KEY:
+        XLA_LOWER_SECONDS.inc(max(duration_s, 0.0))
 
 
 def install_xla_listener(on_build: Optional[Callable[[float], None]] = None) -> None:

@@ -54,7 +54,7 @@ from ..core.flags import cfg_extra
 from ..data.dataset import FederatedDataset, StackedClientData, pad_eval_set, stack_clients
 from ..fl.local_sgd import eval_batch_size, make_eval_fn, own_step_budget
 from ..parallel import mesh as meshlib
-from ..obs import otlp as obsotlp, registry as obsreg
+from ..obs import otlp as obsotlp, registry as obsreg, scopes
 from ..obs.metrics import MetricsLogger
 from ..obs.trace import XLA_COUNTERS, traced
 from ..ops import flops as flopslib
@@ -245,6 +245,7 @@ class MeshSimulator(RoundCheckpointMixin):
                 )
             else:
                 self._eval_fn = jax.jit(eval_fn)
+                scopes.note_program("sim.eval", self._eval_fn, (self.global_vars, *self._test))
 
         # OTLP egress (gated on extra.otlp_endpoint; None -> spans keep
         # their no-sink default and no exporter thread exists): the
@@ -798,6 +799,9 @@ class MeshSimulator(RoundCheckpointMixin):
                     fn = prog.bind(example_args, donate_argnums=donate)
                 else:
                     fn = jitted.lower(*example_args).compile()
+                    # which scope each of its device ops is in (obs/scopes.py); a
+                    # program bound from the AOT store publishes none
+                    scopes.note_program("sim.chunk", fn)
             CHUNK_COMPILE_TIME.observe(time.perf_counter() - t0)
             if self._cost_gauges:
                 cost = aotlib.record_program_cost(fn, f"sim.multi_round.{n}")

@@ -112,6 +112,31 @@ def test_cache_load_is_not_counted_as_a_compile():
     assert compiles.value() == before[0] + 1
 
 
+def test_first_call_counts_tracing_and_lowering_and_a_second_call_neither():
+    obstrace.install_xla_listener()
+    trace_s = REGISTRY.get("fedml_xla_trace_seconds_total")
+    lower_s = REGISTRY.get("fedml_xla_lower_seconds_total")
+    assert {trace_s.name, lower_s.name} <= set(obstrace.XLA_COUNTERS)
+    inner = jax.jit(lambda v: jnp.tanh(v) * 2.0)
+    fresh = jax.jit(lambda v: inner(v) - v)  # a jit traced inside another's trace
+    x = jnp.arange(5.0)
+    with traced("t.trace", counters=obstrace.XLA_COUNTERS) as span:
+        fresh(x).block_until_ready()
+    grew = {k: v[1] for k, v in span.attrs["counters"].items()}
+    assert 0 < grew[trace_s.name] <= span.duration_s and 0 < grew[lower_s.name] <= span.duration_s
+    before = trace_s.value(), lower_s.value()
+    fresh(x).block_until_ready()
+    assert (trace_s.value(), lower_s.value()) == before
+    # what jax 0.9.0 records: a nested trace reports first and lies inside its caller's
+    time.sleep(0.02)  # the real traces above ended before the planted ones start
+    obstrace._on_duration("/jax/core/compile/jaxpr_trace_duration", 0.002)
+    obstrace._on_duration("/jax/core/compile/jaxpr_trace_duration", 0.008)
+    assert trace_s.value() == pytest.approx(before[0] + 0.008)
+    obstrace._on_duration("/jax/core/compile/jaxpr_trace_duration", 1e-6)  # after it: its own
+    obstrace._on_duration("/jax/core/compile/jaxpr_to_mlir_module_duration", 0.5)
+    assert (trace_s.value(), lower_s.value()) == pytest.approx((before[0] + 0.008 + 1e-6, before[1] + 0.5))
+
+
 # ------------------------------------------------------------- LLMTrainer.fit
 @pytest.fixture(scope="module")
 def tiny_trainer():
