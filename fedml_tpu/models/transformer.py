@@ -228,8 +228,12 @@ def _scoped(name: str):
     return wrap
 
 
+def _on_seq_axis(mdl) -> bool:
+    return mdl.mesh is not None and bool(mdl.seq_axis) and mdl.mesh.shape[mdl.seq_axis] > 1
+
+
 def _no_seq_axis(mdl) -> None:
-    if mdl.mesh is not None and mdl.seq_axis and mdl.mesh.shape[mdl.seq_axis] > 1:
+    if _on_seq_axis(mdl):
         raise NotImplementedError(f"{type(mdl).__name__} has no sequence-sharded form yet")
 
 
@@ -241,12 +245,15 @@ def _no_segments(mdl, segments) -> None:
 class Attention(nn.Module):
     """Grouped-query softmax attention: RoPE unless ``cfg.attn_rope`` is off,
     scores times ``cfg.attn_scale`` (0: ``1 / sqrt(head_dim)``), causal, and
-    with ``segments`` within a document.  Unpacked rows build their scores
-    whole, or go round the ring on a ``seq`` mesh axis, as they always did;
-    packed rows go through ``ops/sparse_attention.block_sparse_attention``
-    (every block kept: no ``s x s`` tensor, at 32,768 tokens x 32 heads it
-    would be 137 GB), which takes the fused flash kernel where it can and has
-    no sequence-sharded form."""
+    with ``segments`` within a document.  Rows on a ``seq`` mesh axis go round
+    the ring (unpacked rows only: packed ones have no sequence-sharded form);
+    every other row, packed or not, goes through
+    ``ops/sparse_attention.block_sparse_attention`` with every block kept and
+    K, V at their own ``n_kv_heads``: the fused flash kernel where
+    ``attention_path`` finds that it can (one TPU device, a length that
+    tiles), else the ``lax`` blockwise pass in chunks of ``row_chunk``.  No
+    (b, h, s, s) tensor either way (at 2,048 tokens x 32 heads x 4 rows it was
+    2.1 GB in float32, twice a layer; at 32,768 x 32 it would be 137 GB)."""
 
     cfg: TransformerConfig
     mesh: Optional[Any] = None
@@ -264,34 +271,29 @@ class Attention(nn.Module):
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
         scale = cfg.attn_scale or None
-        if segments is not None:
-            from ..ops.sparse_attention import CHUNK, block_sparse_attention
-
-            _no_seq_axis(self)
-            with jax.named_scope("llm.mixer.attention.core"):
-                out = block_sparse_attention(q, k, v, None, q_chunk=CHUNK, k_chunk=CHUNK,
-                                             scale=scale, mesh=self.mesh, segments=segments)
-            return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
-        if cfg.n_kv_heads != cfg.n_heads:  # GQA: repeat kv heads
-            rep = cfg.n_heads // cfg.n_kv_heads
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        if self.mesh is not None and self.seq_axis and self.mesh.shape[self.seq_axis] > 1:
+        if _on_seq_axis(self) and segments is None:
             from ..ops.ring_attention import ring_attention
             from ..parallel.mesh import AXIS_DATA, AXIS_MODEL
 
             if scale is not None:
                 raise NotImplementedError("ring attention scales its scores by 1 / sqrt(head_dim)")
+            if cfg.n_kv_heads != cfg.n_heads:  # GQA: the ring takes a key per query head
+                rep = cfg.n_heads // cfg.n_kv_heads
+                k = jnp.repeat(k, rep, axis=2)
+                v = jnp.repeat(v, rep, axis=2)
             with jax.named_scope("llm.mixer.attention.core"):
                 out = ring_attention(
                     q, k, v, self.mesh, axis=self.seq_axis, causal=True,
                     dp_axis=AXIS_DATA, tp_axis=AXIS_MODEL,
                 )
         else:
-            from ..ops.ring_attention import dense_attention
+            from ..ops.sparse_attention import block_sparse_attention, row_chunk
 
+            _no_seq_axis(self)     # packed rows have no ring
+            chunk = row_chunk(x.shape[1])
             with jax.named_scope("llm.mixer.attention.core"):
-                out = dense_attention(q, k, v, causal=True, scale=scale)
+                out = block_sparse_attention(q, k, v, None, q_chunk=chunk, k_chunk=chunk,
+                                             scale=scale, mesh=self.mesh, segments=segments)
         return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
 
 

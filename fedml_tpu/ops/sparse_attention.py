@@ -34,6 +34,10 @@ kernel where ``attention_path`` finds that it can:
     With ``segments`` (packed documents) a query attends its own document's
     tokens alone: a token-level mask beside the blocks', on either path.
 
+``row_chunk``  the chunk that pass takes a plain mixer's rows in, decided
+    from their length alone (a length with no useful divisor never runs
+    token by token: whole up to a stated size, refused beyond).
+
 ``attention_path``  the ONE place that chooses between that pass and
     ``ops/pallas/flash_attention.causal_attention``, the same algorithm with
     each pair's scores held in VMEM from the first product to the last: from
@@ -72,6 +76,20 @@ def _chunk(n: int, want: int, multiple: int = 1) -> int:
         if n % c == 0 and c % multiple == 0:
             return c
     raise ValueError(f"no chunk of {n} is a multiple of {multiple}")
+
+
+def row_chunk(s: int) -> int:
+    """The chunk a plain mixer takes rows of ``s`` tokens in: the largest
+    divisor of ``s`` up to ``CHUNK``.  Where that is under ``CHUNK // 8`` (a
+    prime 1,021 would go token by token) the row whole, up to ``4 * CHUNK``
+    tokens; a longer row of such a length is refused."""
+    c, least = _chunk(s, CHUNK), CHUNK // 8
+    if c >= least:
+        return c
+    if s <= 4 * CHUNK:
+        return s
+    raise ValueError(f"rows of {s} tokens have no chunk between {least} and {CHUNK} tokens (the largest "
+                     f"divisor of {s} up to {CHUNK} is {c}): pad them to a multiple of {least}")
 
 
 def compress_keys(k, kernel_size: int, kernel_stride: int):

@@ -2,7 +2,8 @@
 the Pallas interpreter: against the blockwise ``lax`` pass it stands for and
 against full rows of scores, forward and the gradients to q, k and v; and the
 one place that chooses between the two (``ops/sparse_attention.attention_path``):
-what it takes, what it counts, what ``llm.fit`` says.
+what it takes, what it counts, what ``llm.fit`` says; and the plain
+``Attention`` module, whose unpacked rows take that entry too (PR 38).
 
 Small shapes: at most 4 heads and 1,024 tokens (the tile cut to 512 or 128 a side
 where a case wants several).
@@ -123,27 +124,32 @@ def test_the_choice_of_path(case, path, request, eight_devices):
     assert _gap(got, want) < 2e-6
 
 
-def _tiny_mla():
+def _tiny(mixer: str):
+    """Two blocks of latent attention ("mla") or of the plain grouped-query
+    mixer ("plain") -> (cfg, the adapters' targets)."""
     import jax.numpy as jnp
+    from fedml_tpu.llm import lora
     from fedml_tpu.models.transformer import TransformerConfig
 
+    common = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=2, d_ff=64, max_seq_len=128,
+                  dtype=jnp.float32, logits_dtype=jnp.float32, remat=True, remat_policy="full")
+    if mixer == "plain":
+        return TransformerConfig(n_kv_heads=1, **common), lora.DEFAULT_TARGETS
     return TransformerConfig(
-        vocab_size=64, d_model=32, n_layers=2, n_heads=2, n_kv_heads=2, d_ff=64, max_seq_len=128,
-        dtype=jnp.float32, logits_dtype=jnp.float32, remat=True, remat_policy="full",
-        mixer_types=("mla", "mla"), q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=16,
-        qk_rope_head_dim=8, v_head_dim=16)
+        n_kv_heads=2, mixer_types=("mla", "mla"), q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, **common), lora.MLA_TARGETS
 
 
-def _fit(devices, steps=2):
-    """``LLMTrainer.fit`` on the tiny latent-attention model over a ``data``
-    mesh of these devices -> (history, the ``llm.fit`` span)."""
-    from fedml_tpu.llm import lora
+def _fit(mixer, devices, steps=2):
+    """``LLMTrainer.fit`` on the tiny model over a ``data`` mesh of these
+    devices -> (history, the ``llm.fit`` span)."""
     from fedml_tpu.llm.train import LLMTrainArgs, LLMTrainer
     from fedml_tpu.obs import trace as obstrace
     from fedml_tpu.parallel import mesh as meshlib
 
-    tr = LLMTrainer(_tiny_mla(), LLMTrainArgs(batch_size=2, seq_len=128, total_steps=steps, lora_rank=2,
-                                              lora_targets=lora.MLA_TARGETS),
+    cfg, targets = _tiny(mixer)
+    tr = LLMTrainer(cfg, LLMTrainArgs(batch_size=2, seq_len=128, total_steps=steps, lora_rank=2,
+                                      lora_targets=targets),
                     mesh=meshlib.make_mesh((meshlib.AXIS_DATA,), devices=devices))
     rng = np.random.default_rng(0)
     rows = [rng.integers(0, 64, (2, 129)) for _ in range(steps)]
@@ -151,17 +157,116 @@ def _fit(devices, steps=2):
     return history, [s for s in obstrace.recent() if s.name == "llm.fit"][-1]
 
 
+@pytest.mark.parametrize("mixer", ["mla", "plain"])
 @pytest.mark.parametrize("where,kernel,blockwise", [("cpu", 0, 2), ("tpu", 2, 0), ("tpu_mesh", 0, 2)])
-def test_fit_says_which_path_its_attention_sites_took(where, kernel, blockwise, request, eight_devices):
+def test_fit_says_which_path_its_attention_sites_took(where, kernel, blockwise, mixer, request, eight_devices):
     """``attn_kernel_sites`` / ``attn_blockwise_sites`` on ``llm.fit``: the
     step program's own call sites (one a block, however often ``fit`` runs
-    and whatever else was traced), and the same losses either way."""
-    want, span = _fit(eight_devices[:1])
+    and whatever else was traced), and the same losses either way; latent
+    attention's, and the plain mixer's on unpacked rows (PR 38)."""
+    want, span = _fit(mixer, eight_devices[:1])
     assert (span.attrs["attn_kernel_sites"], span.attrs["attn_blockwise_sites"]) == (0, 2)
     if where == "cpu":
         return
     request.getfixturevalue("on_a_tpu")
-    got, span = _fit(eight_devices[:2 if where == "tpu_mesh" else 1])
+    got, span = _fit(mixer, eight_devices[:2 if where == "tpu_mesh" else 1])
     assert (span.attrs["attn_kernel_sites"], span.attrs["attn_blockwise_sites"]) == (kernel, blockwise)
     for g, w in zip(got, want):
         assert abs(g["loss"] - w["loss"]) < 1e-4 * abs(w["loss"])
+
+
+# -- the plain mixer on unpacked rows (PR 38) -------------------------------------------
+def _plain_mixer(s, heads=4, kv=2, width=16):
+    """The plain ``Attention`` at float32 with its input and positions:
+    ``heads`` query over ``kv`` KV heads of ``width``, rows of ``s`` tokens."""
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.models.transformer import Attention, TransformerConfig
+
+    cfg = TransformerConfig(vocab_size=64, d_model=heads * width, n_layers=1, n_heads=heads, n_kv_heads=kv,
+                            d_ff=64, max_seq_len=s, dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(s), (2, s, heads * width), jnp.float32)
+    return Attention(cfg), x, jnp.broadcast_to(jnp.arange(s), (2, s))
+
+
+def _scores_whole(q, k, v, keep=None, *, scale=None, **_):
+    """What the module did before PR 38, in the blockwise entry's place: the
+    KV heads repeated, then ``dense_attention``."""
+    import jax.numpy as jnp
+    from fedml_tpu.ops.ring_attention import dense_attention
+
+    rep = q.shape[2] // k.shape[2]
+    return dense_attention(q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2), causal=True, scale=scale)
+
+
+def _output_and_gradients(mixer, params, x, pos):
+    import jax
+    import jax.numpy as jnp
+
+    w = jax.random.normal(jax.random.PRNGKey(1), x.shape, jnp.float32)
+    loss = lambda params, x: jnp.sum(mixer.apply(params, x, pos) * w)
+    return mixer.apply(params, x, pos), jax.jit(jax.grad(loss, (0, 1)))(params, x)
+
+
+def test_plain_mixer_on_unpacked_rows_takes_the_kernel(on_a_tpu, monkeypatch):
+    """4 query over 2 KV heads, 2 x 2 tiles of 128: the module's one call takes
+    the kernel with the KV heads as they are and gives what full rows of scores
+    over repeated KV heads give: output, and the gradients in the input and in
+    ``wq``, ``wk``, ``wv``, ``wo``."""
+    import jax
+    from fedml_tpu.ops import sparse_attention as spa
+    from fedml_tpu.ops.pallas import flash_attention
+
+    monkeypatch.setattr(flash_attention, "BLOCK", 128)
+    mixer, x, pos = _plain_mixer(256)
+    params = jax.jit(mixer.init)(jax.random.PRNGKey(0), x, pos)
+    before = spa.attention_sites()
+    got = _output_and_gradients(mixer, params, x, pos)
+    after = spa.attention_sites()
+    assert after["kernel"] - before["kernel"] == 2 and after["blockwise"] == before["blockwise"]
+    monkeypatch.setattr(spa, "block_sparse_attention", _scores_whole)
+    want = _output_and_gradients(mixer, params, x, pos)
+    assert spa.attention_sites() == after       # the reference is not the entry
+    assert _gap(got[0], want[0]) < 2e-6 and _gap(got[1][1], want[1][1]) < 2e-6
+    kernels = lambda grads: {name: g["kernel"] for name, g in grads[1][0]["params"].items()}
+    assert sorted(kernels(got)) == ["wk", "wo", "wq", "wv"]
+    for name, g in kernels(got).items():
+        assert _gap(g, kernels(want)[name]) < 2e-6, name
+
+
+@pytest.mark.parametrize("s,chunk", [(16, 16), (512, 512), (2048, 512), (32768, 512), (1000, 500), (2176, 272),
+                                     (1021, 1021), (2038, 2038), (4099, None), (2053, None)])
+def test_the_chunk_of_a_row_is_decided_from_its_length(s, chunk):
+    """The largest divisor up to ``CHUNK``; a length with none of 64 tokens
+    (a prime 1,021, or twice 1,019) is one whole chunk up to 2,048 tokens and
+    refused by name beyond: never a token-by-token pass."""
+    from fedml_tpu.ops.sparse_attention import row_chunk
+
+    if chunk is not None:
+        assert row_chunk(s) == chunk
+        return
+    with pytest.raises(ValueError, match=f"rows of {s} tokens"):
+        row_chunk(s)
+
+
+def test_plain_mixer_on_a_row_of_awkward_length(monkeypatch):
+    """1,021 tokens (a prime): ``dense_attention`` took any length, and the
+    module still does up to 2,048 tokens, in one chunk and so one pair, with
+    the same output; 4,099 tokens it refuses, naming the length."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.ops import sparse_attention as spa
+
+    mixer, x, pos = _plain_mixer(1021, heads=2, kv=1, width=8)
+    params = jax.jit(mixer.init)(jax.random.PRNGKey(0), x, pos)
+    text = str(jax.make_jaxpr(mixer.apply)(params, x, pos))
+    assert re.findall(r"length=(\d+)", text) == ["1", "1"] and "1021,1021]" in text    # one pair of chunks
+    got = jax.jit(mixer.apply)(params, x, pos)
+    with monkeypatch.context() as patched:
+        patched.setattr(spa, "block_sparse_attention", _scores_whole)
+        assert _gap(got, mixer.apply(params, x, pos)) < 2e-6
+    long = (jax.ShapeDtypeStruct((1, 4099, 16), jnp.float32), jax.ShapeDtypeStruct((1, 4099), jnp.int32))
+    with pytest.raises(ValueError, match="rows of 4099 tokens"):
+        jax.eval_shape(mixer.apply, params, *long)

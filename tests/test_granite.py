@@ -632,20 +632,30 @@ def test_mamba_refuses_a_seq_axis(bench, eight_devices):
 
 @pytest.mark.parametrize("rows", ["long_on_a_seq_axis", "on_a_data_axis", "packed_on_a_seq_axis"])
 def test_unpacked_rows_take_the_path_they_took(rows, eight_devices, monkeypatch):
-    """The plain mixer chooses by ``segments`` alone: unpacked rows go round
-    the ring on a ``seq`` axis however large their scores (32 heads x 8,192
-    tokens are 8.6 GB in float32: what the ring is for) and build them whole
-    without one; only packed rows go blockwise, and those refuse a ``seq``
-    axis.  Shapes only (``eval_shape``): nothing of that size is computed."""
+    """The plain mixer chooses by the ``seq`` axis alone: unpacked rows on one
+    go round the ring however large their scores (32 heads x 8,192 tokens are
+    8.6 GB in float32: what the ring is for), with a key per query head;
+    without one they go through the blockwise entry as packed rows do (PR 38:
+    they built their scores whole), with the module's mesh passed on, every
+    block kept and K, V at their own ``n_kv_heads``; packed rows refuse a
+    ``seq`` axis.  Shapes only (``eval_shape``): nothing of that size is
+    computed."""
     import jax
     import jax.numpy as jnp
     from fedml_tpu.models import transformer as tfm
-    from fedml_tpu.ops import ring_attention as ring
+    from fedml_tpu.ops import ring_attention as ring, sparse_attention as spa
     from fedml_tpu.parallel import mesh as meshlib
 
     taken = []
-    for name in ("ring_attention", "dense_attention"):
-        monkeypatch.setattr(ring, name, lambda q, *a, _name=name, **kw: taken.append(_name) or q)
+
+    def took(name):
+        def attend(q, k, v, *args, **kw):
+            taken.append((name, k.shape[2], v.shape[2], args, kw))
+            return q
+        return attend
+
+    monkeypatch.setattr(ring, "ring_attention", took("ring_attention"))
+    monkeypatch.setattr(spa, "block_sparse_attention", took("block_sparse_attention"))
     seq = rows != "on_a_data_axis"
     mesh = jax.sharding.Mesh(np.asarray(eight_devices), (meshlib.AXIS_SEQ if seq else meshlib.AXIS_DATA,))
     b, s = (1, 8192) if seq else (16, 2048)
@@ -660,7 +670,12 @@ def test_unpacked_rows_take_the_path_they_took(rows, eight_devices, monkeypatch)
         assert not taken
         return
     jax.eval_shape(lambda x, p: mixer.init(jax.random.PRNGKey(0), x, p), x, pos)
-    assert taken == ["ring_attention" if seq else "dense_attention"]
+    (name, k_heads, v_heads, args, kw), = taken
+    if seq:
+        assert (name, k_heads, v_heads) == ("ring_attention", 32, 32)
+    else:
+        assert (name, k_heads, v_heads, args) == ("block_sparse_attention", 8, 8, (None,))
+        assert kw["mesh"] is mesh and kw["segments"] is None and kw["q_chunk"] == kw["k_chunk"] == spa.CHUNK
 
 
 def test_fedllm_round_on_the_model(bench, eight_devices):
@@ -766,22 +781,26 @@ def _no_compile_cache():
         compilation_cache.reset_cache()
 
 
-def test_segmented_flash_kernel_compiles_for_the_chip_at_the_cells_size(one_chip):
-    """Mosaic takes the kernel pair with segment ids at 32,768 tokens, 32
-    query over 8 KV heads of 64: the column-against-row compare of document
-    indices, the two SMEM scalars a tile, a head's dq in VMEM."""
+@pytest.mark.parametrize("b,s,width,packed", [(1, 32768, 64, True), (4, 2048, 128, False)],
+                         ids=["granite_packed_32k", "mistral_unpacked_2k"])
+def test_flash_kernel_pair_compiles_for_the_chip_at_a_cells_size(b, s, width, packed, one_chip):
+    """Mosaic takes the kernel pair at 32 query over 8 KV heads: with segment
+    ids at 32,768 tokens x 64 (this cell's packed rows: the column-against-row
+    compare of document indices, the two SMEM scalars a tile, a head's dq in
+    VMEM), and without at 4 x 2,048 x 128, the unpacked rows of
+    ``mistral7b_d2.sft_2k`` (PR 38)."""
     import jax
     import jax.numpy as jnp
     from fedml_tpu.ops.pallas import flash_attention as fa
 
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    q, kv = spec((1, 32768, 32, 64), jnp.bfloat16), spec((1, 32768, 8, 64), jnp.bfloat16)
-    assert fa.tiles(q, kv, kv)
-    loss = lambda q, k, v, doc: jnp.sum(fa.causal_attention(
+    q, kv = spec((b, s, 32, width), jnp.bfloat16), spec((b, s, 8, width), jnp.bfloat16)
+    assert fa.tiles(q, kv, kv) and fa.block_of(s) == 1024
+    loss = lambda q, k, v, doc=None: jnp.sum(fa.causal_attention(
         q, k, v, scale=1 / 64, segments=doc, interpret=False).astype(jnp.float32))
     with _no_compile_cache():
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-            q, kv, kv, spec((1, 32768), jnp.int32)).compile()
+            q, kv, kv, *([spec((b, s), jnp.int32)] if packed else [])).compile()
     text = compiled.as_text()
     assert "fedml_causal_attention_fwd" in text and "fedml_causal_attention_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
