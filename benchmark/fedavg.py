@@ -58,7 +58,7 @@ class Driver:
             client_optimizer="sgd", learning_rate=t["learning_rate"],
             partition_method=t["partition_method"], partition_alpha=t.get("partition_alpha", 0.5),
             frequency_of_the_test=self.chunk, compute_dtype="bfloat16", step_mode="match",
-            metrics_jsonl_path="", random_seed=self.seed % (2 ** 31),
+            metrics_jsonl_path="", random_seed=ref_fedavg.round_seed(t, self.seed),
             mesh_shape=f"clients:{len(self.devices)}")
         fedml_tpu.init(cfg)
         # init() puts the cache where core/cache.py says and resets its
@@ -157,7 +157,7 @@ class Driver:
         from the sampling rule written out in the reference."""
         t = self.t
         n, m = t["clients_total"], min(t["clients_per_round"], t["clients_total"])
-        root = jax.random.PRNGKey(self.seed % (2 ** 31))
+        root = jax.random.PRNGKey(ref_fedavg.round_seed(t, self.seed))
         if n <= m:
             per_round = np.tile(np.arange(n), (n_rounds, 1))
         else:

@@ -40,11 +40,12 @@ def _matmul(m: int, k: int, n: int, flops_scale: float = 1.0) -> tuple[float, fl
     return 2.0 * m * k * n * flops_scale, float(BF16 * (m * k + k * n + m * n))
 
 
-def transformer_step_matmuls(c: dict, batch: int, seq_len: int) -> list[tuple[float, float]]:
+def transformer_step_matmuls(c: dict, batch: int, seq_len: int,
+                             attention: bool = True) -> list[tuple[float, float]]:
     """(flops, least bytes) of every matrix product one training step
     requires: each projection forward, its input gradient and its weight
-    gradient; the causal score and value products per layer, forward and the
-    two gradients each.  The embedding is a gather and has none."""
+    gradient; with ``attention``, each layer's ``attention_products``.  The
+    embedding is a gather and has none."""
     d, f, v = c["hidden_size"], c["intermediate_size"], c["vocab_size"]
     h, kv, hd = c["num_attention_heads"], c["num_key_value_heads"], c["head_dim"]
     t = batch * seq_len
@@ -61,13 +62,30 @@ def transformer_step_matmuls(c: dict, batch: int, seq_len: int) -> list[tuple[fl
         dense(d, f)
         dense(d, f)
         dense(f, d)
-        for _ in range(batch * h):
-            # scores q k^T and values p v: forward + two gradients each,
-            # half the square under the causal mask
-            out.extend([_matmul(seq_len, hd, seq_len, 0.5)] * 3)
-            out.extend([_matmul(seq_len, seq_len, hd, 0.5)] * 3)
+        if attention:
+            out.extend(attention_products(c, batch, seq_len))
     dense(d, v)
     return out
+
+
+def attention_products(c: dict, batch: int, seq_len: int) -> list[tuple[float, float]]:
+    """One layer's causal attention as separate products, head by head, as
+    XLA runs it: scores q k^T and values p v, forward + two gradients each,
+    half the square under the causal mask, the scores written between them."""
+    hd = c["head_dim"]
+    return ([_matmul(seq_len, hd, seq_len, 0.5)] * 3
+            + [_matmul(seq_len, seq_len, hd, 0.5)] * 3) * (batch * c["num_attention_heads"])
+
+
+def attention_work(c: dict, batch: int, seq_len: int) -> tuple[float, float]:
+    """(FLOPs, least bytes) of one layer's causal attention as ONE fused
+    kernel computes it (``fedml_causal_attention_*``): the same FLOPs as
+    ``attention_products``, but no score ever leaves the chip: q, k, v and the
+    output's gradient read, the output and three gradients written, each
+    once, k and v at their own KV heads."""
+    h, kv, hd = c["num_attention_heads"], c["num_key_value_heads"], c["head_dim"]
+    pairs = batch * h * seq_len * (seq_len + 1) / 2
+    return 3.0 * 2.0 * (hd + hd) * pairs, float(BF16 * batch * seq_len * hd * (4 * h + 4 * kv))
 
 
 def roofline_seconds(work: list[tuple[float, float]], peak_flops: float,

@@ -122,19 +122,13 @@ def conv_work(c: dict, batch: int, seq_len: int) -> tuple[float, float]:
     return 3.0 * 2.0 * m["taps"] * m["conv"] * t, float(5 * t * m["conv"] * BF16)
 
 
-def part_work(c: dict, part: str, batch: int, lengths) -> list[tuple[float, float]]:
-    """The required work of a part traced alone (``granite_readers``): the
-    scan, the convolution, or the attention MODULE whole (its four
-    projections forward and the gradient to their inputs, and
-    ``attention_work``)."""
-    s = sum(lengths)
-    if part == "ssd":
-        return [scan_work(c, batch, s)]
-    if part == "conv":
-        return [conv_work(c, batch, s)]
+def attention_module_work(c: dict, batch: int, lengths) -> list[tuple[float, float]]:
+    """The attention MODULE whole, one layer: its four projections forward and
+    the gradient to their inputs, and ``attention_work``."""
+    t = batch * sum(lengths)
     work = [attention_work(c, batch, lengths)]
     for fan_in, fan_out in projections(c, "attention").values():
-        work += [_matmul(batch * s, fan_in, fan_out), _matmul(batch * s, fan_out, fan_in)]
+        work += [_matmul(t, fan_in, fan_out), _matmul(t, fan_out, fan_in)]
     return work
 
 

@@ -114,8 +114,10 @@ def moe_products(c: dict, tokens: int, rows: float) -> list[tuple[float, float]]
     return out
 
 
-def step_matmuls(c: dict, job: dict, batch: int, seq_len: int) -> list[tuple[float, float]]:
-    """(FLOPs, least bytes) of every product one step requires."""
+def step_matmuls(c: dict, job: dict, batch: int, seq_len: int,
+                 attention: bool = True) -> list[tuple[float, float]]:
+    """(FLOPs, least bytes) of every product one step requires; without
+    ``attention`` each block's ``attention_work`` is left out."""
     t = batch * seq_len
     d, v = c["hidden_size"], c["vocab_size"]
     out: list[tuple[float, float]] = []
@@ -124,7 +126,8 @@ def step_matmuls(c: dict, job: dict, batch: int, seq_len: int) -> list[tuple[flo
             out.append(_matmul(t, fan_in, fan_out))
             if not (prefix == "layer_0/" and name in ("attn/wq_a", "attn/wkv_a")):
                 out.append(_matmul(t, fan_out, fan_in))
-        out.append(attention_work(c, batch, seq_len))
+        if attention:
+            out.append(attention_work(c, batch, seq_len))
         if experts:
             out += moe_products(c, t, held_rows(c, t))
         else:

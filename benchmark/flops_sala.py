@@ -112,6 +112,11 @@ def mixer_work(c: dict, kind: str, batch: int, seq_len: int) -> tuple[float, flo
     return 3.0 * attend + select, moved
 
 
+def mixers_work(c: dict, kind: str, batch: int, seq_len: int) -> list[tuple[float, float]]:
+    """``mixer_work`` once for every layer of ``kind`` in one step."""
+    return [mixer_work(c, kind, batch, seq_len)] * _mixers(c).count(kind)
+
+
 def train_flops_per_step(c: dict, job: dict, batch: int, seq_len: int) -> float:
     t = batch * seq_len
     n = param_counts(c)

@@ -149,15 +149,20 @@ class Driver(sala.Driver):
         with jax.profiler.TraceAnnotation("bench.window"):
             hist = self._fit(self._batches(deadline=t0 + seconds))
             clock = time.perf_counter() - t0
-        c = self.c
+        c, n, b = self.c, len(hist), self.batch
+        kinds = flops_granite.kinds(c)
+        mamba, attention = kinds.count("mamba"), kinds.count("attention")
         return {
-            "work": float(self.batch * self.seq * len(hist)), "clock_s": clock,
-            "attempted": len(hist), "failed": 0,
+            "work": float(b * self.seq * n), "clock_s": clock, "attempted": n, "failed": 0,
             "pieces_s": [h["step_time_s"] for h in hist], "piece": "step",
-            "flops_required": len(hist) * flops_granite.train_flops_per_step(
-                c, self.job, self.batch, self.lengths),
-            "roofline_work": {"matmul": [(flops_granite.step_matmuls(c, self.job, self.batch, self.seq),
-                                          len(hist))]},
+            "flops_required": n * flops_granite.train_flops_per_step(c, self.job, b, self.lengths),
+            "roofline_work": {
+                "matmul": [(flops_granite.step_matmuls(c, self.job, b, self.seq), n)],
+                "flash": [([flops_granite.attention_work(c, b, self.lengths)],
+                           n * attention * self.on_kernel())],
+                "ssd": [([flops_granite.scan_work(c, b, self.seq)], n * mamba)],
+                "conv": [([flops_granite.conv_work(c, b, self.seq)], n * mamba)],
+                "attention": [(flops_granite.attention_module_work(c, b, self.lengths), n * attention)]},
             "losses": [h["loss"] for h in hist],
             "loss_tokens": [h["loss_tokens"] for h in hist],
             "doc_pairs": [h["doc_pairs"] for h in hist],

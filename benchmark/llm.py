@@ -127,19 +127,32 @@ class Driver:
         return {"first_call_s": first_s, "steady_s": steady}
 
     # ------------------------------------------------------------- window
+    def on_kernel(self) -> float:
+        """Share of the step program's blockwise-attention call sites that
+        the fused flash kernel took (``LLMTrainer.attention_sites``, counted
+        while the step was traced); 0 where none was counted (the ring, or a
+        model without softmax attention)."""
+        sites = getattr(self.trainer, "attention_sites", None) or {}
+        return sites.get("kernel", 0) / max(sum(sites.values()), 1)
+
     def window(self, seconds: float) -> dict:
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.window"):
             hist = self._fit(self._batches(deadline=t0 + seconds))
             clock = time.perf_counter() - t0
         tokens = self.batch * self.seq * len(hist)
-        c = self.c
+        c, n = self.c, len(hist)
+        # attention's products are the matmul fusions' where XLA runs them,
+        # the kernel's own where the flash kernel does
+        layers, kernel = c["num_hidden_layers"], self.on_kernel()
         return {
-            "work": float(tokens), "clock_s": clock, "attempted": len(hist), "failed": 0,
+            "work": float(tokens), "clock_s": clock, "attempted": n, "failed": 0,
             "pieces_s": [h["step_time_s"] for h in hist], "piece": "step",
             "flops_required": tokens * flops.transformer_train_flops_per_token(c, self.seq),
-            "roofline_work": {"matmul": [(flops.transformer_step_matmuls(c, self.batch, self.seq),
-                                          len(hist))]},
+            "roofline_work": {
+                "matmul": [(flops.transformer_step_matmuls(c, self.batch, self.seq, attention=False), n),
+                           (flops.attention_products(c, self.batch, self.seq), n * layers * (1 - kernel))],
+                "flash": [([flops.attention_work(c, self.batch, self.seq)], n * layers * kernel)]},
             "losses": [h["loss"] for h in hist],
         }
 

@@ -134,15 +134,24 @@ class Driver(sala.Driver):
         with jax.profiler.TraceAnnotation("bench.window"):
             hist = self._fit(self._batches(deadline=t0 + seconds))
             clock = time.perf_counter() - t0
-        c = self.c
+        c, n, t = self.c, len(hist), self.batch * self.seq
+        blocks = flops_pangu.blocks(c)
+        expert_blocks = sum(experts for _, experts in blocks)
+        attention = [flops_pangu.attention_work(c, self.batch, self.seq)]
+        kernel = self.on_kernel()
+        # the rows the held experts REALLY saw in the window, spread evenly
+        # over its steps and expert blocks
+        held = sum(h["moe_held"] for h in hist) / max(n * expert_blocks, 1)
         return {
-            "work": float(self.batch * self.seq * len(hist)), "clock_s": clock,
-            "attempted": len(hist), "failed": 0,
+            "work": float(t * n), "clock_s": clock, "attempted": n, "failed": 0,
             "pieces_s": [h["step_time_s"] for h in hist], "piece": "step",
-            "flops_required": len(hist) * flops_pangu.train_flops_per_step(
-                c, self.job, self.batch, self.seq),
-            "roofline_work": {"matmul": [(flops_pangu.step_matmuls(c, self.job, self.batch, self.seq),
-                                          len(hist))]},
+            "flops_required": n * flops_pangu.train_flops_per_step(c, self.job, self.batch, self.seq),
+            "roofline_work": {
+                "matmul": [(flops_pangu.step_matmuls(c, self.job, self.batch, self.seq, attention=False), n),
+                           (attention, n * len(blocks) * (1 - kernel))],
+                "flash": [(attention, n * len(blocks) * kernel)],
+                "mla": [([flops_pangu.mla_work(c, self.batch, self.seq)], n * len(blocks))],
+                "moe": [(flops_pangu.moe_products(c, t, held), n * expert_blocks)]},
             "losses": [h["loss"] for h in hist],
             # the routing each step saw: a step's rounds follow its busiest held expert
             "moe_held": [h["moe_held"] for h in hist],

@@ -31,6 +31,7 @@ import sys
 import traceback
 
 import bench_trace
+import readers
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MIN_NAMED = 0.5   # of a program's device seconds, by ops whose name the map holds
@@ -149,13 +150,28 @@ def selects(entry: dict, args: dict) -> bool:
     return any((s == prefix or s.startswith(prefix + ".")) and s not in skip for s in entry["scopes"])
 
 
+def selected_seconds(ctx, args) -> float | None:
+    """Device seconds of the ops of ``program`` (inside ``span`` where one is
+    named) that ``selects`` keeps; ``None`` where there is nothing to join."""
+    st = load(ctx, args["program"], args.get("span"))
+    if not st:
+        return None
+    return sum(s for op, s in st["op_seconds"].items() if op in st["map"] and selects(st["map"][op], args))
+
+
 def scope_share(ctx, args):
     """% of the window's device op seconds (the denominator of
-    ``readers:op_share``) spent in the ops of ``program`` that ``selects``
-    keeps."""
-    st = load(ctx, args["program"], args.get("span"))
-    total = ctx[_STATE]["total"]
-    if not st or not total:
-        return None
-    mine = sum(s for op, s in st["op_seconds"].items() if op in st["map"] and selects(st["map"][op], args))
-    return 100.0 * mine / total if mine > 0 else None
+    ``readers:op_share``) spent in the ops that ``selected_seconds`` keeps."""
+    mine = selected_seconds(ctx, args)
+    total = ctx[_STATE]["total"] if mine is not None else None
+    return 100.0 * mine / total if total and mine > 0 else None
+
+
+def scope_roofline(ctx, args):
+    """Least time the chip could take for the window's required ``work``
+    (``readers.required_seconds``) over the device seconds of the ops that
+    ``selected_seconds`` keeps: a part's roofline read inside the step it ran
+    in, its rematerialised forward included."""
+    need = readers.required_seconds(ctx, args["work"])
+    took = selected_seconds(ctx, args) if need > 0 else None
+    return 100.0 * need / took if took else None

@@ -346,6 +346,14 @@ def window_attr_ratio(ctx, args):
     return 100.0 * sum(a[args["num"]] for a in rows) / den if den > 0 else None
 
 
+def load_max_over_mean(ctx, args):
+    """The busiest held expert's tokens (``moe_max_load``) over the held
+    experts' mean (``moe_held`` / the configuration's ``n_routed_experts``),
+    summed over the expert layers and the window's steps (1 = even)."""
+    pct = window_attr_ratio(ctx, {"span": "llm.step", "num": "moe_max_load", "den": "moe_held"})
+    return None if pct is None else pct / 100.0 * ctx["config"]["n_routed_experts"]
+
+
 def setup_span_s(ctx, args):
     """Seconds inside set-up's spans of the given names (those the cell's
     entry points opened)."""

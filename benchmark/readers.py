@@ -56,19 +56,28 @@ def op_share(ctx, args):
     return 100.0 * sel / total if total > 0 and sel > 0 else None
 
 
-def op_roofline(ctx, args):
-    """Least time the chip could take for the products the window required
-    (larger of operations/peak and least bytes/HBM peak, per product) over
-    the device time of the selected ops."""
-    ev = ctx.get("events")
-    work = ctx["window"].get("roofline_work", {}).get(args["work"])
-    if not ev or not work:
-        return None
-    need = sum(n * flops.roofline_seconds(per_piece, ctx["peaks"]["bf16_flops"],
+def required_seconds(ctx, work: str) -> float:
+    """Least time the chip could take for the window's required ``work``
+    (the driver's ``roofline_work[work]``: pieces of products, each piece with
+    how many times the window ran it): per product the larger of operations
+    over peak and least bytes over HBM peak; 0 where there is none."""
+    pieces = ctx["window"].get("roofline_work", {}).get(work)
+    if not pieces or not ctx.get("peaks"):
+        return 0.0
+    return sum(n * flops.roofline_seconds(per_piece, ctx["peaks"]["bf16_flops"],
                                           ctx["peaks"]["hbm_bytes_per_s"])
-               for per_piece, n in work)
+               for per_piece, n in pieces)
+
+
+def op_roofline(ctx, args):
+    """``required_seconds`` of the metric's ``work`` over the device time of
+    the selected ops."""
+    ev = ctx.get("events")
+    need = required_seconds(ctx, args["work"])
+    if not ev or need <= 0:
+        return None
     took = sum(bench_trace.op_seconds(ev, _selector(args)).values())
-    return 100.0 * need / took if took > 0 and need > 0 else None
+    return 100.0 * need / took if took > 0 else None
 
 
 def device_idle_share(ctx, args):

@@ -7,7 +7,7 @@ local SGD with batch normalisation in training mode and steps beyond a
 client's own budget left out (``step_mode=match``), the sample-weighted mean
 of the clients' variables (running statistics included), and the evaluation
 on the test set in inference mode.  The random streams are the program's
-published discipline, written out: root key from the seed, ``fold_in`` by
+published discipline, written out: root key from ``round_seed``, ``fold_in`` by
 round, by the client tag and id, by epoch.
 
 Departures from the published recipe (He et al. ResNet-20 on CIFAR-10,
@@ -33,6 +33,14 @@ CLIENT_TAG = 0x636C69
 BN_MOMENTUM, BN_EPS = 0.9, 1e-5
 E4M3_MAX = 448.0
 HI = jax.lax.Precision.HIGHEST
+
+
+def round_seed(t: dict, seed: int) -> int:
+    """The program's ``random_seed``: every round's cohort and each client's
+    batch order.  A traffic file that names ``round_seed`` gives every
+    ``--seed`` the same cohorts, so the same work in a window, while the
+    images, labels and weights still come from ``--seed``."""
+    return t.get("round_seed", seed) % (2 ** 31)
 
 
 # ------------------------------------------------------------------- data
@@ -224,7 +232,7 @@ class ReferenceFedAvg:
         self.w0 = init_weights(c, seed)
         self.w = dict(self.w0)
         self.round_idx = 0
-        self.root = jax.random.PRNGKey(seed % (2 ** 31))
+        self.root = jax.random.PRNGKey(round_seed(t, seed))
         self.bsz = t["batch_size"]
         self.cap = capacity(data["clients"], self.bsz)
         self.spe = self.cap // self.bsz

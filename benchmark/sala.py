@@ -160,14 +160,14 @@ class Driver(llm.Driver):
             hist = self._fit(self._batches(deadline=t0 + seconds))
             clock = time.perf_counter() - t0
         tokens = self.batch * self.seq * len(hist)
-        c = self.c
+        c, n = self.c, len(hist)
+        mixers = lambda kind: [(flops_sala.mixers_work(c, kind, self.batch, self.seq), n)]
         return {
-            "work": float(tokens), "clock_s": clock, "attempted": len(hist), "failed": 0,
+            "work": float(tokens), "clock_s": clock, "attempted": n, "failed": 0,
             "pieces_s": [h["step_time_s"] for h in hist], "piece": "step",
-            "flops_required": len(hist) * flops_sala.train_flops_per_step(
-                c, self.job, self.batch, self.seq),
-            "roofline_work": {"matmul": [(flops_sala.step_matmuls(c, self.job, self.batch, self.seq),
-                                          len(hist))]},
+            "flops_required": n * flops_sala.train_flops_per_step(c, self.job, self.batch, self.seq),
+            "roofline_work": {"matmul": [(flops_sala.step_matmuls(c, self.job, self.batch, self.seq), n)],
+                              "lightning": mixers("lightning-attn"), "sparse": mixers("minicpm4")},
             "losses": [h["loss"] for h in hist],
         }
 

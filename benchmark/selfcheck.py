@@ -8,11 +8,17 @@ by ``python3 benchmark/selfcheck.py``.
   brute-force sweep below, not by the reducer;
 - the FLOP and byte functions give 698M / 1.57G parameters and ResNet-20's
   245.1 MFLOP a sample to within 1%;
-- every name and unit of ``BENCHMARK.json`` keeps to the contract's letters.
+- every name and unit of ``BENCHMARK.json`` keeps to the contract's letters;
+  ``per_layer`` holds at most 128 entries under names of their own, each with
+  a file whose reader resolves, every cell it lists is a cell, and no two
+  entries read the same number: the same reader with the same arguments,
+  moving the same end-to-end metric (a new cell joins a metric's
+  ``workloads`` in ``BENCHMARK.json``, which alone holds that list).
 """
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import re
@@ -28,6 +34,16 @@ import flops
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+MAX_PER_LAYER = 128
+# tests/test_granite.py, which a benchmark PR may not edit, pins 22 granite.*
+# entries: these read what llm.* and step.* entries read, and go when a PR
+# that may edit that test lists the Granite cell in those entries instead
+PINNED_COPIES = frozenset(
+    [f"granite.{m}" for m in ("mfu", "step_ms_p50", "step_ms_p90", "device_idle_share", "hbm_peak_gb",
+                              "matmul_share", "xla_matmul_roofline", "host_ms_p50", "host_ms_max",
+                              "idle_between_steps_share", "idle_h2d_share", "idle_dispatch_share",
+                              "idle_sync_share", "window_compiles")]
+    + ["granite.mamba_step_share", "granite.ssd_step_share", "granite.attention_step_share"])
 
 
 def sweep_busy(intervals, lo, hi):
@@ -92,9 +108,35 @@ def check_names() -> None:
         for kind, key in (("configs", "config"), ("traffic", "traffic")):
             assert os.path.exists(os.path.join(HERE, kind, w[key] + ".json")), w[key]
         assert os.path.exists(os.path.join(HERE, "limits", w["name"] + ".json")), w["name"]
+    cells = {w["name"] for w in b["workloads"]}
+    for m in b["end_to_end"]:
+        assert set(m.get("workloads", cells)) <= cells, (m["name"], m["workloads"])
+    check_per_layer(b)
+
+
+def _metric_spec(name: str) -> dict:
+    with open(os.path.join(HERE, "metrics", name + ".json")) as fh:
+        return json.load(fh)
+
+
+def check_per_layer(b: dict) -> None:
+    names = [m["name"] for m in b["per_layer"]]
+    assert len(names) <= MAX_PER_LAYER, len(names)
+    assert len(set(names)) == len(names), sorted(n for n in set(names) if names.count(n) > 1)
+    cells = {w["name"] for w in b["workloads"]}
+    by_reader: dict = {}
     for m in b["per_layer"]:
-        assert os.path.exists(os.path.join(HERE, "metrics", m["name"] + ".json")), m["name"]
         assert any(e["name"] == m["moves"] for e in b["end_to_end"]), m
+        assert set(m.get("workloads", cells)) <= cells, (m["name"], m["workloads"])
+        spec = _metric_spec(m["name"])
+        mod, _, fn = spec["reader"].partition(":")
+        assert callable(getattr(importlib.import_module(mod), fn, None)), (m["name"], spec["reader"])
+        # the list is BENCHMARK.json's; a copy in the file may only repeat it
+        assert spec.get("workloads", m.get("workloads")) == m.get("workloads"), m["name"]
+        key = (spec["reader"], json.dumps(spec.get("args", {}), sort_keys=True), m["moves"])
+        by_reader.setdefault(key, []).append(m["name"])
+    copies = {k: v for k, v in by_reader.items() if len([n for n in v if n not in PINNED_COPIES]) > 1}
+    assert not copies, f"entries that read the same number: {list(copies.values())}"
 
 
 def run() -> None:
