@@ -43,6 +43,10 @@ _FAN_IN_ALL_BUT_LAST = r".*attn/wo/kernel"
 #: the held experts' stacked kernels (experts, in, out): one factor pair
 #: would read the number of experts as the fan-in
 _STACKED = r".*moe/experts/w_(gate|up|down)"
+#: a sparse-attention mixer's indexer (``models/transformer.Indexer``): it
+#: only chooses keys, no gradient passes through the choice, so an adapter on
+#: it would never move; no target takes it
+_FROZEN = r".*attn/indexer/.*"
 
 
 def _fan_in(path: str, shape) -> int:
@@ -55,7 +59,7 @@ def _match_paths(params, targets: str):
 
     def visit(path, leaf):
         ps = "/".join(str(getattr(p, "key", p)) for p in path)
-        if re.fullmatch(targets, ps) and leaf.ndim >= 2:
+        if re.fullmatch(targets, ps) and leaf.ndim >= 2 and not re.fullmatch(_FROZEN, ps):
             out.append((ps, leaf.shape, leaf.dtype))
 
     jax.tree_util.tree_map_with_path(visit, params)

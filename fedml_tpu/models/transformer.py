@@ -29,6 +29,12 @@ convolution, the selective scan of ``ops/ssd.py``, a gated norm); beside it
 the plain ``Attention`` may go without RoPE (``attn_rope``), with a scale of
 its own (``attn_scale``) and, on packed rows, blockwise (no ``s x s``
 scores); ``tie_embeddings`` reads the logits off the embedding.
+A sixth, ``DSAttention``, is DeepSeek sparse attention as Keye-VL-2.0 has it
+on grouped-query attention: a frozen learned indexer (``Indexer``,
+``attn/indexer/wq|wk|weights/kernel``) chooses ``dsa_topk`` keys for each
+query (``ops/dsa.py``), and the query's heads attend those alone; beside it
+the expert router may score by a softmax over all the experts
+(``router_scoring``).
 ``segments`` (document ids of packed rows) reach every mixer: state,
 convolution and attention stop at a document's start, and the loss counts
 the positions whose target lies in their own document.
@@ -132,6 +138,13 @@ class TransformerConfig:
     attn_scale: float = 0.0
     # logits = hidden E^T: the embedding is the head (no ``lm_head``)
     tie_embeddings: bool = False
+    # "dsa": DeepSeek sparse attention (``DSAttention``): the indexer's query
+    # heads and their width (one key head), and the keys each query keeps
+    dsa_index_heads: int = 0
+    dsa_index_head_dim: int = 0
+    dsa_topk: int = 0
+    # the expert router's scores (``ops/moe.route``): "sigmoid" or "softmax"
+    router_scoring: str = "sigmoid"
 
     def has_experts(self, layer: int) -> bool:
         return self.n_routed_experts > 0 and layer >= self.first_k_dense
@@ -158,7 +171,9 @@ class TransformerConfig:
 
     @property
     def has_sparse_layers(self) -> bool:
-        return any(self.mixer(i) == "minicpm4" for i in range(self.n_layers))
+        """Layers that choose the keys each query attends: they sow what they
+        kept, and a remat keeps their choice (``SPARSE_KEEP``)."""
+        return any(self.mixer(i) in ("minicpm4", "dsa") for i in range(self.n_layers))
 
     @classmethod
     def tiny(cls, vocab_size: int = 1024):
@@ -419,6 +434,82 @@ class MLAttention(nn.Module):
         return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
 
 
+class Indexer(nn.Module):
+    """DeepSeek-V3.2's lightning indexer over the mixer's input ``x``: queries
+    ``q_j = rope(x W_q)`` (``dsa_index_heads`` of ``dsa_index_head_dim``), ONE
+    key a token ``k = rope(LayerNorm(x W_k))``, weights ``w = x W_w /
+    sqrt(heads x head_dim)``; the ``dsa_topk`` keys ``s <= t`` of best
+    ``sum_j w_j ReLU(q_j . k_s)`` for each query (``ops/dsa.select_tokens``,
+    the choice under ``llm.mixer.dsa.indexer.select``).  Frozen: no adapter
+    reads it and no gradient leaves it.  Returns (the choice packed a bit a
+    key, (b, s, s / 32) uint32; the pairs chosen, summed over the batch)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        from ..ops import dsa
+
+        cfg = self.cfg
+        h, d = cfg.dsa_index_heads, cfg.dsa_index_head_dim
+        x = jax.lax.stop_gradient(x)
+        dense = lambda name, features: nn.DenseGeneral(features, use_bias=False, dtype=cfg.dtype, name=name)(x)
+        q = rope(dense("wq", (h, d)), positions, cfg.rope_theta)
+        k = nn.LayerNorm(cfg.norm_eps, dtype=jnp.float32, name="k_norm")(dense("wk", d))
+        k = rope(k[:, :, None], positions, cfg.rope_theta)[:, :, 0].astype(cfg.dtype)
+        w = dense("weights", h).astype(jnp.float32) * (h * d) ** -0.5
+        return dsa.select_tokens(q, k, w, cfg.dsa_topk)
+
+
+class DSAttention(nn.Module):
+    """DeepSeek sparse attention (DSA) on grouped-query softmax attention:
+    QK-norm and RoPE on ``n_heads`` queries over ``n_kv_heads`` keys and
+    values; the ``Indexer`` chooses ``dsa_topk`` keys ``s <= t`` for each
+    query, and every head attends those alone
+    (``ops/sparse_attention.block_sparse_attention`` with the choice as its
+    mask: the ``lax`` blockwise pass, which computes every causal pair of
+    chunks and lets the mask drop what was not chosen); then ``W_o``.  The
+    choice is named ``SPARSE_KEEP``, so that a remat keeps it (134 MB a layer
+    at 32,768 tokens) and does not choose again.  Sows ``sparse_kept`` and
+    ``sparse_causal`` as ``SparseAttention`` does (keys, summed over batch,
+    KV heads and queries)."""
+
+    cfg: TransformerConfig
+    mesh: Optional[Any] = None
+    seq_axis: Optional[str] = None
+
+    @nn.compact
+    @_scoped("llm.mixer.dsa")
+    def __call__(self, x, positions, segments=None):
+        from jax.ad_checkpoint import checkpoint_name
+
+        from ..ops.sparse_attention import CHUNK, SPARSE_KEEP, WORD, block_sparse_attention
+
+        _no_seq_axis(self)
+        _no_segments(self, segments)
+        cfg = self.cfg
+        b, s = x.shape[:2]
+        hd = cfg.head_dim or cfg.d_model // cfg.n_heads
+        q = _project(self, "wq", x, (cfg.n_heads, hd))
+        k = _project(self, "wk", x, (cfg.n_kv_heads, hd))
+        v = _project(self, "wv", x, (cfg.n_kv_heads, hd))
+        q, k = _qk_normed(cfg, q, k)
+        q, k = rope(q, positions, cfg.rope_theta), rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("llm.mixer.dsa.indexer"):
+            keep, chosen = Indexer(cfg, name="indexer")(x, positions)
+        keep = checkpoint_name(keep, SPARSE_KEEP)
+        with jax.named_scope("llm.mixer.dsa.core"):
+            out = block_sparse_attention(q, k, v, keep[:, None], block_size=WORD, q_chunk=CHUNK,
+                                         k_chunk=CHUNK, mesh=self.mesh)
+        add = lambda a, b: a + b
+        kv = jnp.float32(cfg.n_kv_heads)
+        self.sow("stats", "sparse_kept", chosen.astype(jnp.float32) * kv, init_fn=lambda: jnp.float32(0),
+                 reduce_fn=add)
+        self.sow("stats", "sparse_causal", kv * jnp.float32(b * s * (s + 1) / 2), init_fn=lambda: jnp.float32(0),
+                 reduce_fn=add)
+        return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
+
+
 def causal_conv(x, kernel, bias, segments=None):
     """Depthwise causal convolution over the sequence: x (b, s, c), kernel
     (taps, c), bias (c,) -> ``bias + sum_i kernel[i] * x[t - (taps - 1) + i]``
@@ -492,7 +583,7 @@ def _dt_bias_init(key, shape, dt_min: float = 1e-3, dt_max: float = 1e-1):
 
 
 MIXERS = {"attention": Attention, "lightning-attn": LightningAttention, "minicpm4": SparseAttention,
-          "mla": MLAttention, "mamba": Mamba}
+          "mla": MLAttention, "mamba": Mamba, "dsa": DSAttention}
 
 
 class MLP(nn.Module):
@@ -528,7 +619,8 @@ class Router(nn.Module):
 
         cfg = self.cfg
         kernel = self.param("kernel", nn.initializers.lecun_normal(), (x.shape[-1], cfg.n_routed_experts))
-        return route(x, kernel.astype(x.dtype), cfg.top_k, cfg.routed_scaling_factor, cfg.norm_topk_prob)
+        return route(x, kernel.astype(x.dtype), cfg.top_k, cfg.routed_scaling_factor, cfg.norm_topk_prob,
+                     cfg.router_scoring)
 
 
 class Experts(nn.Module):

@@ -67,6 +67,8 @@ SPARSE_KEEP = "sparse_keep"
 #: the selection once, the attention's forward twice and its backward, at
 #: 16,384 tokens, 32 query and 2 KV heads x 128, on a v5e; PR 30)
 CHUNK = 512
+#: keys one uint32 word of a packed token selection holds (``ops/dsa.py``)
+WORD = 32
 
 
 def _chunk(n: int, want: int, multiple: int = 1) -> int:
@@ -111,12 +113,23 @@ def best_of(score, candidate, topk: int):
     floats order as their bit patterns do, so the row's ``topk``-th largest
     pattern is found bit by bit, 31 times one compare and one count over the
     row, and ties at it are cut by a prefix count."""
-    bits = jnp.where(candidate, jax.lax.bitcast_convert_type(score, jnp.int32), -1)
-    at = jnp.zeros((*bits.shape[:-1], 1), jnp.int32)      # largest v with #(bits >= v) >= topk
-    for bit in range(30, -1, -1):
-        trial = at | (1 << bit)
-        at = jnp.where(jnp.sum(bits >= trial, -1, keepdims=True) >= topk, trial, at)
-    above, tied = bits > at, bits == at
+    return top_by_key(jnp.where(candidate, jax.lax.bitcast_convert_type(score, jnp.int32), -1), topk, 30)
+
+
+def top_by_key(keys, topk: int, top_bit: int, tieable=None):
+    """keys: (..., n) integers below ``2 ** (top_bit + 1)``, a non-candidate's
+    below every candidate's -> (..., n) bool, the ``topk`` largest keys of
+    each row, ties to the lower index.  The row's ``topk``-th largest key is
+    found bit by bit from ``top_bit`` down, one compare and one count over the
+    row a bit, and ties at it are cut by a prefix count; only ``tieable``
+    entries, where given, may be chosen at the cut."""
+    at = jnp.zeros((*keys.shape[:-1], 1), keys.dtype)     # largest v with #(keys >= v) >= topk
+    for bit in range(top_bit, -1, -1):
+        trial = at | keys.dtype.type(1 << bit)
+        at = jnp.where(jnp.sum(keys >= trial, -1, keepdims=True) >= topk, trial, at)
+    above, tied = keys > at, keys == at
+    if tieable is not None:
+        tied = tied & tieable
     room = topk - jnp.sum(above, -1, keepdims=True)
     return above | (tied & (jnp.cumsum(tied, -1) <= room))
 
@@ -201,13 +214,28 @@ def _zeros_for(ks, vs):
     return zk, (zk if vs.shape == ks.shape else jnp.zeros(vs.shape, jnp.float32))
 
 
+def unpack(bits):
+    """A token selection packed ``WORD`` keys a uint32 word, (..., n) ->
+    (..., n x WORD) bool: key ``WORD w + j`` is bit ``j`` of word ``w``."""
+    one = (bits[..., None] >> jnp.arange(WORD, dtype=jnp.uint32)) & jnp.uint32(1)
+    return one.astype(bool).reshape(*bits.shape[:-1], -1)
+
+
+def _keys_kept(keep_qk, block_size: int):
+    """A pair of chunks' mask by key: each kept block's ``block_size`` keys,
+    or the bits of a packed token selection."""
+    if keep_qk.dtype == jnp.uint32:
+        return unpack(keep_qk)
+    return jnp.repeat(keep_qk, block_size, axis=-1)
+
+
 def _masked_logits(qc, kc, keep_qk, iq, ik, block_size: int, scale: float, docs=(None, None)):
     """Scores of one pair of chunks, (b, kv, g, cq, ck) float32, with their
     mask: the kept blocks' tokens at or before each query, and with ``docs``
     (the chunks' document indices, (b, cq) and (b, ck)) in its document."""
     cq, ck = qc.shape[1], kc.shape[1]
     q_pos, k_pos = iq * cq + jnp.arange(cq), ik * ck + jnp.arange(ck)
-    mask = (jnp.repeat(keep_qk, block_size, axis=-1) & (q_pos[:, None] >= k_pos[None, :]))[:, :, None]
+    mask = (_keys_kept(keep_qk, block_size) & (q_pos[:, None] >= k_pos[None, :]))[:, :, None]
     if docs[0] is not None:
         mask = mask & (docs[0][:, :, None] == docs[1][:, None, :])[:, None, None]
     logits = jnp.einsum("bqkgd,btkd->bkgqt", qc, kc, preferred_element_type=jnp.float32) * scale
@@ -348,8 +376,10 @@ def block_sparse_attention(q, k, v, keep=None, *, block_size: int = 64, q_chunk:
     """q: (b, s, h, d); k: (b, s, kv, d); v: (b, s, kv, dv), a value width of
     its own allowed (latent attention: 192-wide queries and keys, 128-wide
     values); keep: (b, kv, s, s // block_size) bool, or (b, 1, ...) for all KV
-    heads alike, or None (every block) -> softmax attention of each query over
-    the tokens ``j <= t`` of its kept blocks, (b, s, h, dv) in q's dtype.
+    heads alike, or None (every block), or with ``block_size`` 32 a token
+    selection packed into uint32 words (``ops/dsa.py:pack``, a bit a key) ->
+    softmax attention of each query over the tokens ``j <= t`` of its kept
+    blocks, (b, s, h, dv) in q's dtype.
     ``mesh`` is the mesh the calling module holds, if any: what it computes on
     may be sharded, which keeps it on the ``lax`` pass (``attention_path``).
     ``head_group``, with ``keep=None`` and a key per head: the heads the

@@ -41,6 +41,12 @@ TRANSFORMER_RULES = [
     (r".*attn/in_proj/kernel", lambda dp, tp: P(dp, tp)),
     (r".*attn/out_proj/kernel", lambda dp, tp: P(tp, dp)),
     (r".*attn/(conv_kernel|conv_bias|A_log|D|dt_bias)", lambda dp, tp: P()),
+    # a sparse-attention mixer's indexer: its query heads (d_model -> heads x
+    # width), its one key and its weights shard their input over data only
+    # (the choice sums over the heads on every shard); the key's LayerNorm is
+    # small and replicated
+    (r".*attn/indexer/(wq|wk|weights)/kernel", lambda dp, tp: P(dp, None, None)),
+    (r".*attn/indexer/k_norm/(scale|bias)", lambda dp, tp: P()),
     # mlp: gate/up shard out over model; down shards in over model; an expert
     # layer's shared experts (moe/shared) are an mlp
     (r".*(mlp|moe/shared)/w_(gate|up)/kernel", lambda dp, tp: P(dp, tp)),
