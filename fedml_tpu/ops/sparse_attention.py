@@ -25,12 +25,16 @@ kernel where ``attention_path`` finds that it can:
     weighted sum), key chunks wholly in a query chunk's future skipped.  It
     computes the scores of every key chunk at or before the query chunk and
     lets the mask drop what was not kept: a dense-masked pass, not yet one
-    that skips dropped blocks.  The backward pass is written out (the
-    flash-attention one): the forward keeps the output and each row's
-    log-sum-exp, the backward recomputes each pair of chunks' probabilities
-    once; no score matrix is kept and nothing is rematerialised twice.  The
-    values may have a width of their own (latent attention's 192-wide keys
-    over 128-wide values), and ``keep=None`` is plain causal attention.
+    that skips dropped blocks.  The mask is cut by query chunk alone and each
+    pair of chunks slices its key chunk's columns from it in place, so that
+    its minor axis stays lane-dense (a packed choice's words whole) and the
+    whole mask is never relaid into pairs of chunks.  The backward pass is
+    written out (the flash-attention one): the forward keeps the output and
+    each row's log-sum-exp, the backward recomputes each pair of chunks'
+    probabilities once; no score matrix is kept and nothing is
+    rematerialised twice.  The values may have a width of their own (latent
+    attention's 192-wide keys over 128-wide values), and ``keep=None`` is
+    plain causal attention.
     With ``segments`` (packed documents) a query attends its own document's
     tokens alone: a token-level mask beside the blocks', on either path.
 
@@ -181,18 +185,21 @@ def select_blocks(q, k, *, kernel_size: int, kernel_stride: int, block_size: int
     return keep, kept, jnp.float32(b * kv) * (s * (s + 1) / 2)
 
 
-def _chunks(q, k, v, keep, block_size: int, cq: int, ck: int):
+def _chunks(q, k, v, keep, cq: int, ck: int):
     """The operands cut into chunks, the chunk index leading: q (nq, b, cq,
     kv, g, d), k (nk, b, ck, kv, d), v (nk, b, ck, kv, dv), and the mask by
-    pair of chunks (nq, nk, b, kv or 1, cq, ck // block_size)."""
+    query chunk alone, (nq, b, kv or 1, cq, n) with its ``n`` columns whole:
+    each pair of chunks slices its key chunk's columns in place
+    (``_masked_logits``).  Only leading axes move, so the mask keeps its
+    lane-dense minor axis (a packed choice's 1,024 words at 32,768 tokens,
+    where a pair of chunks holds 16) and is never relaid whole."""
     b, s, h, d = q.shape
     kv = k.shape[2]
     nq, nk = s // cq, s // ck
     qs = jnp.moveaxis(q.reshape(b, nq, cq, kv, h // kv, d), 1, 0)
     ks = jnp.moveaxis(k.reshape(b, nk, ck, kv, d), 1, 0)
     vs = jnp.moveaxis(v.reshape(b, nk, ck, kv, v.shape[-1]), 1, 0)
-    keeps = jnp.transpose(keep.reshape(b, keep.shape[1], nq, cq, nk, ck // block_size),
-                          (2, 4, 0, 1, 3, 5))
+    keeps = jnp.moveaxis(keep.reshape(b, keep.shape[1], nq, cq, keep.shape[-1]), 2, 0)
     return qs, ks, vs, keeps
 
 
@@ -229,11 +236,16 @@ def _keys_kept(keep_qk, block_size: int):
     return jnp.repeat(keep_qk, block_size, axis=-1)
 
 
-def _masked_logits(qc, kc, keep_qk, iq, ik, block_size: int, scale: float, docs=(None, None)):
+def _masked_logits(qc, kc, keep_q, iq, ik, block_size: int, scale: float, docs=(None, None)):
     """Scores of one pair of chunks, (b, kv, g, cq, ck) float32, with their
     mask: the kept blocks' tokens at or before each query, and with ``docs``
-    (the chunks' document indices, (b, cq) and (b, ck)) in its document."""
+    (the chunks' document indices, (b, cq) and (b, ck)) in its document.
+    ``keep_q`` is the query chunk's whole mask (b, kv or 1, cq, n); the key
+    chunk's columns are sliced from it here, so that the slice fuses into the
+    unpacking and the mask."""
     cq, ck = qc.shape[1], kc.shape[1]
+    cols = ck // block_size
+    keep_qk = jax.lax.dynamic_slice_in_dim(keep_q, ik * cols, cols, axis=-1)
     q_pos, k_pos = iq * cq + jnp.arange(cq), ik * ck + jnp.arange(ck)
     mask = (_keys_kept(keep_qk, block_size) & (q_pos[:, None] >= k_pos[None, :]))[:, :, None]
     if docs[0] is not None:
@@ -253,18 +265,18 @@ def _attend_fwd(q, k, v, keep, block_size, cq, ck, scale, doc=None):
     (b, s): the tokens' document indices on packed rows."""
     b, s, h, d = q.shape
     kv, f32 = k.shape[2], jnp.float32
-    qs, ks, vs, keeps = _chunks(q, k, v, keep, block_size, cq, ck)
+    qs, ks, vs, keeps = _chunks(q, k, v, keep, cq, ck)
     doc_qs, doc_ks = _doc_chunks(doc, cq, ck)
 
     def one_query_chunk(args):
         qc, keep_q, iq, doc_q = args
 
         def one_key_chunk(carry, xs):
-            kc, vc, keep_qk, ik, doc_k = xs
+            kc, vc, ik, doc_k = xs
 
             def attend(carry):
                 m, l, o = carry
-                logits, mask = _masked_logits(qc, kc, keep_qk, iq, ik, block_size, scale,
+                logits, mask = _masked_logits(qc, kc, keep_q, iq, ik, block_size, scale,
                                               (doc_q, doc_k))
                 m_new = jnp.maximum(m, logits.max(-1))
                 alpha = jnp.exp(m - m_new)
@@ -279,7 +291,7 @@ def _attend_fwd(q, k, v, keep, block_size, cq, ck, scale, doc=None):
         init = (jnp.full((b, kv, h // kv, cq), NEG_INF, f32), jnp.zeros((b, kv, h // kv, cq), f32),
                 jnp.zeros((b, cq, kv, h // kv, v.shape[-1]), f32))
         (m, l, o), _ = jax.lax.scan(one_key_chunk, init,
-                                    (ks, vs, keep_q, jnp.arange(s // ck), doc_ks))
+                                    (ks, vs, jnp.arange(s // ck), doc_ks))
         l = jnp.maximum(l, 1e-30)
         return (o / jnp.moveaxis(l, 3, 1)[..., None]).astype(q.dtype), m + jnp.log(l)
 
@@ -294,7 +306,7 @@ def _attend_bwd(block_size, cq, ck, scale, saved, d_out):
     q, k, v, keep, doc, out, lse = saved                 # lse: (nq, b, kv, g, cq)
     b, s, h, d = q.shape
     kv, f32 = k.shape[2], jnp.float32
-    qs, ks, vs, keeps = _chunks(q, k, v, keep, block_size, cq, ck)
+    qs, ks, vs, keeps = _chunks(q, k, v, keep, cq, ck)
     doc_qs, doc_ks = _doc_chunks(doc, cq, ck)
     dos = jnp.moveaxis(d_out.reshape(b, s // cq, cq, kv, h // kv, v.shape[-1]), 1, 0)
     # delta_t = sum_j p_tj dp_tj = do_t . o_t
@@ -306,10 +318,10 @@ def _attend_bwd(block_size, cq, ck, scale, saved, d_out):
         delta_q = jnp.transpose(delta_q, (0, 2, 3, 1))   # (b, kv, g, cq)
 
         def one_key_chunk(dq, xs):
-            kc, vc, keep_qk, ik, doc_k = xs
+            kc, vc, ik, doc_k = xs
 
             def attend(dq):
-                logits, mask = _masked_logits(qc, kc, keep_qk, iq, ik, block_size, scale,
+                logits, mask = _masked_logits(qc, kc, keep_q, iq, ik, block_size, scale,
                                               (doc_q, doc_k))
                 p = jnp.where(mask, jnp.exp(logits - lse_q[..., None]), 0.0)
                 dv = jnp.einsum("bkgqt,bqkgd->btkd", p.astype(doc.dtype), doc, preferred_element_type=f32)
@@ -324,7 +336,7 @@ def _attend_bwd(block_size, cq, ck, scale, saved, d_out):
                                 lambda dq: (dq, nothing), dq)
 
         dq, (dk, dv) = jax.lax.scan(one_key_chunk, jnp.zeros(qc.shape, f32),
-                                    (ks, vs, keep_q, jnp.arange(s // ck), doc_ks))
+                                    (ks, vs, jnp.arange(s // ck), doc_ks))
         return (dkv[0] + dk, dkv[1] + dv), dq.astype(q.dtype)
 
     (dk, dv), dq = jax.lax.scan(one_query_chunk, _zeros_for(ks, vs),

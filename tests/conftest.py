@@ -108,3 +108,29 @@ def tiny_config(**overrides):
 @pytest.fixture
 def make_tiny_config():
     return tiny_config
+
+
+def transposes_of(closed_jaxpr, dtype) -> list:
+    """Every ``transpose`` equation on an operand of ``dtype`` in a jaxpr and
+    in the jaxprs inside it (loops, branches, custom rules), as (operand
+    shape, permutation)."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "transpose" and eqn.invars[0].aval.dtype == dtype:
+                found.append((eqn.invars[0].aval.shape, tuple(eqn.params["permutation"])))
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (tuple, list)) else (param,):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(closed_jaxpr.jaxpr)
+    return found
+
+
+def minor_axes_moved(perm) -> bool:
+    """A permutation that moves either of an array's last two axes."""
+    n = len(perm)
+    return tuple(perm[-2:]) != (n - 2, n - 1)

@@ -172,6 +172,80 @@ def test_sparse_attention_mixer_is_the_reference(chunk, index_chunk, bench, monk
     _close(got_g, want_g)
 
 
+# -- the blockwise pass's read of the packed choice ----------------------------------------
+def _packed_choice(b, s, seed):
+    """A random packed choice (b, 1, s, s / 32) uint32 that keeps about half
+    of every query's keys and always its own token."""
+    from fedml_tpu.ops.dsa import pack
+
+    chosen = np.random.default_rng(seed).random((b, 1, s, s)) < 0.5
+    chosen[..., np.arange(s), np.arange(s)] = True
+    return pack(chosen)
+
+
+def test_blockwise_pass_reads_the_packed_choice_in_place():
+    """The pass cuts the packed choice by query chunk alone and slices a key
+    chunk's words where the pair is computed: the uint32 choice is moved by
+    its leading axes only, so no relayout of its rows into chunk-pair order
+    (a minor axis of ``k_chunk / 32`` words) is left, in the forward or in
+    the gradient."""
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.ops.sparse_attention import block_sparse_attention
+
+    from .conftest import minor_axes_moved, transposes_of
+
+    q = jax.ShapeDtypeStruct((1, 256, 4, 16), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 256, 2, 16), jnp.bfloat16)
+    keep = jax.ShapeDtypeStruct((1, 1, 256, 8), jnp.uint32)
+    f = lambda q, k, v, keep: block_sparse_attention(q, k, v, keep, block_size=32, q_chunk=64, k_chunk=64)
+    loss = lambda q, k, v, keep: jnp.sum(f(q, k, v, keep).astype(jnp.float32) ** 2)
+    for jaxpr in (jax.make_jaxpr(f)(q, k, k, keep),
+                  jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, k, keep)):
+        moved = transposes_of(jaxpr, jnp.uint32)
+        assert moved, "the choice's query chunks are moved ahead by a transpose the walk must see"
+        assert [m for m in moved if minor_axes_moved(m[1])] == []
+
+
+@pytest.mark.parametrize("b", [1, 2])
+@pytest.mark.parametrize("q_chunk,k_chunk", [(64, 64), (64, 128), (128, 64)])
+def test_packed_choice_is_its_unpacked_token_mask(q_chunk, k_chunk, b):
+    """The packed choice (a bit a key, ``block_size`` 32) against the same
+    choice unpacked to a token mask (``block_size`` 1) and against softmax
+    over the masked scores whole: the output and the gradients to q, k and v,
+    with every key chunk's words sliced at its own offset."""
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.ops.sparse_attention import block_sparse_attention, unpack
+
+    s, h, kv, d = 256, 4, 2, 16
+    keys = jax.random.split(jax.random.PRNGKey(41 + b), 4)
+    q, k, v, probe = (jax.random.normal(key, (b, s, n, d)) for key, n in zip(keys, (h, kv, kv, h)))
+    packed = _packed_choice(b, s, seed=b)
+    tokens = unpack(packed)
+
+    def dense(q, k, v):
+        kk, vv = (jnp.repeat(t, h // kv, axis=2) for t in (k, v))
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, kk, precision="highest") * d ** -0.5
+        mask = tokens & jnp.tril(jnp.ones((s, s), bool))
+        p = jax.nn.softmax(jnp.where(mask, logits, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, vv, precision="highest")
+
+    def run(attend):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(attend(q, k, v) * probe), argnums=(0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        got = run(lambda q, k, v: block_sparse_attention(q, k, v, packed, block_size=32,
+                                                         q_chunk=q_chunk, k_chunk=k_chunk))
+        as_tokens = run(lambda q, k, v: block_sparse_attention(q, k, v, tokens, block_size=1,
+                                                               q_chunk=q_chunk, k_chunk=k_chunk))
+        want = run(dense)
+    for g, t, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(as_tokens),
+                       jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, t, rtol=1e-6, atol=1e-6)
+        _close(g, w)
+
+
 # -- the router and the expert layer -------------------------------------------------
 def test_softmax_router_is_the_reference(bench):
     """Softmax scores over all 32, the 4 best without a sort, gates

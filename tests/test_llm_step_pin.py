@@ -20,8 +20,16 @@ PR 38 sent the plain ``Attention``'s unpacked rows through the blockwise entry
 TPU device, the ``lax`` pass here) where they built (b, h, s, s) scores whole:
 ``tiny_default`` and ``mistral_7b_d2_rehearsal`` build that module, so their
 text changed by design and both pins were taken again on that PR's own tree;
-``minicpm_sala_d4_rehearsal_adapters`` builds no ``Attention`` and is PR 33's
-pin still.
+``minicpm_sala_d4_rehearsal_adapters`` builds no ``Attention``, and its pin
+stayed as it was then.
+
+Since then the blockwise pass cuts its mask by query chunk alone and slices
+each key chunk's columns where the pair is computed, where it relaid the
+whole mask into chunk-pair order: the same products in the same order, so
+one step of each of the three gives the earlier trained leaves and loss to
+the bit on the CPU, but the text differs (a ``dynamic_slice`` a pair, a
+``transpose`` of leading axes alone).  All three pins were taken again on
+the tree that made that change.
 """
 
 import hashlib
@@ -36,11 +44,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: (sha256 of the text, its length) on the commit the docstring names for each
 PARENT = {
-    "tiny_default": ("39e60b8755a50d377a2942f102e1eee8218e15cf5500fd85a50c931b4f1bce27", 200745),
+    "tiny_default": ("a678bc931553e6e880163f0c8aa70fd748705cd41fa506d2f97314514ef46dee", 203253),
     "mistral_7b_d2_rehearsal": (
-        "390beb8e2ba545ffa443b39ccac333e63c6abe3912f143387c950183e404764f", 199400),
+        "8d58af5b2f3cf940192313ebb2e06602c19799d0b1494d914147871baf8c3b0e", 201908),
     "minicpm_sala_d4_rehearsal_adapters": (
-        "83102dad2337370934b4d6fcead3d89413457caa84beb5e1cc01587fa428d77f", 407280),
+        "227672b0e87f743adae87c959d3a50ae61caa873ae1330cf1902911597213cfc", 408507),
 }
 
 

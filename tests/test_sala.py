@@ -146,9 +146,12 @@ def test_block_attention_with_equal_widths_is_the_parents_program():
     """PR 33 gave ``block_sparse_attention`` a value width of its own.  With
     values as wide as the keys it must still be the program it was: the jaxpr
     of its output and three gradients at this file's sizes (two KV heads, a
-    block mask by KV head, chunks of 16), taken on that PR's parent commit
-    (ca052ac) with this very function, is pinned by SHA-256, so outputs and
-    gradients are the parent's to the bit on any backend."""
+    block mask by KV head, chunks of 16) is pinned by SHA-256, so outputs and
+    gradients stay the same to the bit on any backend.  First taken on
+    commit ca052ac; taken again when the pass came to cut the mask
+    by query chunk and slice each key chunk's columns in place (the same
+    products in the same order: outputs and gradients equal to the bit at
+    these sizes on the CPU, the text not)."""
     import hashlib
     import re
 
@@ -163,7 +166,30 @@ def test_block_attention_with_equal_widths_is_the_parents_program():
         q, k, v, keep, block_size=8, q_chunk=16, k_chunk=16).astype(jnp.float32) ** 2)
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2)))(q, k, k, keep)))
     assert (hashlib.sha256(text.encode()).hexdigest(), len(text)) == (
-        "9fd155ef6b291aa953c9c4e562a8e6209757d60a2ad7ad12e566e5c7fe9789f2", 21302)
+        "e0097377eaea6f87ce16b1ecb271455675dd5d0d8a13c1018a74a25cb154ad29", 22018)
+
+
+def test_blockwise_pass_reads_the_block_mask_in_place():
+    """The pass cuts a bool block mask by query chunk alone and slices a key
+    chunk's blocks where the pair is computed: the mask is moved by its
+    leading axes only, in the forward and in the gradient, never relaid into
+    chunk-pair order (a minor axis of ``k_chunk / block_size`` blocks)."""
+    import jax
+    import jax.numpy as jnp
+    from fedml_tpu.ops.sparse_attention import block_sparse_attention
+
+    from .conftest import minor_axes_moved, transposes_of
+
+    q = jax.ShapeDtypeStruct((1, 256, 4, 16), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 256, 2, 16), jnp.bfloat16)
+    keep = jax.ShapeDtypeStruct((1, 2, 256, 16), jnp.bool_)
+    f = lambda q, k, v, keep: block_sparse_attention(q, k, v, keep, block_size=16, q_chunk=64, k_chunk=64)
+    loss = lambda q, k, v, keep: jnp.sum(f(q, k, v, keep).astype(jnp.float32) ** 2)
+    for jaxpr in (jax.make_jaxpr(f)(q, k, k, keep),
+                  jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, k, keep)):
+        moved = transposes_of(jaxpr, jnp.bool_)
+        assert moved, "the mask's query chunks are moved ahead by a transpose the walk must see"
+        assert [m for m in moved if minor_axes_moved(m[1])] == []
 
 
 def _top_k_form(score, candidate, topk):
