@@ -37,7 +37,7 @@ import numpy as np
 import optax
 
 from ..core import rng
-from ..models.transformer import MOE_STATS, Transformer, TransformerConfig, targets_in_document
+from ..models.transformer import KDA_STATS, MOE_STATS, Transformer, TransformerConfig, targets_in_document
 from ..obs import scopes
 from ..obs.metrics import MetricsLogger
 from ..obs.trace import (LLM_ATTENDED_KEYS, LLM_EXPERT_TOKENS, LLM_LOSS_TOKENS, LLM_PACKED_DOCUMENTS,
@@ -186,7 +186,8 @@ class LLMTrainer:
     def _sown_names(self) -> tuple:
         """What the model's layers sow into collection ``stats``."""
         return ((ATTENDED if self.cfg.has_sparse_layers else ())
-                + (MOE_STATS if self.cfg.has_expert_layers else ()))
+                + (MOE_STATS if self.cfg.has_expert_layers else ())
+                + (KDA_STATS if self.cfg.has_kda_layers else ()))
 
     def _metric_names(self, packed: bool = False) -> tuple:
         return (("loss", "ppl") + self._sown_names() + (("mtp_loss",) if self.cfg.mtp_layers else ())
@@ -301,7 +302,9 @@ class LLMTrainer:
         call sites were built: ``attn_kernel_sites``, ``attn_blockwise_sites``
         (``fedml_llm_attention_sites_total`` counts every traced program's),
         and its selective-scan sites: ``scan_kernel_sites``, ``scan_scan_sites``
-        (``fedml_llm_scan_sites_total``).
+        (``fedml_llm_scan_sites_total``); a model with KDA layers
+        ``kda_chunk_decay`` (``KDA_STATS``) in each history entry and on
+        ``llm.step``.
         A batch of three arrays is packed rows: ``docs``, ``loss_tokens``,
         ``doc_pairs`` and ``causal_pairs`` in each history entry and on
         ``llm.step``, ``fedml_llm_packed_documents_total``,
@@ -332,6 +335,8 @@ class LLMTrainer:
                         span.attrs.update({k: m[k] for k in MOE_STATS})
                         for name, kind in zip(MOE_STATS, ("routed", "held")):
                             LLM_EXPERT_TOKENS.inc(m[name], kind=kind)
+                    if KDA_STATS[0] in m:
+                        span.attrs.update({k: m[k] for k in KDA_STATS})
                     if PACKED[0] in m:
                         span.attrs.update({k: m[k] for k in PACKED}, tokens=float(batch[0].size))
                         LLM_PACKED_DOCUMENTS.inc(m["docs"])

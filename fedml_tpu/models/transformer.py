@@ -35,6 +35,12 @@ on grouped-query attention: a frozen learned indexer (``Indexer``,
 query (``ops/dsa.py``), and the query's heads attend those alone; beside it
 the expert router may score by a softmax over all the experts
 (``router_scoring``).
+A seventh, ``KDA``, is Kimi Delta Attention (the gated delta rule with a
+decay per channel, ``ops/kda.py``, beside three short convolutions and
+low-rank gates); beside it ``MLAttention`` may take its queries directly
+(``q_lora_rank`` 0) and go without positions (``mla_use_nope``), and the
+router may choose by a selection bias that its gates do not see
+(``router_bias``).
 ``segments`` (document ids of packed rows) reach every mixer: state,
 convolution and attention stop at a document's start, and the loss counts
 the positions whose target lies in their own document.
@@ -145,6 +151,17 @@ class TransformerConfig:
     dsa_topk: int = 0
     # the expert router's scores (``ops/moe.route``): "sigmoid" or "softmax"
     router_scoring: str = "sigmoid"
+    # ... and a selection bias of its own (``router/e_score_correction_bias``):
+    # the choice is the top_k of scores + bias, the gates the scores alone
+    router_bias: bool = False
+    # "mla" without positions (NoPE): no RoPE on q_r or k_r; q_lora_rank 0 is
+    # one direct ``wq`` in place of the low-rank query path
+    mla_use_nope: bool = False
+    # "kda": Kimi Delta Attention (``KDA``): heads and their width (keys and
+    # values alike), taps of its three short convolutions
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
 
     def has_experts(self, layer: int) -> bool:
         return self.n_routed_experts > 0 and layer >= self.first_k_dense
@@ -160,6 +177,11 @@ class TransformerConfig:
         if kind not in MIXERS:
             raise ValueError(f"unknown mixer {kind!r} for layer {layer} (known: {sorted(MIXERS)})")
         return kind
+
+    @property
+    def has_kda_layers(self) -> bool:
+        """Layers that sow ``KDA_STATS``."""
+        return any(self.mixer(i) == "kda" for i in range(self.n_layers))
 
     @property
     def sparse_selection(self) -> dict:
@@ -396,9 +418,11 @@ MLA_HEAD_GROUP = 32
 
 class MLAttention(nn.Module):
     """Multi-head latent attention in its expanded (training) form: queries
-    through a low-rank path ``c_q = N_q(x W_dq)``, ``[q_n | q_r] = c_q W_uq``;
-    keys and values through another, ``[c_kv | k_r] = x W_dkv``, ``[k_n | v] =
-    N_kv(c_kv) W_ukv``; RoPE on ``q_r`` and on the ONE ``k_r`` all heads share;
+    through a low-rank path ``c_q = N_q(x W_dq)``, ``[q_n | q_r] = c_q W_uq``
+    (with ``q_lora_rank`` 0 one direct ``[q_n | q_r] = x W_q``); keys and
+    values through another, ``[c_kv | k_r] = x W_dkv``, ``[k_n | v] =
+    N_kv(c_kv) W_ukv``; RoPE on ``q_r`` and on the ONE ``k_r`` all heads share
+    (none with ``mla_use_nope``);
     causal softmax attention of ``[q_n | q_r]`` over ``[k_n | k_r]`` scaled by
     the whole query width, values of their own width; ``W_o`` over (heads x
     v_head_dim).  Blockwise (``ops/sparse_attention.block_sparse_attention``,
@@ -419,13 +443,18 @@ class MLAttention(nn.Module):
         _no_segments(self, segments)
         cfg = self.cfg
         h, nope, rot, dv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        c_q = RMSNorm(cfg.norm_eps, name="q_a_norm")(_project(self, "wq_a", x, cfg.q_lora_rank))
-        q = _project(self, "wq_b", c_q.astype(cfg.dtype), (h, nope + rot))
+        if cfg.q_lora_rank:
+            c_q = RMSNorm(cfg.norm_eps, name="q_a_norm")(_project(self, "wq_a", x, cfg.q_lora_rank))
+            q = _project(self, "wq_b", c_q.astype(cfg.dtype), (h, nope + rot))
+        else:
+            q = _project(self, "wq", x, (h, nope + rot))
         kv_a = _project(self, "wkv_a", x, cfg.kv_lora_rank + rot)
         c_kv = RMSNorm(cfg.norm_eps, name="kv_a_norm")(kv_a[..., : cfg.kv_lora_rank])
         kv = _project(self, "wkv_b", c_kv.astype(cfg.dtype), (h, nope + dv))
-        k_r = rope(kv_a[..., None, cfg.kv_lora_rank:], positions, cfg.rope_theta)   # (b, s, 1, rot)
-        q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
+        k_r = kv_a[..., None, cfg.kv_lora_rank:]                                   # (b, s, 1, rot)
+        if not cfg.mla_use_nope:
+            k_r = rope(k_r, positions, cfg.rope_theta)
+            q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
         k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_r, k_r.shape[:2] + (h, rot))], -1)
         with jax.named_scope("llm.mixer.mla.core"):
             out = block_sparse_attention(q, k, kv[..., nope:], None, q_chunk=CHUNK, k_chunk=CHUNK,
@@ -510,15 +539,15 @@ class DSAttention(nn.Module):
         return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
 
 
-def causal_conv(x, kernel, bias, segments=None):
+def causal_conv(x, kernel, bias=None, segments=None):
     """Depthwise causal convolution over the sequence: x (b, s, c), kernel
-    (taps, c), bias (c,) -> ``bias + sum_i kernel[i] * x[t - (taps - 1) + i]``
-    in float32, as ``taps`` shifted products; a tap that would reach before
-    the row's first token, or with ``segments`` (b, s) before its document's,
-    reads zero."""
+    (taps, c), bias (c,) or None -> ``bias + sum_i kernel[i] * x[t - (taps -
+    1) + i]`` in float32, as ``taps`` shifted products; a tap that would reach
+    before the row's first token, or with ``segments`` (b, s) before its
+    document's, reads zero."""
     taps, s = kernel.shape[0], x.shape[1]
     x32, kernel = x.astype(jnp.float32), kernel.astype(jnp.float32)
-    y = bias.astype(jnp.float32) + kernel[-1] * x32
+    y = kernel[-1] * x32 if bias is None else bias.astype(jnp.float32) + kernel[-1] * x32
     for back in range(1, min(taps, s)):
         shifted = jnp.pad(x32[:, : s - back], ((0, 0), (back, 0), (0, 0)))
         if segments is not None:   # documents are runs: the same id ``back`` tokens ago is the same document
@@ -582,8 +611,76 @@ def _dt_bias_init(key, shape, dt_min: float = 1e-3, dt_max: float = 1e-1):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
+#: what a model with KDA layers sows into collection ``stats``, summed over
+#: them: the mean log-decay a chunk lets the state through (``ops/kda.chunk_decay``)
+KDA_STATS = ("kda_chunk_decay",)
+
+
+def _l2_normed(x, eps: float = 1e-6):
+    """``x / sqrt(|x|^2 + eps)`` over each head, in float32."""
+    x32 = x.astype(jnp.float32)
+    return (x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True) + eps)).astype(x.dtype)
+
+
+class KDA(nn.Module):
+    """Kimi Delta Attention as flash-linear-attention's ``KimiDeltaAttention``
+    has it: ``q, k, v = silu(conv(x W_q|k|v))`` (a depthwise causal
+    convolution of ``kda_conv`` taps each, no bias, ``attn/conv_q|k|v``); q
+    and k L2-normed per head; a log-decay per channel ``g = -exp(A_log_h)
+    softplus(x W_fa W_fb + dt_bias)`` (a rank-``head_dim`` pair); ``beta =
+    sigmoid(x W_beta)``; the gated delta rule of ``ops/kda.kda``; then
+    ``W_o(RMSNorm_head(o) * sigmoid(x W_ga W_gb + b_g))``.  Scopes
+    ``llm.mixer.kda`` and beneath it ``.conv``, ``.gate`` (the decay, beta
+    and the output gate) and ``.core`` (the chunked pass).  Sows ``KDA_STATS``
+    into collection ``stats``."""
+
+    cfg: TransformerConfig
+    mesh: Optional[Any] = None
+    seq_axis: Optional[str] = None
+
+    @nn.compact
+    @_scoped("llm.mixer.kda")
+    def __call__(self, x, positions, segments=None):
+        from ..ops.kda import chunk_decay, kda
+
+        _no_seq_axis(self)
+        _no_segments(self, segments)
+        cfg, f32 = self.cfg, jnp.float32
+        h, d = cfg.kda_heads, cfg.kda_head_dim
+        b, s = x.shape[:2]
+        dense = lambda name, y, features, bias=False: nn.DenseGeneral(
+            features, use_bias=bias, dtype=cfg.dtype, name=name)(y)
+        # torch's Conv1d draw (uniform within 1 / sqrt(taps)), as Mamba's
+        conv_init = lambda key, shape: jax.random.uniform(key, shape, f32, -1.0, 1.0) * cfg.kda_conv ** -0.5
+
+        def short_conv(name):
+            y = _project(self, "w" + name, x, (h, d)).reshape(b, s, h * d)
+            kernel = self.param("conv_" + name, conv_init, (cfg.kda_conv, h * d))
+            with jax.named_scope("llm.mixer.kda.conv"):
+                return nn.silu(causal_conv(y, kernel)).astype(cfg.dtype).reshape(b, s, h, d)
+
+        q, k, v = short_conv("q"), short_conv("k"), short_conv("v")
+        q, k = _l2_normed(q), _l2_normed(k)
+        with jax.named_scope("llm.mixer.kda.gate"):
+            # A = 1..16 drawn uniformly (flash-linear-attention's), dt's bias as Mamba-2's
+            a_log = self.param("A_log", lambda key, shape: jnp.log(
+                jax.random.uniform(key, shape, f32, 1.0, 16.0)), (h,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (h * d,))
+            f = dense("wf_b", dense("wf_a", x, d), (h, d)).astype(f32) + dt_bias.reshape(h, d)
+            g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(f)
+            beta = jax.nn.sigmoid(dense("wbeta", x, h).astype(f32))
+        with jax.named_scope("llm.mixer.kda.core"):
+            out = kda(q, k, v, g, beta)
+        self.sow("stats", KDA_STATS[0], chunk_decay(g), init_fn=lambda: jnp.float32(0),
+                 reduce_fn=lambda a, b: a + b)
+        with jax.named_scope("llm.mixer.kda.gate"):
+            gate = jax.nn.sigmoid(dense("wg_b", dense("wg_a", x, d), (h, d), bias=True).astype(f32))
+            out = (RMSNorm(cfg.norm_eps, name="o_norm")(out).astype(f32) * gate).astype(cfg.dtype)
+        return _project(self, "wo", out, cfg.d_model, axis=(-2, -1))
+
+
 MIXERS = {"attention": Attention, "lightning-attn": LightningAttention, "minicpm4": SparseAttention,
-          "mla": MLAttention, "mamba": Mamba, "dsa": DSAttention}
+          "mla": MLAttention, "mamba": Mamba, "dsa": DSAttention, "kda": KDA}
 
 
 class MLP(nn.Module):
@@ -609,7 +706,8 @@ MOE_STATS = ("moe_assignments", "moe_held", "moe_max_load")
 
 
 class Router(nn.Module):
-    """``route`` over a kernel of its own (``router/kernel``)."""
+    """``route`` over a kernel of its own (``router/kernel``) and, with
+    ``router_bias``, a selection bias (``router/e_score_correction_bias``)."""
 
     cfg: TransformerConfig
 
@@ -619,8 +717,10 @@ class Router(nn.Module):
 
         cfg = self.cfg
         kernel = self.param("kernel", nn.initializers.lecun_normal(), (x.shape[-1], cfg.n_routed_experts))
+        bias = (self.param("e_score_correction_bias", nn.initializers.zeros, (cfg.n_routed_experts,))
+                if cfg.router_bias else None)
         return route(x, kernel.astype(x.dtype), cfg.top_k, cfg.routed_scaling_factor, cfg.norm_topk_prob,
-                     cfg.router_scoring)
+                     cfg.router_scoring, bias)
 
 
 class Experts(nn.Module):

@@ -3,8 +3,9 @@ experts, and the held experts' part of the result.
 
 Expert parallelism gives a chip ``experts_held`` of a layer's ``n_routed``
 experts.  Every chip routes every token of its own over all of them
-(``route``: sigmoid or softmax scores, the ``top_k`` best, ties to the lower index, no
-gradient through the choice) and computes what its own experts add for the
+(``route``: sigmoid or softmax scores, the ``top_k`` best, by a selection
+bias where one is given, ties to the lower index, no gradient through the
+choice) and computes what its own experts add for the
 tokens routed to them (``expert_ffn``).  On one chip the layer runs without
 its exchange: what the absent experts would add is left out, and nothing
 stands in for them.
@@ -33,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .dsa import top_of
 from .sparse_attention import best_of
 
 #: a round's rows come in multiples of this (the MXU's tile of rows)
@@ -52,20 +54,27 @@ def round_rows(tokens: int, top_k: int, n_routed: int) -> int:
 SCORINGS = ("sigmoid", "softmax")
 
 
-def route(x, w_r, top_k: int, scale: float = 1.0, norm: bool = True, scoring: str = "sigmoid"):
+def route(x, w_r, top_k: int, scale: float = 1.0, norm: bool = True, scoring: str = "sigmoid",
+          bias=None):
     """x: (t, d); w_r: (d, n_routed) -> (idx (t, top_k) int32, ascending;
     gates (t, top_k) float32; counts (n_routed,) int32).  Scores are
     ``sigmoid(x w_r)``, or with ``scoring`` "softmax" ``softmax(x w_r)`` over
     all ``n_routed``, in float32; the ``top_k`` best of each token are chosen
     without a sort and without a gradient (``best_of``: exact, ties to the
-    lower index); ``gates = scale * s / (sum of the chosen s + 1e-20)`` with
-    ``norm``, else ``scale * s``; ``counts`` is how many tokens chose each
-    expert."""
+    lower index), by ``s + bias`` where a selection bias (n_routed,) is given
+    (DeepSeek-V3's ``e_score_correction_bias``; a bias may make a sum
+    negative, which ``best_of``'s keys cannot order and ``dsa.top_of``'s
+    can); ``gates = scale * s / (sum of the chosen s + 1e-20)`` with
+    ``norm``, else ``scale * s``: the bias never reaches a gate; ``counts``
+    is how many tokens chose each expert."""
     if scoring not in SCORINGS:
         raise ValueError(f"unknown router scoring {scoring!r} (known: {SCORINGS})")
     logits = jnp.dot(x, w_r, preferred_element_type=jnp.float32)
     s = jax.nn.sigmoid(logits) if scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
-    chosen = best_of(jax.lax.stop_gradient(s), jnp.ones(s.shape, bool), top_k)
+    if bias is None:
+        chosen = best_of(jax.lax.stop_gradient(s), jnp.ones(s.shape, bool), top_k)
+    else:
+        chosen = top_of(jax.lax.stop_gradient(s + bias.astype(jnp.float32)), jnp.ones(s.shape, bool), top_k)
     rank = jnp.cumsum(chosen, -1, dtype=jnp.int32)[:, None, :]       # 1-based at a chosen expert
     r = jnp.arange(top_k, dtype=jnp.int32)[None, :, None]
     idx = jnp.sum(rank <= r, -1, dtype=jnp.int32)                     # the (r + 1)-th chosen expert

@@ -41,6 +41,11 @@ TRANSFORMER_RULES = [
     (r".*attn/in_proj/kernel", lambda dp, tp: P(dp, tp)),
     (r".*attn/out_proj/kernel", lambda dp, tp: P(tp, dp)),
     (r".*attn/(conv_kernel|conv_bias|A_log|D|dt_bias)", lambda dp, tp: P()),
+    # a KDA mixer's three short convolutions and its low-rank gates (the
+    # decay's pair, beta's, the output gate's pair and its bias): small and
+    # replicated; its wq wk wv wo are laid out as attention's
+    (r".*attn/conv_[qkv]", lambda dp, tp: P()),
+    (r".*attn/(wf_a|wf_b|wbeta|wg_a|wg_b)/(kernel|bias)", lambda dp, tp: P()),
     # a sparse-attention mixer's indexer: its query heads (d_model -> heads x
     # width), its one key and its weights shard their input over data only
     # (the choice sums over the heads on every shard); the key's LayerNorm is
@@ -56,7 +61,7 @@ TRANSFORMER_RULES = [
     # expert axis to spread it over.  The router is small and replicated.
     (r".*moe/experts/w_(gate|up)", lambda dp, tp: P(None, dp, tp)),
     (r".*moe/experts/w_down", lambda dp, tp: P(None, tp, dp)),
-    (r".*moe/router/kernel", lambda dp, tp: P()),
+    (r".*moe/router/(kernel|e_score_correction_bias)", lambda dp, tp: P()),
     # the MTP module's (2 d_model -> d_model) projection, like an mlp's down
     (r".*mtp/proj/kernel", lambda dp, tp: P(tp, dp)),
     # embeddings / head: vocab over model axis

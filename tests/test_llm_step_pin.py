@@ -30,6 +30,15 @@ one step of each of the three gives the earlier trained leaves and loss to
 the bit on the CPU, but the text differs (a ``dynamic_slice`` a pair, a
 ``transpose`` of leading axes alone).  All three pins were taken again on
 the tree that made that change.
+
+Kimi Delta Attention's PR made ``causal_conv``'s bias optional, gave
+``MLAttention`` a direct query projection and a form without positions, and
+gave ``ops/moe.route`` a selection bias.  ``pangu_ultra_moe_d5_ep32`` (latent
+attention with its low-rank query path and RoPE, the sigmoid router) and
+``granite_4_0_h_micro_d10`` (``causal_conv`` with its bias, on the packed
+step) at their rehearsal sizes in adapter mode, as ``benchmark/pangu.py`` and
+``benchmark/granite.py`` build them, are pinned the same way, taken on that
+PR's parent commit (fc2fe36).
 """
 
 import hashlib
@@ -49,6 +58,10 @@ PARENT = {
         "8d58af5b2f3cf940192313ebb2e06602c19799d0b1494d914147871baf8c3b0e", 201908),
     "minicpm_sala_d4_rehearsal_adapters": (
         "227672b0e87f743adae87c959d3a50ae61caa873ae1330cf1902911597213cfc", 408507),
+    "pangu_ultra_moe_d5_ep32_rehearsal_adapters": (
+        "76886b4e0e4136f90be1a760638cba2548c903c64502f5a798aca051160243b2", 566825),
+    "granite_4_0_h_micro_d10_rehearsal_adapters_packed": (
+        "b83a9bea8da2d83c53881673404e248a3537ef6af29b4eb933dcd698d9ca1732", 337712),
 }
 
 
@@ -68,24 +81,30 @@ def _configs():
         logits_dtype=jnp.bfloat16)
     return {"tiny_default": (TransformerConfig.tiny(vocab_size=256), 2, 16, {}),
             "mistral_7b_d2_rehearsal": (mistral, 4, 32, {}),
-            "minicpm_sala_d4_rehearsal_adapters": _sala()}
+            "minicpm_sala_d4_rehearsal_adapters": _adapter_cell("sala", "minicpm_sala_d4", "lora_sft_16k_b1"),
+            "pangu_ultra_moe_d5_ep32_rehearsal_adapters": _adapter_cell(
+                "pangu", "pangu_ultra_moe_d5_ep32", "lora_sft_8k_b1"),
+            "granite_4_0_h_micro_d10_rehearsal_adapters_packed": _adapter_cell(
+                "granite", "granite_4_0_h_micro_d10", "lora_sft_32k_packed_b1") + (True,)}
 
 
-def _sala():
-    """(cfg, batch, seq, the job's adapters) as ``benchmark/sala.py`` builds
-    the cell at its rehearsal sizes."""
+def _adapter_cell(driver: str, config: str, traffic: str):
+    """(cfg, batch, seq, the job's adapters) as ``benchmark/<driver>.py``
+    builds the cell at its rehearsal sizes."""
     bench = os.path.join(ROOT, "benchmark")
     sys.path.insert(0, bench)
     try:
-        import sala
+        import importlib
+
+        module = importlib.import_module(driver)
     finally:
         sys.path.remove(bench)
-    with open(os.path.join(bench, "configs", "minicpm_sala_d4.json")) as fh:
+    with open(os.path.join(bench, "configs", config + ".json")) as fh:
         c = json.load(fh)
-    with open(os.path.join(bench, "traffic", "lora_sft_16k_b1.json")) as fh:
+    with open(os.path.join(bench, "traffic", traffic + ".json")) as fh:
         t = json.load(fh)
     c, t = {**c, **c["rehearsal"]}, {**t, **t["rehearsal"]}
-    cfg = sala.transformer_config(c, t["seq_len"], t["remat_policy"], **t["program"])
+    cfg = module.transformer_config(c, t["seq_len"], t["remat_policy"], **t["program"])
     job = {k: t["train_args"][k] for k in ("lora_rank", "lora_alpha", "lora_targets")}
     return cfg, t["batch_size"], t["seq_len"], job
 
@@ -97,14 +116,15 @@ def step_text(name: str) -> str:
 
     from fedml_tpu.parallel import mesh as meshlib
 
-    cfg, batch, seq, job = _configs()[name]
+    cfg, batch, seq, job, *packed = _configs()[name]
     # a batch of one row needs a mesh of one device (the default takes all eight)
     mesh = meshlib.make_mesh((meshlib.AXIS_DATA,), devices=jax.devices()[:1]) if batch == 1 else None
     tr = LLMTrainer(cfg, LLMTrainArgs(batch_size=batch, seq_len=seq, total_steps=10, warmup_steps=2,
                                       **job), mesh=mesh)
     tok = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
     state = (tr.params, tr.opt_state) if tr.lora is None else (tr.lora, tr.opt_state, tr.params)
-    text = str(jax.make_jaxpr(tr._make_train_step())(*state, tok, tok))
+    segments = (tok,) if packed else ()     # a packed row's document ids: the third array
+    text = str(jax.make_jaxpr(tr._make_train_step())(*state, tok, tok, *segments))
     return re.sub(r"0x[0-9a-f]+", "0x", text)  # addresses of callables differ from run to run
 
 
