@@ -42,6 +42,7 @@ from ..obs import scopes
 from ..obs.metrics import MetricsLogger
 from ..obs.trace import (LLM_ATTENDED_KEYS, LLM_EXPERT_TOKENS, LLM_LOSS_TOKENS, LLM_PACKED_DOCUMENTS,
                          XLA_COUNTERS, install_xla_listener, traced)
+from ..ops.kda import KDA_PATHS, kda_sites
 from ..ops.sparse_attention import ATTENTION_PATHS, attention_sites
 from ..ops.ssd import SCAN_PATHS, scan_sites
 from ..parallel import mesh as meshlib, sharding
@@ -166,6 +167,8 @@ class LLMTrainer:
         self.attention_sites = dict.fromkeys(ATTENTION_PATHS, 0)
         #: ... and its selective-scan call sites, likewise
         self.scan_sites = dict.fromkeys(SCAN_PATHS, 0)
+        #: ... and its KDA call sites, likewise
+        self.kda_sites = dict.fromkeys(KDA_PATHS, 0)
         #: the step program last published to ``obs/scopes.py`` (``_dispatch``)
         self._noted_step = None
         # Pin the step's output shardings to the input shardings: with
@@ -204,7 +207,7 @@ class LLMTrainer:
         args = self.args
         # the step writes its sites into the trainer's two dicts, not into the
         # trainer: the jitted step outlives it (``obs/scopes.py`` keeps the step)
-        attn_sites, scans = self.attention_sites, self.scan_sites
+        attn_sites, scans, kdas = self.attention_sites, self.scan_sites, self.kda_sites
         sown_names = self._sown_names()
         mtp = self.cfg.mtp_layers > 0
         # the MTP module's loss needs the targets inside the model too
@@ -244,13 +247,14 @@ class LLMTrainer:
 
         def update(trained, opt_state, base, tokens, targets, *segments):
             # runs while the program is traced: the sites counted meanwhile are its own
-            before, scans_before = attention_sites(), scan_sites()
+            before, scans_before, kdas_before = attention_sites(), scan_sites(), kda_sites()
             # the scopes name each op's phase in a device profile (XProf)
             with jax.named_scope("llm.fwd_bwd"):
                 (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                     trained, base, tokens, targets, *segments)
             attn_sites.update({p: n - before[p] for p, n in attention_sites().items()})
             scans.update({p: n - scans_before[p] for p, n in scan_sites().items()})
+            kdas.update({p: n - kdas_before[p] for p, n in kda_sites().items()})
             with jax.named_scope("llm.optimizer"):
                 updates, opt_state = opt.update(grads, opt_state, trained)
                 trained = optax.apply_updates(trained, updates)
@@ -302,9 +306,10 @@ class LLMTrainer:
         call sites were built: ``attn_kernel_sites``, ``attn_blockwise_sites``
         (``fedml_llm_attention_sites_total`` counts every traced program's),
         and its selective-scan sites: ``scan_kernel_sites``, ``scan_scan_sites``
-        (``fedml_llm_scan_sites_total``); a model with KDA layers
-        ``kda_chunk_decay`` (``KDA_STATS``) in each history entry and on
-        ``llm.step``.
+        (``fedml_llm_scan_sites_total``), and its KDA sites:
+        ``kda_kernel_sites``, ``kda_scan_sites`` (``fedml_llm_kda_sites_total``);
+        a model with KDA layers ``kda_chunk_decay`` (``KDA_STATS``) in each
+        history entry and on ``llm.step``.
         A batch of three arrays is packed rows: ``docs``, ``loss_tokens``,
         ``doc_pairs`` and ``causal_pairs`` in each history entry and on
         ``llm.step``, ``fedml_llm_packed_documents_total``,
@@ -349,6 +354,7 @@ class LLMTrainer:
                 history.append(m)
             fit_span.attrs.update({f"attn_{p}_sites": n for p, n in self.attention_sites.items()})
             fit_span.attrs.update({f"scan_{p}_sites": n for p, n in self.scan_sites.items()})
+            fit_span.attrs.update({f"kda_{p}_sites": n for p, n in self.kda_sites.items()})
         return history
 
     def n_params(self) -> int:

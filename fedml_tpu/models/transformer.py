@@ -670,7 +670,7 @@ class KDA(nn.Module):
             g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(f)
             beta = jax.nn.sigmoid(dense("wbeta", x, h).astype(f32))
         with jax.named_scope("llm.mixer.kda.core"):
-            out = kda(q, k, v, g, beta)
+            out = kda(q, k, v, g, beta, mesh=self.mesh)
         self.sow("stats", KDA_STATS[0], chunk_decay(g), init_fn=lambda: jnp.float32(0),
                  reduce_fn=lambda a, b: a + b)
         with jax.named_scope("llm.mixer.kda.gate"):

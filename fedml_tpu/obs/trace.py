@@ -43,7 +43,7 @@ __all__ = [
     "Span", "traced", "activate", "current", "start_span",
     "inject", "extract", "new_id", "recent", "clear_recent",
     "install_xla_listener", "XLA_COUNTERS", "LLM_ATTENDED_KEYS", "LLM_EXPERT_TOKENS",
-    "LLM_ATTENTION_SITES", "LLM_SCAN_SITES", "LLM_PACKED_DOCUMENTS", "LLM_LOSS_TOKENS",
+    "LLM_ATTENTION_SITES", "LLM_SCAN_SITES", "LLM_KDA_SITES", "LLM_PACKED_DOCUMENTS", "LLM_LOSS_TOKENS",
 ]
 
 #: finished spans, oldest first; a window of some thousand steps fits, and
@@ -338,6 +338,16 @@ LLM_SCAN_SITES = REGISTRY.counter(
     "(one TPU device, shapes it tiles), path=scan the lax.scan over chunks (a "
     "mesh, another backend or other shapes).  Counted at build time, once a "
     "site a trace.",
+    labels=("path",),
+)
+#: fed by ``ops/kda.kda_path`` while a program is traced
+LLM_KDA_SITES = REGISTRY.counter(
+    "fedml_llm_kda_sites_total",
+    "Kimi Delta Attention (chunked gated delta rule) call sites of the programs "
+    "traced so far, by the path each was built on: path=kernel is the fused "
+    "Pallas kernel pair (one TPU device, shapes it tiles), path=scan the "
+    "lax.scan over chunks (a mesh, another backend or other shapes).  Counted "
+    "at build time, once a site a trace.",
     labels=("path",),
 )
 

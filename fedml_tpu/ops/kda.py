@@ -27,15 +27,22 @@ nothing overflows: a chunk goes in sub-blocks of ``SUB`` tokens; a pair in
 two different sub-blocks splits its decay at the later one's first token
 ``r`` (``exp(G_t - G_r) exp(G_r - G_j)``, both factors at most 1, and a
 product over the channels on the matrix unit), and the pairs inside one
-sub-block take their (SUB, SUB, d_k) decays whole.  The triangular solve is
-``lax.linalg``'s.  The backward pass is the scan's own with each chunk
-rematerialised (``jax.checkpoint`` around the body), as ``ops/ssd.py``'s
-``"scan"`` path: only the carried states (2 MB a chunk at 32 heads of 128)
-are kept.  Everything inside a chunk is float32, its products at
-``HIGHEST`` precision (a TPU's default rounds a float32 operand to bfloat16,
-and the solve and the carried state would inherit that); q, k, v come in the
-model's dtype.  ``kda_recurrent`` is the token-by-token form the chunks are
-held to.
+sub-block take their (SUB, SUB, d_k) decays whole.  Everything inside a
+chunk is float32, its products at ``HIGHEST`` precision (a TPU's default
+rounds a float32 operand to bfloat16, and the solve and the carried state
+would inherit that); q, k, v come in the model's dtype.
+
+Two paths, one algorithm; ``kda_path`` chooses, from what the program can
+observe, as ``ops/ssd.scan_path`` does.  ``"scan"`` is the ``lax.scan``
+below: its triangular solve is ``lax.linalg``'s, and its backward pass is the
+scan's own with each chunk rematerialised (``jax.checkpoint`` around the
+body), so only the carried states (2 MB a chunk at 32 heads of 128) are kept.
+``"kernel"`` is the fused Pallas pair of ``ops/pallas/kda.py``, for one TPU
+device and shapes it tiles: the state and a chunk's tiles stay in VMEM, the
+solve is exact inside the kernel, and the backward is written out from the
+saved entry states.  The scan is the path of every mesh and of the CPU, and
+the form the kernel is held to (``tests/test_kda_kernel.py``);
+``kda_recurrent`` is the token-by-token form both are held to.
 """
 
 from __future__ import annotations
@@ -43,12 +50,38 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import LLM_KDA_SITES
+from .pallas import kda as kda_kernel
+
 #: tokens a step of the scan takes where the caller names none (the chunk of
 #: flash-linear-attention's KDA kernels)
 CHUNK = 64
-#: tokens of a sub-block whose pairs take their decays whole
-SUB = 16
+#: tokens of a sub-block whose pairs take their decays whole (the kernel's too)
+SUB = kda_kernel.SUB
 _HI = jax.lax.Precision.HIGHEST
+
+#: the paths a KDA call site is built on
+KDA_PATHS = ("kernel", "scan")
+
+
+def kda_sites() -> dict:
+    """Call sites counted so far, by path (``fedml_llm_kda_sites_total``): a
+    program's own are what its tracing adds."""
+    return {path: int(LLM_KDA_SITES.value(path=path)) for path in KDA_PATHS}
+
+
+def kda_path(q, v, chunk: int, mesh) -> str:
+    """``"kernel"`` where the fused Pallas pair can stand for the ``lax.scan``,
+    else ``"scan"``: no mesh in the caller's hands (jax cannot partition a
+    Mosaic call), a TPU backend, and shapes the kernel tiles (whole chunks
+    among them: a ragged tail is the scan's).  Counts the call site under the
+    path it takes: called while a program is traced, so once for each site
+    the program has."""
+    kernel = (mesh is None and jax.default_backend() == "tpu"
+              and kda_kernel.tiles(q, v, min(chunk or CHUNK, q.shape[1])))
+    path = "kernel" if kernel else "scan"
+    LLM_KDA_SITES.inc(1, path=path)
+    return path
 
 
 def _pairs(x, k, gam, strict: bool = False):
@@ -74,16 +107,20 @@ def _pairs(x, k, gam, strict: bool = False):
     return across + inside
 
 
-def kda(q, k, v, g, beta, chunk: int = 0, scale=None):
+def kda(q, k, v, g, beta, chunk: int = 0, scale=None, mesh=None):
     """q, k: (b, s, h, d_k); v: (b, s, h, d_v); g: (b, s, h, d_k) float32,
     <= 0; beta: (b, s, h) float32 -> (b, s, h, d_v) in q's dtype.  ``chunk``
     0 is ``CHUNK``; ``scale`` None is ``d_k ** -0.5``.  ``s`` need not be a
     multiple of the chunk: the tail is padded with ``g = 0`` and ``beta = 0``
-    (a token that neither decays nor writes) and cut off again."""
+    (a token that neither decays nor writes) and cut off again.  ``mesh`` is
+    the mesh the calling module holds, if any: what it computes on may be
+    sharded, which keeps it on the ``lax.scan`` (``kda_path``)."""
     b, s, h, dk = q.shape
     dv = v.shape[-1]
     scale = dk ** -0.5 if scale is None else scale
     c = min(chunk or CHUNK, s)
+    if kda_path(q, v, chunk, mesh) == "kernel":
+        return kda_kernel.kda(q, k, v, g, beta, c, scale)
     nc = -(-s // c)
     f32 = jnp.float32
     g, beta = g.astype(f32), beta.astype(f32)

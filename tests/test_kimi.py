@@ -516,15 +516,19 @@ def _no_compile_cache():
 def test_mixer_compiles_for_the_chip_at_the_cells_size(kind, limit_gb, bench, one_chip, monkeypatch):
     """One KDA and one latent-attention mixer at the cell's widths and 16,384
     tokens, forward and the gradient to its input under the block's remat:
-    the chip's compiler takes each, the latent one on the flash kernel (as
-    one TPU device runs it), each within its temporaries' bound (KDA's 5.67
-    GB: the layer's float32 convolutions, decays and gates and its chunked
-    pass, whose HIGHEST-precision products split their operands)."""
+    the chip's compiler takes each on the path one TPU device runs, KDA's
+    chunked pass as the fused kernel pair of ``ops/pallas/kda.py`` (in the
+    cell's chunks of 64) and the latent one on the flash kernel, each within
+    its temporaries' bound (KDA's 4.37 GB: the layer's float32 convolutions
+    and gates and the kernel's saved entry states, 512 MB; 5.67 GB on the
+    ``lax`` scan, whose HIGHEST-precision products split their operands)."""
     import jax
     import jax.numpy as jnp
     from fedml_tpu.models import transformer as tfm
+    from fedml_tpu.ops import kda
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kda, "CHUNK", bench["flops"].CHUNK)     # the cell's chunk, as its work is counted
     cfg = bench["kimi"].transformer_config(bench["full_config"], 16384)
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     x, pos = spec((1, 16384, 2304), jnp.bfloat16), spec((1, 16384), jnp.int32)
@@ -538,5 +542,6 @@ def test_mixer_compiles_for_the_chip_at_the_cells_size(kind, limit_gb, bench, on
     loss = lambda p, x, pos: jnp.sum(remat(p, x, pos).astype(jnp.float32))
     with _no_compile_cache():
         compiled = jax.jit(jax.grad(loss, argnums=1)).lower(params, x, pos).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == (kind == "mla")
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and (("fedml_kda_bwd" in text) == (kind == "kda"))
     assert compiled.memory_analysis().temp_size_in_bytes < limit_gb * 1e9
